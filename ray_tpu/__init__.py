@@ -16,7 +16,7 @@ a C++ client frontend over a cross-language gateway (``cpp/``,
 logs, Chrome-trace timeline), and the library family (``data``, ``train``, ``tune``,
 ``serve``, ``rllib``, ``workflow``) — with the scheduling/packing data
 planes evaluated as dense TPU computations (JAX/XLA/Pallas) per
-BASELINE.json's north star.  Remaining gaps are tracked in VERDICT.md.
+BASELINE.json's north star.  Remaining gaps are tracked in ROADMAP.md.
 
 Public API mirrors the reference's (``ray.init/remote/get/put/wait/...``,
 SURVEY.md §1 layer 9).

@@ -449,6 +449,10 @@ def init(resources: dict[str, float] | None = None,
             return
         if system_config is not None:
             Config.reset(system_config)
+        # before the raylets' first device beat compiles (the head
+        # daemon reaches this through HeadNode -> init)
+        from .util.compile_cache import enable_compile_cache
+        enable_compile_cache()
         cfg = get_config()
         ncpu = os.cpu_count() or 4
         if resources is None:

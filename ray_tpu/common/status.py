@@ -1,4 +1,4 @@
-"""Status / error taxonomy for the runtime.
+"""Status / error classes for the runtime.
 
 Reference parity: upstream Ray's ``ray::Status`` (``src/ray/common/status.h``)
 plus the user-visible exception hierarchy in ``python/ray/exceptions.py``

@@ -729,13 +729,8 @@ def _send_call_results(ctx, task_id_bin, method, out,
 def worker_main(conn, worker_index: int,
                 arena_path: str | None = None,
                 runtime_env_payload: dict | None = None) -> None:
-    """Entry point of a spawned worker process."""
-    # workers never own the TPU: the device data plane belongs to the
-    # raylet/driver process; user task code that imports jax gets CPU.
-    # FORCED, not setdefault — the ambient environment may already pin
-    # JAX_PLATFORMS to the TPU platform (single chip, owned elsewhere),
-    # and a worker trying to claim it fails or contends
-    os.environ["JAX_PLATFORMS"] = "cpu"
+    """Entry point of a spawned worker process.  It never owns the TPU:
+    ``LocalSpawner`` starts it with ``JAX_PLATFORMS=cpu``."""
     # enter the staged runtime environment BEFORE any user code runs
     from .runtime_env import apply_payload
     apply_payload(runtime_env_payload)

@@ -1,9 +1,7 @@
-"""JAX version compatibility shims shared across the codebase.
+"""JAX shims shared across the codebase.
 
-One place for the ``jax.shard_map`` vs ``jax.experimental.shard_map``
-split (and its ``check_vma``/``check_rep`` kwarg rename) — parallel
-copies of this try/except drifted across modules and must move together
-on a JAX upgrade.
+One place for the ``shard_map`` flavour the collective bodies use, so
+the replication-check setting moves together on a JAX upgrade.
 """
 
 from __future__ import annotations
@@ -12,14 +10,8 @@ from functools import partial
 
 
 def shard_map_compat(*, check: bool = False):
-    """The current JAX's ``shard_map``, with replication checking
-    disabled by default (our collective bodies return deliberately
-    replicated outputs that the checker cannot always prove)."""
-    try:
-        from jax import shard_map              # jax >= 0.8
-        return shard_map if check else partial(shard_map,
-                                               check_vma=False)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-        return shard_map if check else partial(shard_map,
-                                               check_rep=False)
+    """``jax.shard_map``, with replication checking disabled by default
+    (our collective bodies return deliberately replicated outputs that
+    the checker cannot always prove)."""
+    from jax import shard_map
+    return shard_map if check else partial(shard_map, check_vma=False)
