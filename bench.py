@@ -125,10 +125,9 @@ def delta_churn_bench(n_nodes: int = 256, n_classes: int = 32,
     """Delta-scheduling heartbeat under node churn, on the REAL stack:
     a ClusterResourceManager takes random subtract/add_back mutations
     between beats and the DeltaScheduler syncs its HBM mirror from the
-    dirty journal.  Returns hit rate, per-beat p50, the per-phase
-    breakdown (profile mode inserts device syncs, so phase sums exceed
-    the unprofiled beat wall time), and bit-parity of the final beat
-    vs the CPU oracle.
+    dirty journal.  Returns hit rate, per-beat p50 and bit-parity of
+    the final beat vs the CPU oracle.  Where the beat's time goes is
+    read from its ``delta.*`` profiler spans (ray_tpu.util.tracing).
 
     ``shards > 1`` runs the mesh-sharded engine instead (r14): node
     rows partitioned over the device mesh with the two-level ICI/DCN
@@ -149,15 +148,11 @@ def delta_churn_bench(n_nodes: int = 256, n_classes: int = 32,
     class_reqs = [ResourceRequest({"CPU": int(rng.integers(1, 4)),
                                    "memory": float(rng.integers(0, 8))})
                   for _ in range(n_classes)]
-    t0 = time.perf_counter()
     vecs = np.stack([crm.intern_request(r) for r in class_reqs])
-    densify_ms = (time.perf_counter() - t0) * 1e3
     counts = rng.integers(1, 40, size=n_classes).astype(np.int32)
 
     eng = ShardedDeltaScheduler(crm, shards) if shards > 1 \
         else DeltaScheduler(crm)
-    eng.profile = True
-    eng.phase_ms["densify"] += densify_ms
     churn_req = ResourceRequest({"CPU": 1})
     debts: list[int] = []
     got = eng.beat(vecs, counts)            # beat 1: the full sync
@@ -174,14 +169,11 @@ def delta_churn_bench(n_nodes: int = 256, n_classes: int = 32,
         got = eng.beat(vecs, counts)
         per_beat.append((time.perf_counter() - t0) * 1e3)
     want = schedule_grouped_oracle(crm.snapshot(), vecs, counts)
-    n_beats = eng.stats["beats"]
     return {
         "workload": f"{n_nodes} nodes x {n_classes} classes, "
                     f"{churn} dirty rows/beat x {beats} beats",
         "hit_rate": round(eng.hit_rate(), 4),
         "beat_p50_ms": round(float(np.percentile(per_beat, 50)), 3),
-        "phases_ms_per_beat": {k: round(v / n_beats, 4)
-                               for k, v in eng.phase_ms.items()},
         "oracle_parity": bool((got == want).all()),
         "shards": eng.stats.get("shards", 1),
         **{k: eng.stats[k] for k in ("beats", "delta_beats",
@@ -189,13 +181,6 @@ def delta_churn_bench(n_nodes: int = 256, n_classes: int = 32,
                                      "rows_uploaded")},
     }
 
-
-# sharded-phase names for the r14 breakdown (ISSUE 14 satellite 1):
-# the engine's phase timers keep the r08 keys; the record maps them to
-# what each phase IS on the sharded path.
-_SHARDED_PHASE_NAMES = {"h2d": "shard_upload", "score": "local_score",
-                        "argmin": "cross_device_reduce",
-                        "readback": "readback", "densify": "densify"}
 
 # per-device HBM for the ceiling model, keyed by ``device_kind``.
 # Source: Google Cloud documentation, "TPU v5e" (16 GB HBM2 per chip).
@@ -232,9 +217,8 @@ def sharded_delta_bench(n_nodes: int = 512, n_classes: int = 48,
                         seed: int = 0, shards: int = 0) -> dict:
     """The r14 sharded-vs-fused stage: the SAME churn workload through
     the single-device engine and the mesh-sharded engine, with the
-    sharded per-phase breakdown (shard upload / local score /
-    cross-device reduce / readback) and the HBM-ceiling model showing
-    how much larger a problem the mesh holds than one chip."""
+    HBM-ceiling model showing how much larger a problem the mesh holds
+    than one chip."""
     import jax
 
     from ray_tpu.ops.shard_reduce import resolve_shards
@@ -245,9 +229,6 @@ def sharded_delta_bench(n_nodes: int = 512, n_classes: int = 48,
     if s > 1:
         sharded = delta_churn_bench(n_nodes, n_classes, beats, churn,
                                     seed, shards=s)
-        sharded["phases_ms_per_beat"] = {
-            _SHARDED_PHASE_NAMES.get(k, k): v
-            for k, v in sharded["phases_ms_per_beat"].items()}
         rec["sharded"] = sharded
         rec["bit_exact_fused_vs_sharded"] = bool(
             sharded["oracle_parity"] and fused["oracle_parity"])

@@ -32,6 +32,7 @@ from ..common.resources import ResourceRequest
 from ..common.task_spec import SchedulingStrategyKind
 from ..scheduling.policy import (CompositeSchedulingPolicy,
                                  SchedulingOptions, SchedulingType)
+from ..util.tracing import phase
 from .object_ref import ObjectRef
 from .serialization import (RayTaskError, WorkerCrashedError, deserialize,
                             serialize)
@@ -494,7 +495,6 @@ class Raylet:
         otherwise — bit-identical placements either way (parity contract).
         """
         cfg = get_config()
-        specs = [rec.spec for rec in batch]
         # ALL-DEFAULT batches take the subgroup route (device or its
         # host twin): locality bias, lease-avoidance, and top-k are
         # device surfaces too (ops/locality_kernel.py).  Both backends
@@ -502,19 +502,23 @@ class Raylet:
         # first-appearance order, so scheduler_device_batch_min is not
         # observable in placements (top-k rounds excepted — documented
         # sampling divergence)
-        if (cfg.scheduler_device_backend
-                and all(s.strategy.kind is SchedulingStrategyKind.DEFAULT
-                        for s in specs)):
-            prefs = [self._locality_row(s) if not
-                     (s.task_id in self._avoid_local) else None
-                     for s in specs]
-            avoids = [s.task_id in self._avoid_local for s in specs]
-            with self._cv:      # flags consumed, like the host path
-                for s, a in zip(specs, avoids):
-                    if a:
-                        self._avoid_local.discard(s.task_id)
-            n_sub = len({(s.scheduling_class(), p, a) for s, p, a
-                         in zip(specs, prefs, avoids)})
+        with phase("raylet.group"):
+            specs = [rec.spec for rec in batch]
+            default = cfg.scheduler_device_backend and all(
+                s.strategy.kind is SchedulingStrategyKind.DEFAULT
+                for s in specs)
+            if default:
+                prefs = [self._locality_row(s) if not
+                         (s.task_id in self._avoid_local) else None
+                         for s in specs]
+                avoids = [s.task_id in self._avoid_local for s in specs]
+                with self._cv:      # flags consumed, like the host path
+                    for s, a in zip(specs, avoids):
+                        if a:
+                            self._avoid_local.discard(s.task_id)
+                n_sub = len({(s.scheduling_class(), p, a) for s, p, a
+                             in zip(specs, prefs, avoids)})
+        if default:
             if (len(batch) >= cfg.scheduler_device_batch_min
                     and n_sub <= cfg.tpu_group_capacity):
                 return self._schedule_rows_device(specs, prefs, avoids)
@@ -622,45 +626,46 @@ class Raylet:
         from ..ops import schedule_grouped
         from ..scheduling.contract import threshold_fp
 
-        if prefs is None:
-            prefs = [None] * len(specs)
-        if avoids is None:
-            avoids = [False] * len(specs)
         cfg = get_config()
-        width = self.crm.arrays()[0].shape[1]
-        groups: dict[tuple, int] = {}
-        reqs: list[np.ndarray] = []
-        counts: list[int] = []
-        pref_rows: list[int] = []
-        avoid_flags: list[bool] = []
-        task_group = np.empty(len(specs), dtype=np.int32)
-        for t, spec in enumerate(specs):
-            pref = prefs[t] if prefs[t] is not None else -1
-            key = (spec.scheduling_class(), pref, avoids[t])
-            g = groups.get(key)
-            if g is None:
-                g = len(reqs)
-                groups[key] = g
-                reqs.append(spec.resources.dense(self.crm.resource_index,
-                                                 width))
-                counts.append(0)
-                pref_rows.append(int(pref))
-                avoid_flags.append(bool(avoids[t]))
-            counts[g] += 1
-            task_group[t] = g
-        G = len(reqs)
-        # pad the class axis to a power-of-2 bucket: every distinct G would
-        # otherwise be a fresh XLA compilation (SURVEY §7 hard part 3);
-        # count-0 padding rows are no-ops in the water-fill
-        Gp = max(8, 1 << (G - 1).bit_length())
-        req_arr = np.zeros((Gp, width), dtype=np.int32)
-        req_arr[:G] = np.stack(reqs)
-        cnt_arr = np.zeros(Gp, dtype=np.int32)
-        cnt_arr[:G] = counts
-        pref_arr = np.full(Gp, -1, dtype=np.int32)
-        pref_arr[:G] = pref_rows
-        top_k = cfg.scheduler_top_k_fraction
-        plain = (pref_arr < 0).all() and not any(avoid_flags)
+        with phase("raylet.group"):
+            if prefs is None:
+                prefs = [None] * len(specs)
+            if avoids is None:
+                avoids = [False] * len(specs)
+            width = self.crm.arrays()[0].shape[1]
+            groups: dict[tuple, int] = {}
+            reqs: list[np.ndarray] = []
+            counts: list[int] = []
+            pref_rows: list[int] = []
+            avoid_flags: list[bool] = []
+            task_group = np.empty(len(specs), dtype=np.int32)
+            for t, spec in enumerate(specs):
+                pref = prefs[t] if prefs[t] is not None else -1
+                key = (spec.scheduling_class(), pref, avoids[t])
+                g = groups.get(key)
+                if g is None:
+                    g = len(reqs)
+                    groups[key] = g
+                    reqs.append(spec.resources.dense(
+                        self.crm.resource_index, width))
+                    counts.append(0)
+                    pref_rows.append(int(pref))
+                    avoid_flags.append(bool(avoids[t]))
+                counts[g] += 1
+                task_group[t] = g
+            G = len(reqs)
+            # pad the class axis to a power-of-2 bucket: every distinct G
+            # would otherwise be a fresh XLA compilation (SURVEY §7 hard
+            # part 3); count-0 padding rows are no-ops in the water-fill
+            Gp = max(8, 1 << (G - 1).bit_length())
+            req_arr = np.zeros((Gp, width), dtype=np.int32)
+            req_arr[:G] = np.stack(reqs)
+            cnt_arr = np.zeros(Gp, dtype=np.int32)
+            cnt_arr[:G] = counts
+            pref_arr = np.full(Gp, -1, dtype=np.int32)
+            pref_arr[:G] = pref_rows
+            top_k = cfg.scheduler_top_k_fraction
+            plain = (pref_arr < 0).all() and not any(avoid_flags)
         if plain and top_k == 0 and not cfg.scheduler_sharded_state \
                 and cfg.scheduler_delta_beats:
             # incremental heartbeat: no snapshot copy, no full upload —
@@ -710,16 +715,17 @@ class Raylet:
                 counts_host = np.asarray(counts_dev)[:G]
         # expand (G, N+1) counts into per-task rows, class-internal order
         # node-row-ascending (tasks within a class are interchangeable)
-        slots = [np.repeat(
-            np.concatenate([np.arange(N, dtype=np.int32),
-                            np.array([-1], dtype=np.int32)]),
-            counts_host[g]) for g in range(G)]
-        cursor = np.zeros(G, dtype=np.int64)
-        rows = []
-        for t in range(len(specs)):
-            g = task_group[t]
-            rows.append(int(slots[g][cursor[g]]))
-            cursor[g] += 1
+        with phase("raylet.expand"):
+            slots = [np.repeat(
+                np.concatenate([np.arange(N, dtype=np.int32),
+                                np.array([-1], dtype=np.int32)]),
+                counts_host[g]) for g in range(G)]
+            cursor = np.zeros(G, dtype=np.int64)
+            rows = []
+            for t in range(len(specs)):
+                g = task_group[t]
+                rows.append(int(slots[g][cursor[g]]))
+                cursor[g] += 1
         return rows
 
     def _schedule_rows_delta(self, req_arr, cnt_arr) -> "np.ndarray":
@@ -741,33 +747,36 @@ class Raylet:
         eng = self._delta_engine
         if eng is None:
             eng = self._delta_engine = make_delta_scheduler(self.crm)
-        _v, totals_f, avail_f, place_mask, _rows = self.crm.delta_view(-2)
-        # suspect soft-avoid, same healthy-survivor rule as
-        # _effective_snapshot (suspect is advisory, never hard)
-        self._suspect_softmask = False
-        extra = None
-        sus = self.crm.suspect_mask()
-        if sus.any():
-            n = min(sus.shape[0], place_mask.shape[0])
-            healthy = place_mask.copy()
-            healthy[:n] &= ~sus[:n]
-            if healthy.any():
-                extra = ~sus
-                self._suspect_softmask = True
-        overrides: dict[int, np.ndarray] = {}
-        for row, planned in self._planned_overrides(
-                avail_f.shape[1]).items():
-            if not 0 <= row < avail_f.shape[0]:
-                continue
-            w = min(avail_f.shape[1], planned.shape[0])
-            base = avail_f[row].astype(np.int64)
-            base[:w] = (base[:w] - planned[:w]).clip(-(2**30), 2**30)
-            overrides[row] = base.astype(np.int32)
+        with phase("raylet.overrides"):
+            _v, totals_f, avail_f, place_mask, _rows = \
+                self.crm.delta_view(-2)
+            # suspect soft-avoid, same healthy-survivor rule as
+            # _effective_snapshot (suspect is advisory, never hard)
+            self._suspect_softmask = False
+            extra = None
+            sus = self.crm.suspect_mask()
+            if sus.any():
+                n = min(sus.shape[0], place_mask.shape[0])
+                healthy = place_mask.copy()
+                healthy[:n] &= ~sus[:n]
+                if healthy.any():
+                    extra = ~sus
+                    self._suspect_softmask = True
+            overrides: dict[int, np.ndarray] = {}
+            for row, planned in self._planned_overrides(
+                    avail_f.shape[1]).items():
+                if not 0 <= row < avail_f.shape[0]:
+                    continue
+                w = min(avail_f.shape[1], planned.shape[0])
+                base = avail_f[row].astype(np.int64)
+                base[:w] = (base[:w] - planned[:w]).clip(-(2**30), 2**30)
+                overrides[row] = base.astype(np.int32)
         counts = eng.beat(req_arr, cnt_arr, overrides=overrides,
                           extra_mask=extra)
         cfg = get_config()
         if cfg.lease_plane_enabled and cfg.lease_budget_source == "beat":
-            self._publish_beat_budgets(eng)
+            with phase("raylet.publish"):
+                self._publish_beat_budgets(eng)
         return counts
 
     def _publish_beat_budgets(self, eng) -> None:

@@ -89,7 +89,10 @@ class ClusterResourceManager:
         # (see _frozen_locked) instead of re-copying every shard's rows.
         self._frozen: tuple | None = None
         self._frozen_prev: tuple | None = None
-        self.frozen_stats = {"full": 0, "patched": 0, "rows_patched": 0}
+        # journal_scanned: journal entries read by delta_view and by the
+        # frozen-generation recycle, one full pass each
+        self.frozen_stats = {"full": 0, "patched": 0, "rows_patched": 0,
+                             "journal_scanned": 0}
         # interned dense request vectors: (req.key(), width) -> frozen vec
         self._req_cache: dict[tuple, np.ndarray] = {}
 
@@ -394,6 +397,7 @@ class ClusterResourceManager:
             if sys.getrefcount(cand[i]) > self._FROZEN_FREE_REFS:
                 return None
         rows = sorted({r for (ver, r) in self._dirty_log if ver > v0})
+        self.frozen_stats["journal_scanned"] += len(self._dirty_log)
         _v, totals, avail, raw_mask, place_mask = cand
         for arr in (totals, avail, raw_mask, place_mask):
             arr.setflags(write=True)
@@ -474,6 +478,7 @@ class ClusterResourceManager:
             else:
                 rows = {r for (ver, r) in self._dirty_log
                         if ver > since_version}
+                self.frozen_stats["journal_scanned"] += len(self._dirty_log)
             return v, totals, avail, place, rows
 
     def row_of(self, node_id: NodeID) -> int | None:

@@ -17,11 +17,11 @@ property test (SURVEY §4 closing note).
 from __future__ import annotations
 
 import enum
-import time
 from dataclasses import dataclass
 
 import numpy as np
 
+from ..util.tracing import phase
 from .contract import (AVAIL_SHIFT, INFEASIBLE_KEY, compute_keys,
                        threshold_fp)
 from .oracle import ClusterState
@@ -291,12 +291,6 @@ class DeltaScheduler:
         self.stats = {"beats": 0, "delta_beats": 0, "full_rescores": 0,
                       "clean_beats": 0, "rows_uploaded": 0,
                       "classes_installed": 0}
-        # opt-in phase profiling (bench.py): inserts device syncs after
-        # every phase, so it DEFEATS the double-buffered overlap — never
-        # enable on the live dispatch path
-        self.profile = False
-        self.phase_ms = {"densify": 0.0, "h2d": 0.0, "score": 0.0,
-                         "argmin": 0.0, "readback": 0.0}
 
     # -- public surface -----------------------------------------------------
     def beat(self, group_reqs, group_counts, overrides=None,
@@ -311,78 +305,77 @@ class DeltaScheduler:
         bool (n,) soft mask ANDed into the placement mask for this beat
         (suspect avoidance) — the carried key tensor ignores it.
         """
-        from ..common.config import get_config
+        with phase("delta.beat"):
+            with phase("delta.view"):
+                from ..common.config import get_config
 
-        thr = int(threshold_fp(spread_threshold))
-        v, totals, avail, place_mask, rows = \
-            self._crm.delta_view(self._version)
-        n_real, r_real = totals.shape
-        cfg = get_config()
-        resync = (rows is None or self._totals is None
-                  or thr != self._thr or n_real != self._n_real
-                  or r_real != self._r_real)
-        if not resync and rows and len(rows) > \
-                cfg.scheduler_delta_max_dirty_fraction * n_real:
-            # the fallback knob: 0.0 disables the delta path entirely
-            resync = True
-        if resync:
-            self._full_sync(totals, avail, place_mask, thr)
-            self.stats["full_rescores"] += 1
-        elif rows:
-            self._delta_sync(sorted(rows), totals, avail, place_mask, thr)
-            self.stats["delta_beats"] += 1
-            self.stats["rows_uploaded"] += len(rows)
-        else:
-            self.stats["clean_beats"] += 1
-        self._version = v
-        self.stats["beats"] += 1
+                thr = int(threshold_fp(spread_threshold))
+                v, totals, avail, place_mask, rows = \
+                    self._crm.delta_view(self._version)
+                n_real, r_real = totals.shape
+                cfg = get_config()
+                resync = (rows is None or self._totals is None
+                          or thr != self._thr or n_real != self._n_real
+                          or r_real != self._r_real)
+                if not resync and rows and len(rows) > \
+                        cfg.scheduler_delta_max_dirty_fraction * n_real:
+                    # the fallback knob: 0.0 disables the delta path
+                    resync = True
+            with phase("delta.upload"):
+                if resync:
+                    self._full_sync(totals, avail, place_mask, thr)
+                    self.stats["full_rescores"] += 1
+                elif rows:
+                    self._delta_sync(sorted(rows), totals, avail,
+                                     place_mask, thr)
+                    self.stats["delta_beats"] += 1
+                    self.stats["rows_uploaded"] += len(rows)
+                else:
+                    self.stats["clean_beats"] += 1
+                self._version = v
+                self.stats["beats"] += 1
 
-        t0 = time.perf_counter() if self.profile else 0.0
-        group_reqs = np.ascontiguousarray(
-            np.asarray(group_reqs, np.int32))
-        g = group_reqs.shape[0]
-        if group_reqs.shape[1] != self._r_real:
-            # caller densified at an older width; columns only ever
-            # append, so zero-padding to the mirror's width is exact
-            norm = np.zeros((g, self._r_real), np.int32)
-            w = min(self._r_real, group_reqs.shape[1])
-            norm[:, :w] = group_reqs[:, :w]
-            group_reqs = norm
-        slots = self._ensure_classes(group_reqs, thr)
-        gp = _bucket(g)
-        slots_p = np.full((gp,), self._cap_c, np.int32)
-        slots_p[:g] = slots
-        counts_p = np.zeros((gp,), np.int32)
-        counts_p[:g] = np.asarray(group_counts, np.int32)
+            with phase("delta.stage"):
+                group_reqs = np.ascontiguousarray(
+                    np.asarray(group_reqs, np.int32))
+                g = group_reqs.shape[0]
+                if group_reqs.shape[1] != self._r_real:
+                    # caller densified at an older width; columns only
+                    # ever append, so zero-padding to the mirror's width
+                    # is exact
+                    norm = np.zeros((g, self._r_real), np.int32)
+                    w = min(self._r_real, group_reqs.shape[1])
+                    norm[:, :w] = group_reqs[:, :w]
+                    group_reqs = norm
+                slots = self._ensure_classes(group_reqs, thr)
+                gp = _bucket(g)
+                slots_p = np.full((gp,), self._cap_c, np.int32)
+                slots_p[:g] = slots
+                counts_p = np.zeros((gp,), np.int32)
+                counts_p[:g] = np.asarray(group_counts, np.int32)
 
-        ov = self._pack_overrides(overrides)
-        if extra_mask is None:
-            em = self._ones
-        else:
-            emp = np.zeros((self._n,), bool)
-            emp[:n_real] = np.asarray(extra_mask, bool)[:n_real]
-            em = self._put_extra_mask(emp)
-        if self.profile:
-            self.phase_ms["densify"] += (time.perf_counter() - t0) * 1e3
-            t0 = time.perf_counter()
+                ov = self._pack_overrides(overrides)
+                if extra_mask is None:
+                    em = self._ones
+                else:
+                    emp = np.zeros((self._n,), bool)
+                    emp[:n_real] = np.asarray(extra_mask, bool)[:n_real]
+                    em = self._put_extra_mask(emp)
 
-        counts_d, amin_d = self._fused_call(
-            slots_p, counts_p, em, ov, thr, require_available)
-        self._last_amin = amin_d
-        if self.profile:
-            counts_d.block_until_ready()    # rtlint: disable=W6
-            self.phase_ms["argmin"] += (time.perf_counter() - t0) * 1e3
-            t0 = time.perf_counter()
-        # the one sanctioned host<-device readback of the beat: rows
-        # [:gp] are the water-fill counts, rows [gp:] the lease budgets
-        packed = np.asarray(counts_d)
-        counts = packed[:gp]
-        self._budgets_host = packed[gp:, :n_real]
-        self._budget_seq += 1
-        if self.profile:
-            self.phase_ms["readback"] += (time.perf_counter() - t0) * 1e3
-        return np.concatenate(
-            [counts[:g, :n_real], counts[:g, -1:]], axis=1)
+            with phase("delta.dispatch"):
+                counts_d, amin_d = self._fused_call(
+                    slots_p, counts_p, em, ov, thr, require_available)
+                self._last_amin = amin_d
+            with phase("delta.readback"):
+                # the one sanctioned host<-device readback of the beat:
+                # rows [:gp] are the water-fill counts, rows [gp:] the
+                # lease budgets
+                packed = np.asarray(counts_d)
+                counts = packed[:gp]
+                self._budgets_host = packed[gp:, :n_real]
+                self._budget_seq += 1
+                return np.concatenate(
+                    [counts[:g, :n_real], counts[:g, -1:]], axis=1)
 
     def hit_rate(self) -> float:
         """Fraction of beats served without a full re-upload/rescore."""
@@ -499,8 +492,6 @@ class DeltaScheduler:
 
     # -- sync internals -----------------------------------------------------
     def _full_sync(self, totals, avail, mask, thr):
-        import jax
-
         n_real, r_real = totals.shape
         n = self._node_pad(n_real)
         r = _bucket(r_real)
@@ -520,27 +511,18 @@ class DeltaScheduler:
         ha[:n_real, :r_real] = avail
         hm = np.zeros((n,), bool)
         hm[:n_real] = mask
-        t0 = time.perf_counter() if self.profile else 0.0
         self._n, self._r = n, r
         self._n_real, self._r_real = n_real, r_real
         self._put_state(ht, ha, hm)
         self._empty_ov = None
-        if self.profile:
-            jax.block_until_ready(self._avail)  # rtlint: disable=W6
-            self.phase_ms["h2d"] += (time.perf_counter() - t0) * 1e3
-            t0 = time.perf_counter()
         self._rebuild_class_plane(thr, rescore=False)
         self._keys = self._full_rescore_call(thr)
-        if self.profile:
-            jax.block_until_ready(self._keys)   # rtlint: disable=W6
-            self.phase_ms["score"] += (time.perf_counter() - t0) * 1e3
         self._thr = thr
 
     def _delta_sync(self, rows, totals, avail, mask, thr):
         import jax
 
         from ..ops import hybrid_kernel as hk
-        t0 = time.perf_counter() if self.profile else 0.0
         b = _bucket(len(rows))
         idx = np.full((b,), self._n, np.int32)   # padding idx -> dropped
         idx[:len(rows)] = rows
@@ -555,16 +537,9 @@ class DeltaScheduler:
         staged = jax.device_put((idx, rt, ra, rm))
         self._stage[self._parity] = staged
         self._parity ^= 1
-        if self.profile:
-            jax.block_until_ready(staged)       # rtlint: disable=W6
-            self.phase_ms["h2d"] += (time.perf_counter() - t0) * 1e3
-            t0 = time.perf_counter()
         self._totals, self._avail, self._mask, self._keys = \
             hk.apply_dirty_rows(self._totals, self._avail, self._mask,
                                 self._keys, self._reqs, *staged, thr)
-        if self.profile:
-            jax.block_until_ready(self._keys)   # rtlint: disable=W6
-            self.phase_ms["score"] += (time.perf_counter() - t0) * 1e3
 
     def _rebuild_class_plane(self, thr, rescore=True):
         cap = _bucket(max(self._next_slot, 1))

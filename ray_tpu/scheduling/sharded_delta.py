@@ -29,8 +29,6 @@ mesh resolves to one chip.
 
 from __future__ import annotations
 
-import time
-
 import numpy as np
 
 from .policy import DeltaScheduler, _bucket
@@ -140,7 +138,6 @@ class ShardedDeltaScheduler(DeltaScheduler):
     def _delta_sync(self, rows, totals, avail, mask, thr):
         import jax
         pl = self._plane
-        t0 = time.perf_counter() if self.profile else 0.0
         buckets, b, n_l = self._shard_buckets(rows)
         s = pl.n_shards
         idx = np.full((s * b,), n_l, np.int32)   # local idx; pad dropped
@@ -164,16 +161,9 @@ class ShardedDeltaScheduler(DeltaScheduler):
                   jax.device_put(rm, pl.sh_vec))
         self._stage[self._parity] = staged
         self._parity ^= 1
-        if self.profile:
-            jax.block_until_ready(staged)       # rtlint: disable=W6
-            self.phase_ms["h2d"] += (time.perf_counter() - t0) * 1e3
-            t0 = time.perf_counter()
         self._totals, self._avail, self._mask, self._keys = \
             pl.apply_dirty_rows(self._totals, self._avail, self._mask,
                                 self._keys, self._reqs, *staged, thr)
-        if self.profile:
-            jax.block_until_ready(self._keys)   # rtlint: disable=W6
-            self.phase_ms["score"] += (time.perf_counter() - t0) * 1e3
 
     def _pack_overrides(self, overrides):
         import jax

@@ -10,14 +10,50 @@ trace id per root submission, every task's span id is its task id, and
 nested submissions inherit the executing task's span as parent.  Spans
 land in the cluster timeline (``runtime/events.py``) tagged with both
 ids; ``get_trace`` rebuilds the tree.
+
+Separately, ``phase(name)`` marks a phase of the placement round on the
+profiler's own clock (``jax.profiler.TraceAnnotation``), so that a
+profiler trace puts every idle gap of the device beside the host phase
+that held it.  The names are ``PHASES``; docs/architecture.md says what
+each covers.
 """
 
 from __future__ import annotations
 
+import contextlib
 import os
+import sys
 import threading
 
 _local = threading.local()      # driver-side ambient context
+
+# ``delta.beat`` is the whole of ``DeltaScheduler.beat``; the other five
+# ``delta.*`` phases cover it between them.  The ``raylet.*`` phases are
+# the raylet round's host work around the beat.
+PHASES = ("delta.beat", "delta.view", "delta.upload", "delta.stage",
+          "delta.dispatch", "delta.readback",
+          "raylet.group", "raylet.overrides", "raylet.expand",
+          "raylet.publish")
+
+_NO_SPAN = contextlib.nullcontext()
+_annotation = None      # jax.profiler.TraceAnnotation, once JAX is loaded
+
+
+def phase(name: str):
+    """A profiler span named ``name``, one of ``PHASES``.
+
+    It records only while a profiler session runs
+    (``jax.profiler.trace`` / ``start_server``) and costs well under a
+    microsecond otherwise.  In a process that has not imported JAX no
+    session can exist: the span is a shared no-op and imports nothing.
+    One span per phase of a round, never one per task or class."""
+    global _annotation
+    if _annotation is None:
+        if "jax" not in sys.modules:
+            return _NO_SPAN
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation(name)
 
 
 def enabled() -> bool:
