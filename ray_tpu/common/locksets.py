@@ -30,6 +30,7 @@ installed, zero (``track`` only records the class in a registry).
 
 from __future__ import annotations
 
+import itertools
 import threading
 
 _registry: list[tuple[type, tuple[str, ...]]] = []
@@ -37,6 +38,7 @@ _originals: dict[type, tuple] = {}
 _installed = False
 _state_lock = threading.Lock()
 _tls = threading.local()
+_serials = itertools.count()
 
 # born instances: sampled only after __init__ returned.  Keyed by id()
 # (some tracked classes may not be weakref-able); entries are dropped
@@ -56,6 +58,16 @@ def _held() -> dict:
     if h is None:
         h = _tls.held = {}
     return h
+
+
+def _thread_serial() -> int:
+    """A per-thread id that is never reused: ``threading.get_ident()``
+    is recycled once a thread exits, so two short-lived writers that
+    never overlap would otherwise count as one thread."""
+    s = getattr(_tls, "serial", None)
+    if s is None:
+        s = _tls.serial = next(_serials)
+    return s
 
 
 def _token(inner) -> int:
@@ -120,7 +132,7 @@ def _is_lockish(v) -> bool:
 def _sample_write(obj, attr) -> None:
     key = (id(obj), attr)
     held = frozenset(_held())
-    tid = threading.get_ident()
+    tid = _thread_serial()
     cls_name = type(obj).__name__
     with _state_lock:
         st = _access.get(key)

@@ -10,8 +10,11 @@ request, strategy, function) triple so equal tasks share lease pools.
 
 TPU-first: the scheduling class is load-bearing here — the device kernel
 batches pending tasks *by scheduling class* (identical demand vectors are
-water-fill-able as one group, see ray_tpu/ops/hybrid_kernel.py), so the class
-key is computed eagerly at spec construction.
+water-fill-able as one group, see ray_tpu/ops/hybrid_kernel.py).  The class
+key pairs the request's key, which ``ResourceRequest`` builds once at
+construction, with the strategy's key, which ``SchedulingStrategy.key()``
+builds on every call; the raylet's subgrouping pass
+(``Raylet._subgroup``) builds it once per strategy object in a round.
 """
 
 from __future__ import annotations

@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import threading
 from collections import deque
+from typing import NamedTuple
 
 import numpy as np
 
@@ -95,6 +96,29 @@ class _ClassQueue:
             yield from list(dq)
 
 
+class _Subgroups(NamedTuple):
+    """A round's (scheduling class, locality row, avoid flag) subgroups
+    in order of first appearance (``Raylet._subgroup``)."""
+    task_group: np.ndarray      # (T,) int32: each task's subgroup
+    counts: np.ndarray          # (G,) tasks per subgroup
+    first: list                 # per subgroup: index of its first task
+    prefs: list                 # per subgroup: locality row, -1 for none
+    avoids: list                # per subgroup: keep off this node
+
+
+def _expand(task_group: np.ndarray, counts: np.ndarray) -> list[int]:
+    """Per-task rows from (G, N+1) placement counts (column N counts the
+    unplaced): the tasks of a subgroup, in batch order, take its node
+    rows ascending, then -1 (tasks of one class are interchangeable)."""
+    n_groups, width = counts.shape
+    cols = np.arange(width, dtype=np.int32)
+    cols[-1] = -1
+    rows = np.empty(task_group.shape[0], dtype=np.int32)
+    rows[np.argsort(task_group, kind="stable")] = np.repeat(
+        np.tile(cols, n_groups), counts.ravel())
+    return rows.tolist()
+
+
 class Raylet:
     def __init__(self, node_id, cluster, num_workers: int,
                  spawner=None, inline_objects: bool = False,
@@ -140,6 +164,9 @@ class Raylet:
         # died between pipeline_target and the commit)
         self._assigned_total = 0
         self._avoid_local: set[TaskID] = set()  # lease-spilled: skip here
+        # subgrouping pass: tasks seen, and those whose key needed a
+        # locality probe or an avoid flag (the slow key; under _cv)
+        self._group_stats = {"group_tasks": 0, "group_probed": 0}
         # last _effective_snapshot soft-masked SUSPECT rows out (gray
         # failures): tells _schedule_rows a -1 deserves a full-cluster
         # fallback pass before parking the task
@@ -235,12 +262,14 @@ class Raylet:
                         or self.agent_inflight)
 
     def queue_stats(self) -> dict:
-        """Live depths + recent scheduling-round durations (metrics)."""
+        """Live depths, recent scheduling-round durations (metrics) and
+        the subgrouping pass's task counts (``_subgroup``)."""
         with self._cv:
             return {"pending": len(self._queue) + len(self._waiting),
                     "placed": len(self._local_queue),
                     "running": len(self._running),
-                    "round_durations": list(self._round_durations)}
+                    "round_durations": list(self._round_durations),
+                    **self._group_stats}
 
     # -- health (GCS health-check manager probes this) -----------------------
     def ping(self) -> None:
@@ -498,32 +527,18 @@ class Raylet:
         # ALL-DEFAULT batches take the subgroup route (device or its
         # host twin): locality bias, lease-avoidance, and top-k are
         # device surfaces too (ops/locality_kernel.py).  Both backends
-        # process subgroups keyed (class, locality row, avoid) in
-        # first-appearance order, so scheduler_device_batch_min is not
-        # observable in placements (top-k rounds excepted — documented
-        # sampling divergence)
+        # take the one subgrouping below, so scheduler_device_batch_min
+        # is not observable in placements (top-k rounds excepted —
+        # documented sampling divergence)
         with phase("raylet.group"):
             specs = [rec.spec for rec in batch]
-            default = cfg.scheduler_device_backend and all(
-                s.strategy.kind is SchedulingStrategyKind.DEFAULT
-                for s in specs)
-            if default:
-                prefs = [self._locality_row(s) if not
-                         (s.task_id in self._avoid_local) else None
-                         for s in specs]
-                avoids = [s.task_id in self._avoid_local for s in specs]
-                with self._cv:      # flags consumed, like the host path
-                    for s, a in zip(specs, avoids):
-                        if a:
-                            self._avoid_local.discard(s.task_id)
-                n_sub = len({(s.scheduling_class(), p, a) for s, p, a
-                             in zip(specs, prefs, avoids)})
-        if default:
+            sub = (self._subgroup(specs) if cfg.scheduler_device_backend
+                   else None)
+        if sub is not None:
             if (len(batch) >= cfg.scheduler_device_batch_min
-                    and n_sub <= cfg.tpu_group_capacity):
-                return self._schedule_rows_device(specs, prefs, avoids)
-            return self._schedule_rows_host_subgrouped(specs, prefs,
-                                                       avoids)
+                    and len(sub.first) <= cfg.tpu_group_capacity):
+                return self._schedule_rows_device(specs, sub)
+            return self._schedule_rows_host_subgrouped(specs, sub)
         # mixed-strategy batches: per-task CPU policy on a snapshot
         # (sequential within the round), partitioned by scheduling class
         # in first-appearance order
@@ -544,13 +559,78 @@ class Raylet:
                                       snapshot.node_mask.shape[0]))
         return rows
 
-    def _schedule_rows_host_subgrouped(self, specs, prefs,
-                                       avoids) -> list[int]:
-        """Host twin of the device subgroup path: the SAME
-        (class, pref, avoid) subgroups in first-appearance order — so
-        small rounds and device rounds evolve ``avail`` identically
-        (the batch-size threshold stays unobservable) and the locality
-        probe is never run twice.
+    def _subgroup(self, specs: list) -> _Subgroups | None:
+        """The round's one grouping pass, shared by the device path and
+        its host twin: each task's subgroup, keyed (scheduling class,
+        locality row, avoid flag) in first-appearance order.  None when
+        a non-DEFAULT strategy sends the batch to the per-class CPU
+        policy (no avoid flag is consumed then).
+
+        The class is ``TaskSpec.scheduling_class()``, its strategy key
+        built once per strategy object in the round (most specs share
+        ``DEFAULT_STRATEGY``).  Only a spec with args can have a
+        locality row and only a non-empty avoid set can flag one, so
+        the plain key skips both probes; ``group_probed`` counts the
+        tasks that took them."""
+        default = SchedulingStrategyKind.DEFAULT
+        avoid_set = self._avoid_local
+        strategy_slot: dict[int, int] = {}     # id(strategy) -> slot
+        strategy_keys: dict[tuple, int] = {}   # strategy key -> slot
+        groups: dict[tuple, int] = {}
+        first: list[int] = []
+        prefs: list[int] = []
+        avoids: list[bool] = []
+        task_group: list[int] = []
+        avoided: list[TaskID] = []
+        probed = 0
+        prev, slot = None, 0
+        for s in specs:
+            st = s.strategy
+            if st is not prev:
+                prev = st
+                slot = strategy_slot.get(id(st))
+                if slot is None:
+                    if st.kind is not default:
+                        return None
+                    slot = strategy_slot[id(st)] = strategy_keys.setdefault(
+                        st.key(), len(strategy_keys))
+            # the plain key (class, slot): no locality row, no flag
+            key = (s.resources.key(), slot)
+            if s.args or avoid_set:
+                if avoid_set and s.task_id in avoid_set:
+                    # locality skipped: the local data node is exactly
+                    # what starved the task
+                    probed += 1
+                    avoided.append(s.task_id)
+                    key = (*key, -1, True)
+                elif s.args:
+                    probed += 1
+                    row = self._locality_row(s)
+                    if row is not None:
+                        key = (*key, int(row), False)
+            g = groups.get(key)
+            if g is None:
+                g = groups[key] = len(first)
+                first.append(len(task_group))
+                pref, avoid = key[2:] or (-1, False)
+                prefs.append(pref)
+                avoids.append(avoid)
+            task_group.append(g)
+        with self._cv:      # flags consumed, like the host path
+            for tid in avoided:
+                self._avoid_local.discard(tid)
+            self._group_stats["group_probed"] += probed
+            self._group_stats["group_tasks"] += len(specs)
+        tg = np.array(task_group, dtype=np.int32)
+        return _Subgroups(tg, np.bincount(tg, minlength=len(first)),
+                          first, prefs, avoids)
+
+    def _schedule_rows_host_subgrouped(self, specs: list,
+                                       sub: _Subgroups) -> list[int]:
+        """Host twin of the device subgroup path: the SAME subgroups
+        (``_subgroup``) in the same order — so small rounds and device
+        rounds evolve ``avail`` identically (the batch-size threshold
+        stays unobservable) and the locality probe is never run twice.
 
         Multi-task subgroups place through the pure-numpy water-fill
         (``ops.hybrid_kernel.schedule_group_host`` — one vectorized
@@ -562,19 +642,20 @@ class Raylet:
         (the host sampler draws per task)."""
         snapshot = self._effective_snapshot()
         n_rows = snapshot.node_mask.shape[0]
-        by_sub: dict[tuple, list[int]] = {}
-        for t, spec in enumerate(specs):
-            key = (spec.scheduling_class(),
-                   prefs[t] if prefs[t] is not None else -1, avoids[t])
-            by_sub.setdefault(key, []).append(t)
-        rows = [-1] * len(specs)
+        # each subgroup's tasks in batch order
+        members = np.split(np.argsort(sub.task_group, kind="stable"),
+                           np.cumsum(sub.counts)[:-1])
+        rows = np.full(len(specs), -1, dtype=np.int32)
         vec_ok = get_config().scheduler_top_k_fraction == 0
         if vec_ok:
             from ..ops.hybrid_kernel import schedule_group_host
             from ..scheduling.contract import threshold_fp
             thr = threshold_fp(None)
-        for (cls_key, pref, avoid), idxs in by_sub.items():
-            req = specs[idxs[0]].resources.dense(
+            cols = np.arange(n_rows + 1, dtype=np.int32)
+            cols[-1] = -1
+        for g in range(len(sub.first)):
+            idxs, pref, avoid = members[g], sub.prefs[g], sub.avoids[g]
+            req = specs[sub.first[g]].resources.dense(
                 self.crm.resource_index, snapshot.totals.shape[1])
             if vec_ok and len(idxs) > 1:
                 gmask = None
@@ -588,14 +669,9 @@ class Raylet:
                 counts_row, new_avail = schedule_group_host(
                     snapshot.avail, snapshot.totals, snapshot.node_mask,
                     req, len(idxs), gmask, thr,
-                    pref_row=-1 if avoid else int(pref))
+                    pref_row=-1 if avoid else pref)
                 snapshot.avail[:] = new_avail       # sequential carry
-                slots = np.repeat(
-                    np.concatenate([np.arange(n_rows, dtype=np.int32),
-                                    np.array([-1], dtype=np.int32)]),
-                    counts_row)
-                for t, r in zip(idxs, slots):
-                    rows[t] = int(r)
+                rows[idxs] = np.repeat(cols, counts_row)
                 continue
             for t in idxs:
                 if avoid:
@@ -604,18 +680,18 @@ class Raylet:
                 elif pref >= 0:
                     opts = SchedulingOptions(
                         scheduling_type=SchedulingType.NODE_AFFINITY,
-                        node_row=int(pref), soft=True)
+                        node_row=pref, soft=True)
                 else:
                     opts = SchedulingOptions()
                 rows[t] = self._policy.schedule(snapshot, req, opts)
-        return rows
+        return rows.tolist()
 
     def _schedule_rows_device(self, specs: list,
-                              prefs: list | None = None,
-                              avoids: list | None = None) -> list[int]:
+                              sub: _Subgroups) -> list[int]:
         """One device water-fill call places the whole batch (north star).
 
-        Subgroups key on (scheduling class, locality row, avoid flag):
+        Subgroups (``_subgroup``) key on (scheduling class, locality
+        row, avoid flag):
         locality-biased groups pre-place on their preferred row (soft
         affinity, bit-identical to the host sequence), avoid groups mask
         out this node, and with ``scheduler_top_k_fraction`` > 0 the
@@ -628,44 +704,22 @@ class Raylet:
 
         cfg = get_config()
         with phase("raylet.group"):
-            if prefs is None:
-                prefs = [None] * len(specs)
-            if avoids is None:
-                avoids = [False] * len(specs)
             width = self.crm.arrays()[0].shape[1]
-            groups: dict[tuple, int] = {}
-            reqs: list[np.ndarray] = []
-            counts: list[int] = []
-            pref_rows: list[int] = []
-            avoid_flags: list[bool] = []
-            task_group = np.empty(len(specs), dtype=np.int32)
-            for t, spec in enumerate(specs):
-                pref = prefs[t] if prefs[t] is not None else -1
-                key = (spec.scheduling_class(), pref, avoids[t])
-                g = groups.get(key)
-                if g is None:
-                    g = len(reqs)
-                    groups[key] = g
-                    reqs.append(spec.resources.dense(
-                        self.crm.resource_index, width))
-                    counts.append(0)
-                    pref_rows.append(int(pref))
-                    avoid_flags.append(bool(avoids[t]))
-                counts[g] += 1
-                task_group[t] = g
-            G = len(reqs)
+            G = len(sub.first)
             # pad the class axis to a power-of-2 bucket: every distinct G
             # would otherwise be a fresh XLA compilation (SURVEY §7 hard
             # part 3); count-0 padding rows are no-ops in the water-fill
             Gp = max(8, 1 << (G - 1).bit_length())
             req_arr = np.zeros((Gp, width), dtype=np.int32)
-            req_arr[:G] = np.stack(reqs)
+            req_arr[:G] = np.stack([
+                specs[t].resources.dense(self.crm.resource_index, width)
+                for t in sub.first])
             cnt_arr = np.zeros(Gp, dtype=np.int32)
-            cnt_arr[:G] = counts
+            cnt_arr[:G] = sub.counts
             pref_arr = np.full(Gp, -1, dtype=np.int32)
-            pref_arr[:G] = pref_rows
+            pref_arr[:G] = sub.prefs
             top_k = cfg.scheduler_top_k_fraction
-            plain = (pref_arr < 0).all() and not any(avoid_flags)
+            plain = (pref_arr < 0).all() and not any(sub.avoids)
         if plain and top_k == 0 and not cfg.scheduler_sharded_state \
                 and cfg.scheduler_delta_beats:
             # incremental heartbeat: no snapshot copy, no full upload —
@@ -673,7 +727,6 @@ class Raylet:
             # planned load rides along as per-beat avail overrides
             counts_host = self._schedule_rows_delta(req_arr[:G],
                                                     cnt_arr[:G])
-            N = counts_host.shape[1] - 1
         else:
             snapshot = self._effective_snapshot()
             totals, avail, mask = (snapshot.totals, snapshot.avail,
@@ -687,7 +740,7 @@ class Raylet:
                 req_arr = wider
             N = totals.shape[0]
             gmask = np.ones((Gp, N), dtype=bool)
-            for g, av in enumerate(avoid_flags):
+            for g, av in enumerate(sub.avoids):
                 if av and 0 <= self.row < N:
                     gmask[g, self.row] = False
             if cfg.scheduler_sharded_state and plain and top_k == 0:
@@ -713,20 +766,8 @@ class Raylet:
                     jnp.asarray(cnt_arr), jnp.asarray(gmask),
                     jnp.asarray(pref_arr), jnp.int32(threshold_fp(None)))
                 counts_host = np.asarray(counts_dev)[:G]
-        # expand (G, N+1) counts into per-task rows, class-internal order
-        # node-row-ascending (tasks within a class are interchangeable)
         with phase("raylet.expand"):
-            slots = [np.repeat(
-                np.concatenate([np.arange(N, dtype=np.int32),
-                                np.array([-1], dtype=np.int32)]),
-                counts_host[g]) for g in range(G)]
-            cursor = np.zeros(G, dtype=np.int64)
-            rows = []
-            for t in range(len(specs)):
-                g = task_group[t]
-                rows.append(int(slots[g][cursor[g]]))
-                cursor[g] += 1
-        return rows
+            return _expand(sub.task_group, counts_host)
 
     def _schedule_rows_delta(self, req_arr, cnt_arr) -> "np.ndarray":
         """The fused delta-heartbeat placement call

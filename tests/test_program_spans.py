@@ -115,7 +115,8 @@ def test_raylet_round_emits_its_spans(tmp_path):
     finally:
         cluster.stop()
     names = [n for n, _, _ in _program_spans(tmp_path)]
-    # the batch check and the subgrouping: two intervals of one name
+    # the one subgrouping pass, then the device path's packing of its
+    # subgroups: two intervals of one name
     assert names.count("raylet.group") == 2
     for name in ("raylet.overrides", "raylet.expand", "raylet.publish",
                  "delta.beat"):
