@@ -1,15 +1,17 @@
 """``correct``: the sampled rounds of the window against the reference.
 
-For every round the sampler kept, the benchmark's own reference
-(``reference/oracle.py``) places the same classes on the generator's own
-record of the cluster at that round (drawn totals and usage, booked by
-every churn op; every node alive, none suspect), and three numbers count
-where the program's answer differs:
+For every round the sampler kept, the configuration's round module
+(``rounds/<name>.py``) gives the reference's answer, from the
+benchmark's own reference (``reference/``) on the generator's own record
+of the cluster at that round (drawn totals and usage, booked by every
+churn op; every node alive, none suspect) and the module's own record of
+what it adds, and the program's answer in the same form.  Three numbers
+count where they differ:
 
 * ``placement_cells_off``: (class, node) cells, the column of tasks no
   node holds included, whose task count differs;
-* ``task_rows_off``: tasks whose node row differs (cells that drive
-  ``_schedule_rows``; the rows follow the raylet's expansion);
+* ``task_rows_off``: tasks whose node row differs (rounds of task
+  records; the rows follow the raylet's expansion);
 * ``budget_cells_off``: (class, node) lease budgets on the budget board
   that differ, a missing class counting every node (rounds that
   published).
@@ -26,51 +28,8 @@ from __future__ import annotations
 
 import numpy as np
 
-from .reference import oracle as ref
-
 LIMITS = {"placement_cells_off": 0, "task_rows_off": 0,
           "budget_cells_off": 0}
-
-
-def _round_inputs(record: dict, sample: dict):
-    """(class vectors in group order, counts, task -> group or None)."""
-    vecs, rounds = record["ref_vecs"], record["rounds"]
-    if rounds.entry == "delta":
-        return vecs, rounds.counts, None
-    _recs, task_class = rounds.pool[sample["index"]]
-    order = list(dict.fromkeys(int(c) for c in task_class))  # first seen
-    group_of = {c: g for g, c in enumerate(order)}
-    task_group = np.array([group_of[int(c)] for c in task_class])
-    counts = np.bincount(task_group, minlength=len(order))
-    return vecs[order], counts, task_group
-
-
-def reference_answer(record: dict, sample: dict, carry: bool = True):
-    """(placed (G, N+1), per-task rows or None, {class key: budgets})."""
-    thr = record["config"]["system_config"]["scheduler_spread_threshold"]
-    totals = record["totals"]
-    mask = np.ones(len(totals), bool)
-    vecs, counts, task_group = _round_inputs(record, sample)
-    placed, after = ref.schedule_grouped(totals, sample["avail"], mask,
-                                         vecs, counts, thr, carry=carry)
-    rows = None if task_group is None else ref.expand_rows(placed,
-                                                           task_group)
-    budgets = ref.compute_budgets(totals, after, record["ref_vecs"], mask)
-    return placed, rows, dict(zip(record["class_keys"], budgets))
-
-
-def program_answer(record: dict, sample: dict):
-    """The program's answer to a sampled round, in the reference's form."""
-    out = sample["out"]
-    if record["rounds"].entry == "delta":
-        placed, rows = np.asarray(out), None
-    else:
-        _v, _c, task_group = _round_inputs(record, sample)
-        rows = np.asarray(out)
-        n = max(len(record["totals"]), int(rows.max()) + 1)
-        placed = np.zeros((int(task_group.max()) + 1, n + 1), np.int64)
-        np.add.at(placed, (task_group, np.where(rows < 0, n, rows)), 1)
-    return placed, rows, sample["board"]
 
 
 def _pad_rows(want: np.ndarray, width: int, last_is_none: bool):
@@ -88,15 +47,18 @@ def _pad_rows(want: np.ndarray, width: int, last_is_none: bool):
 def control(record: dict, sample: dict):
     """The reference in the program's place, with the classes placed
     against the round's starting state instead of one after another."""
-    return reference_answer(record, sample, carry=False)
+    return record["module"].reference_answer(record, sample, carry=False)
 
 
-def judge(record: dict, produce=program_answer) -> dict:
-    """{name: {"value": n, "limit": 0}} over the sampled rounds."""
+def judge(record: dict, produce=None) -> dict:
+    """{name: {"value": n, "limit": 0}} over the sampled rounds; the
+    program's answers unless ``produce`` stands in for them."""
+    module = record["module"]
+    produce = produce or module.program_answer
     off = {k: 0 for k in LIMITS}
     has_rows = has_board = False
     for sample in record["samples"]:
-        want_p, want_r, want_b = reference_answer(record, sample)
+        want_p, want_r, want_b = module.reference_answer(record, sample)
         got_p, got_r, got_b = produce(record, sample)
         want_p = _pad_rows(want_p, got_p.shape[-1], True)
         if got_p.shape != want_p.shape:

@@ -1,8 +1,10 @@
 """The one traffic generator: a deployment and a traffic mix, read from
-their data files, become a cluster, a raylet and the rounds it places.
+their data files, become a cluster and a raylet; the configuration's
+round module (``rounds/<name>.py``) makes the rounds it places.
 
-Everything here is drawn from ``--seed``.  Node totals, class vectors,
-the backlog and the task batches come from one stream; the CRM churn
+Everything here is drawn from ``--seed``.  Node totals, class vectors
+and, after them, the round module's draws (the backlog, the task
+batches) come from one stream (``Deployment.rng``); the CRM churn
 between rounds and the choice of rounds compared with the reference
 from streams of their own, so a change to one leaves the others alone.
 
@@ -151,54 +153,3 @@ class Churn:
                 self.crm.force_subtract(row, self.req)
                 avail[row] -= self.vec
                 self.debts.append(row)
-
-
-class Rounds:
-    """The rounds a traffic mix offers, in order, for ever.
-
-    ``entry: delta`` hands the raylet the whole backlog as class counts
-    (``_schedule_rows_delta``) every round.  ``entry: rows`` hands it a
-    batch of task records (``_schedule_rows``) from a seeded pool of
-    ``pool_batches`` batches of ``round_tasks``.  Each round yields
-    ``(index, payload)``: the batch's place in the pool, and what the
-    raylet is handed.
-    """
-
-    def __init__(self, dep: Deployment, config: dict, traffic: dict):
-        self.entry = traffic["entry"]
-        rng = dep.rng
-        g = len(dep.class_cu)
-        if self.entry == "delta":
-            self.counts = rng.multinomial(
-                config["pending_tasks"], np.full(g, 1 / g)).astype(np.int32)
-            self.pool = []
-            return
-        if self.entry != "rows":
-            raise ValueError(f"unknown entry {self.entry!r}")
-        self.pool = [self._batch(dep, rng, traffic["round_tasks"])
-                     for _ in range(traffic["pool_batches"])]
-
-    @staticmethod
-    def _batch(dep: Deployment, rng, size: int):
-        """(records, task_class): ``size`` default-strategy TaskSpecs with
-        no arguments, class counts multinomial and arrival order shuffled."""
-        from ray_tpu.common.ids import JobID, TaskID
-        from ray_tpu.common.task_spec import TaskSpec, TaskType
-        from ray_tpu.runtime.task_manager import TaskRecord
-
-        g = len(dep.class_cu)
-        counts = rng.multinomial(size, np.full(g, 1 / g))
-        task_class = rng.permutation(np.repeat(np.arange(g), counts))
-        job = JobID.from_int(1)
-        recs = [TaskRecord(TaskSpec(
-            task_id=TaskID.for_task(job), job_id=job,
-            task_type=TaskType.NORMAL_TASK,
-            function_descriptor="benchmark:task",
-            resources=dep.request(int(c))), 0, [])
-            for c in task_class]
-        return recs, task_class
-
-    def at(self, i: int):
-        if self.entry == "delta":
-            return 0, self.counts
-        return i % len(self.pool), self.pool[i % len(self.pool)]

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import contextlib
 import gc
+import importlib
 import json
 import os
 import shutil
@@ -19,7 +20,7 @@ from pathlib import Path
 
 import numpy as np
 
-from .generator import Churn, Deployment, Rounds
+from .generator import Churn, Deployment
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -33,8 +34,9 @@ class NoDevice(SystemExit):
 
 
 def load_cell(workload: str, root: Path = ROOT) -> dict:
-    """The cell, its configuration and traffic files, and the metrics it
-    reports, all found by name through ``BENCHMARK.json``."""
+    """The cell, its configuration and traffic files, its configuration's
+    round module and the metrics it reports, all found by name through
+    ``BENCHMARK.json``."""
     spec = json.loads((root / "BENCHMARK.json").read_text())
     cells = {w["name"]: w for w in spec["workloads"]}
     if workload not in cells:
@@ -46,11 +48,31 @@ def load_cell(workload: str, root: Path = ROOT) -> dict:
     e2e, layer = ([m for m in spec[k]
                    if workload in m.get("workloads", [workload])]
                   for k in ("end_to_end", "per_layer"))
-    return {"cell": cell,
-            "config": json.loads((root / cfg["file"]).read_text()),
+    config = json.loads((root / cfg["file"]).read_text())
+    return {"cell": cell, "config": config,
             "traffic": json.loads(
                 (BENCH / "traffic" / f"{cell['traffic']}.json").read_text()),
+            "module": rounds_module(config),
             "end_to_end": e2e, "per_layer": layer}
+
+
+def rounds_module(config: dict):
+    """``rounds/<name>.py`` for the configuration's ``rounds`` key,
+    ``hybrid`` where it has none: the round stream, the raylet call and
+    its tally, and the reference's and the program's answers."""
+    return importlib.import_module(
+        f".rounds.{config.get('rounds', 'hybrid')}", __package__)
+
+
+def counters(engine, crm, raylet) -> dict:
+    """Every numeric entry of the program's counters, by source: the
+    delta engine's ``stats``, the CRM's ``frozen_stats`` and the head
+    raylet's ``queue_stats()``."""
+    sources = {"engine": engine.stats if engine is not None else {},
+               "crm": crm.frozen_stats, "raylet": raylet.queue_stats()}
+    return {src: {k: v for k, v in d.items()
+                  if isinstance(v, (int, float)) and not isinstance(v, bool)}
+            for src, d in sources.items()}
 
 
 def warmup_rounds(traffic: dict) -> int:
@@ -164,16 +186,11 @@ def run_cell(loaded: dict, seed: int, seconds: float, trace: bool,
     clock = None
     trace_dir = None
     try:
-        rounds = Rounds(dep, config, traffic)
+        rounds = loaded["module"].Rounds(dep, config, traffic)
         churn = Churn(dep, traffic["dirty_request"],
                       traffic["dirty_ops_per_round"], seed)
         raylet = dep.raylet
-        if rounds.entry == "delta":
-            def call(payload):
-                return raylet._schedule_rows_delta(dep.class_vecs, payload)
-        else:
-            def call(payload):
-                return raylet._schedule_rows(payload[0])
+        call, tally = rounds.call, rounds.tally
 
         warm = warmup_rounds(traffic)
         for i in range(warm):
@@ -198,7 +215,7 @@ def run_cell(loaded: dict, seed: int, seconds: float, trace: bool,
             jax.profiler.start_trace(
                 trace_dir, profiler_options=_profile_options())
         seconds = min(seconds, TRACE_SECONDS) if trace else seconds
-        stats0 = dict(engine.stats) if engine is not None else {}
+        counts0 = counters(engine, dep.crm, raylet)
         sampler = Sampler(traffic["sample_rounds"], seed)
         setup_compiles = log.since(setup_mark)
         gc.collect()
@@ -225,12 +242,9 @@ def run_cell(loaded: dict, seed: int, seconds: float, trace: bool,
                 round_s.append(r1 - r0)
                 if clock:
                     beat_s.append(clock.total - in_beat)
-                if rounds.entry == "delta":
-                    offered += int(payload.sum())
-                    placed += int(out[:, :-1].sum())
-                else:
-                    offered += len(out)
-                    placed += len(out) - out.count(-1)
+                o, p = tally(payload, out)
+                offered += o
+                placed += p
                 if slot is not None:
                     sampler.put(slot, {
                         "index": j, "avail": avail, "out": out,
@@ -241,8 +255,11 @@ def run_cell(loaded: dict, seed: int, seconds: float, trace: bool,
         gc.unfreeze()
         if trace:
             jax.profiler.stop_trace()
-        stats = ({k: v - stats0.get(k, 0) for k, v in engine.stats.items()}
-                 if engine is not None else {})
+        # each counter's change over the window
+        window_counts = {src: {k: v - counts0[src].get(k, 0)
+                               for k, v in now.items()}
+                         for src, now in counters(engine, dep.crm,
+                                                  raylet).items()}
         memory_peak = _peak_bytes(used)
         record = {
             "cell": cell, "config": config, "traffic": traffic,
@@ -251,7 +268,7 @@ def run_cell(loaded: dict, seed: int, seconds: float, trace: bool,
             "round_s": round_s, "beat_s": beat_s,
             "beat_times": clock.times if clock else [],
             "offered": offered, "placed": placed,
-            "engine_stats": stats, "compiles": compiles,
+            "counters": window_counts, "compiles": compiles,
             "setup_compiles": {k: setup_compiles[k] for k in
                                ("compiles", "compile_s", "cache_hits",
                                 "cache_misses")},
@@ -261,6 +278,7 @@ def run_cell(loaded: dict, seed: int, seconds: float, trace: bool,
                        if engine is not None else None),
             "class_keys": dep.class_keys, "totals": dep.totals,
             "ref_vecs": dep.ref_vecs, "rounds": rounds,
+            "module": loaded["module"],
             "device": {"platform": used[0].platform,
                        "kind": used[0].device_kind, "count": len(devices),
                        "memory_peak_bytes": memory_peak},

@@ -10,7 +10,7 @@ from benchmark.roofline import beat_bytes
 
 def read(record):
     summary = record.get("trace_summary") or {}
-    stats = record["engine_stats"]
+    stats = record["counters"]["engine"]
     beats = stats.get("beats", 0)
     if not beats or not summary.get("kernel_s"):
         return None

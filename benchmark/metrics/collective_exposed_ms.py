@@ -5,7 +5,7 @@ that chip, from the trace, averaged over the chips."""
 
 def read(record):
     summary = record.get("trace_summary") or {}
-    beats = record["engine_stats"].get("beats", 0)
+    beats = record["counters"]["engine"].get("beats", 0)
     if record["cell"]["chips"] < 2 or not beats \
             or not summary.get("collective_ops"):
         return None

@@ -3,7 +3,8 @@ re-upload and rescore, from the engine's own ``stats``."""
 
 
 def read(record):
-    beats = record["engine_stats"].get("beats", 0)
+    stats = record["counters"]["engine"]
+    beats = stats.get("beats", 0)
     if not beats:
         return None
-    return 100.0 * (1 - record["engine_stats"]["full_rescores"] / beats)
+    return 100.0 * (1 - stats["full_rescores"] / beats)

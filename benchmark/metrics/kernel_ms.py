@@ -1,11 +1,13 @@
 """Kernels (``ops/hybrid_kernel.py``, ``ops/shard_reduce.py``): device
-time per beat of the programs the window runs, which are the beat's,
-from the profiler trace, averaged over the chips."""
+time per round of the programs the window runs, from the profiler trace,
+averaged over the chips.  Per round, not per beat, so that it reads in a
+cell whose rounds take no delta beat; where each round takes one beat,
+as in every cell of the hybrid rounds, the two are the same."""
 
 
 def read(record):
     summary = record.get("trace_summary") or {}
-    beats = record["engine_stats"].get("beats", 0)
-    if not beats or not summary.get("kernel_s"):
+    rounds = len(record["round_s"])
+    if not rounds or not summary.get("kernel_s"):
         return None
-    return summary["kernel_s"] / beats * 1e3
+    return summary["kernel_s"] / rounds * 1e3

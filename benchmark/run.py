@@ -73,6 +73,9 @@ def result_line(record: dict, loaded: dict) -> dict:
           f"{record['setup_compiles']} "
           f"engine {record['engine']}",
           file=sys.stderr)
+    if record["trace"]:
+        from benchmark import xtrace
+        print(f"reconcile {xtrace.reconcile(summary)}", file=sys.stderr)
     for name, c in checks.items():
         print(f"check {name} {c['value']} limit {c['limit']}",
               file=sys.stderr, flush=True)

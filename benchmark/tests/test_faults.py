@@ -44,6 +44,14 @@ def _failed(checks, record):
 def test_sound_runs_pass(workload):
     record, checks = _run(workload)
     assert check.passed(checks, record), checks
+    # the program's counters by source, each round one beat (so that
+    # ``kernel_ms`` per round is per beat)
+    counts = record["counters"]
+    assert counts["engine"]["beats"] == len(record["round_s"]) > 0
+    assert counts["crm"]["journal_scanned"] > 0
+    assert {"group_tasks", "group_probed"} <= set(counts["raylet"])
+    assert (counts["raylet"]["group_tasks"] > 0) == (
+        workload == "h256.arrivals")
     if workload == "envelope_2k.sharded4":
         assert record["engine"] == {"type": "ShardedDeltaScheduler",
                                     "shards": 4}
@@ -56,7 +64,7 @@ def test_engine_state_left_unchanged(workload, monkeypatch):
     for cls in (DeltaScheduler, ShardedDeltaScheduler):
         monkeypatch.setattr(cls, "_delta_sync", lambda self, *a, **kw: None)
     record, checks = _run(workload)
-    assert record["engine_stats"]["delta_beats"] > 0
+    assert record["counters"]["engine"]["delta_beats"] > 0
     assert _failed(checks, record), checks
 
 
@@ -132,7 +140,8 @@ def test_generator_record_matches_the_crm():
     """Sound runs: the generator's record is the CRM's state, column by
     column, after set-up and rounds with churn between them (a round
     plans; it books nothing in the CRM)."""
-    from benchmark.generator import Churn, Deployment, Rounds
+    from benchmark.generator import Churn, Deployment
+    from benchmark.rounds.hybrid import Rounds
     from ray_tpu.common.config import Config
     loaded = harness.load_cell("h256.arrivals")
     loaded["traffic"]["pool_batches"] = 1
@@ -162,3 +171,4 @@ def test_control_fails(workload):
     assert check.passed(checks, record), checks
     control = check.judge(record, produce=check.control)
     assert _failed(control, record), control
+    assert control["placement_cells_off"]["value"] > 0, control

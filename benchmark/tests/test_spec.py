@@ -1,5 +1,6 @@
 """``BENCHMARK.json`` against the contract's shape, and every name in it
-resolving to its file: configs, traffic mixes and metric readers."""
+resolving to its file: configs, their round modules, traffic mixes and
+metric readers."""
 
 import json
 import re
@@ -40,6 +41,8 @@ def test_top_level_and_entries():
             if "unit" in e:
                 assert UNIT.match(e["unit"]) and e["better"] in (
                     "lower", "higher")
+                assert e["source"] in ("device_trace", "program_span",
+                                       "program_counter", "host_clock")
 
 
 def test_bounds_and_sources():
@@ -53,7 +56,10 @@ def test_cell_resolves(cell):
     loaded = harness.load_cell(cell["name"])
     assert loaded["config"]["name"] == cell["config"]
     assert loaded["traffic"]["name"] == cell["traffic"]
-    assert loaded["config"]["reduced"] == []
+    assert loaded["config"]["reduced"] == {
+        c["name"]: c for c in SPEC["configs"]}[cell["config"]]["reduced"]
+    rounds = loaded["config"].get("rounds", "hybrid")
+    assert (harness.BENCH / "rounds" / f"{rounds}.py").exists()
     assert cell["chips"] in (1, 4)
     e2e = {m["name"] for m in loaded["end_to_end"]}
     assert e2e == {m["name"] for m in SPEC["end_to_end"]

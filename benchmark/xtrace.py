@@ -1,11 +1,15 @@
-"""From a profiler trace to the device's busy time, the beat's kernel time
-and its exposed collectives.
+"""From a profiler trace to the device's busy time, the beat's kernel time,
+its exposed collectives and the program's spans.
 
 ``load`` keeps of a JAX profiler trace (``*.xplane.pb``) only what the
 reduction reads: per device, the intervals of its XLA operations and of
-its XLA programs (modules); on the host, the harness's own spans (names
-that start with ``bench.``).  ``reduce`` turns that into numbers over the
-window that the host span ``bench.window`` marks:
+its XLA programs (modules); on the host, every span whose name follows
+the program's span rule, a lower-case dotted name (``SPAN_NAME``): the
+harness's own (``bench.*``) and the program's (``ray_tpu.util.tracing``
+``PHASES``, such as ``raylet.group`` and ``delta.upload``), so that a
+span a later change adds reaches the record as it is.  ``reduce`` turns
+that into numbers over the window that the host span ``bench.window``
+marks:
 
 * ``busy_s``: per chip, the union of the intervals in which a program
   or an operation runs on it (a program holds the chip between the
@@ -19,16 +23,27 @@ window that the host span ``bench.window`` marks:
   not count as running), averaged over the devices;
 * ``device_ops``: the ten operations that took most time, and
   ``idle_gaps``: the ten longest gaps between operations, each named by
-  what the harness was doing on the host then.
+  what the harness was doing on the host then and, after ``": "``, the
+  program's leaf span with most time in the gap, where one touches it;
+* ``program_spans``: per span (every one but ``bench.window`` and
+  ``bench.round``), its summed duration in each window round that holds
+  it, in seconds; a span belongs to the round in which it starts, and
+  rounds without it are left out;
+* ``idle_in_rounds_s``: per chip, the time inside ``bench.round`` in
+  which no program or operation runs on it, averaged over the chips, and
+  ``idle_explained_s``, the part of it that the union of the program's
+  leaf spans (those that hold no other program span) covers.
 """
 
 from __future__ import annotations
 
+import bisect
 import glob
 import gzip
 import json
 import os
 import re
+import statistics
 
 # op names as the TPU trace gives them: XLA's collective ops, and the
 # shard_map primitives they are lowered from (``%psum.53``, ``%pmin.31``)
@@ -41,6 +56,8 @@ MODULES_LINE = "XLA Modules"
 CONTAINERS = ("while", "conditional", "call")
 # chips, not the profiler's other "/device:" planes (e.g. CUSTOM:Megascale)
 CHIP_PLANE = re.compile(r"^/device:TPU:\d+$")
+# the program's span rule: lower-case words joined by dots
+SPAN_NAME = re.compile(r"^[a-z][a-z0-9_]*(\.[a-z][a-z0-9_]*)+$")
 
 
 def load(log_dir: str) -> dict:
@@ -66,7 +83,7 @@ def load(log_dir: str) -> dict:
             for line in plane.lines:
                 out["host"] += [[e.name, e.start_ns, e.duration_ns]
                                 for e in line.events
-                                if e.name.startswith("bench.")]
+                                if SPAN_NAME.match(e.name)]
     return out
 
 
@@ -118,6 +135,11 @@ def _minus(a, b):
     return out
 
 
+def _intersect(a, b):
+    """Merged intervals ``a`` within merged intervals ``b``."""
+    return _minus(a, _minus(a, b))
+
+
 def _overlap(intervals, lo, hi) -> float:
     return _length(_clip(intervals, lo, hi))
 
@@ -134,6 +156,67 @@ def _host_label(spans: dict, lo: float, hi: float) -> str:
                (churn, "churn between rounds"), (other, "harness"))[1]
 
 
+def _label(spans: dict, leaves: list, lo: float, hi: float) -> str:
+    """``_host_label`` of the gap, and the program's leaf span with most
+    time in it, where one touches it."""
+    label = _host_label(spans, lo, hi)
+    t, leaf = max(((_overlap(spans[n], lo, hi), n) for n in leaves),
+                  default=(0, None))
+    return f"{label}: {leaf}" if t > 0 else label
+
+
+def _leaves(spans: dict, names: list) -> list:
+    """Those of ``names`` whose spans hold no span of another of them."""
+    def holds(outer, inner):
+        starts = [s for s, _ in outer]
+        for s, e in inner:
+            i = bisect.bisect_right(starts, s) - 1
+            if i >= 0 and e <= outer[i][1]:
+                return True
+        return False
+    return [n for n in names
+            if not any(holds(spans[n], spans[m]) for m in names if m != n)]
+
+
+def _per_round(host: list, lo: float, hi: float, names) -> dict:
+    """{name: [summed duration in each window round holding it, s]}."""
+    rounds = sorted((s, s + d) for n, s, d in host
+                    if n == "bench.round" and lo <= s < hi)
+    starts = [s for s, _ in rounds]
+    sums: dict[str, dict[int, float]] = {}
+    for n, s, d in host:
+        if n not in names:
+            continue
+        i = bisect.bisect_right(starts, s) - 1
+        if i >= 0 and s < rounds[i][1]:
+            by = sums.setdefault(n, {})
+            by[i] = by.get(i, 0.0) + d * 1e-9
+    return {n: [by[i] for i in sorted(by)] for n, by in sums.items()}
+
+
+def span_ms(record: dict, name: str) -> float | None:
+    """Median over the traced window's rounds that hold span ``name`` of
+    its summed duration in the round, in ms; None where none holds it."""
+    summary = record.get("trace_summary") or {}
+    times = (summary.get("program_spans") or {}).get(name)
+    return statistics.median(times) * 1e3 if times else None
+
+
+def reconcile(summary: dict) -> dict:
+    """The program's spans against the harness's own timing over the
+    window, from ``reduce``'s ``program_spans``: summed ``delta.beat``
+    over summed ``bench.beat``, and the ``delta.*`` leaves summed over
+    ``delta.beat``; None where a sum is 0."""
+    tot = {n: sum(v) for n, v in
+           (summary.get("program_spans") or {}).items()}
+    beat = tot.get("delta.beat", 0.0)
+    leaves = sum(v for n, v in tot.items()
+                 if n.startswith("delta.") and n != "delta.beat")
+    return {"delta_beat_over_bench_beat":
+            beat / tot["bench.beat"] if tot.get("bench.beat") else None,
+            "leaves_over_delta_beat": leaves / beat if beat else None}
+
+
 def reduce(tr: dict) -> dict:
     """Numbers over the window marked by the host span ``bench.window``."""
     windows = [(s, s + d) for n, s, d in tr["host"] if n == "bench.window"]
@@ -148,8 +231,12 @@ def reduce(tr: dict) -> dict:
              if CHIP_PLANE.match(name) and (d["ops"] or d["modules"])]
     if not chips:
         return {}
+    leaves = _leaves(spans, sorted(n for n in spans
+                                   if not n.startswith("bench.")))
+    covered = _union([iv for n in leaves for iv in spans[n]])
+    rounds = _clip(spans.get("bench.round", []), lo, hi)
     n_dev = len(chips)
-    busy = kernel = exposed = 0.0
+    busy = kernel = exposed = idle_in = explained = 0.0
     op_time: dict[str, float] = {}
     gaps = []
     for dev in chips:
@@ -170,7 +257,11 @@ def reduce(tr: dict) -> dict:
             t = min(e, hi) - max(s, lo)
             if t > 0:
                 op_time[n] = op_time.get(n, 0.0) + t / n_dev
-        gaps += _minus([[lo, hi]], busy_iv)
+        idle = _minus([[lo, hi]], busy_iv)
+        gaps += idle
+        in_rounds = _intersect(idle, rounds)
+        idle_in += _length(in_rounds)
+        explained += _length(_intersect(in_rounds, covered))
     gaps.sort(key=lambda g: g[0] - g[1])
     ns = 1e-9
     return {
@@ -183,6 +274,11 @@ def reduce(tr: dict) -> dict:
                               and lo <= s < hi),
         "device_ops": [[n, t * ns] for n, t in
                        sorted(op_time.items(), key=lambda x: -x[1])[:10]],
-        "idle_gaps": [[_host_label(spans, s, e), (e - s) * ns]
+        "idle_gaps": [[_label(spans, leaves, s, e), (e - s) * ns]
                       for s, e in gaps[:10]],
+        "program_spans": _per_round(
+            tr["host"], lo, hi,
+            set(spans) - {"bench.window", "bench.round"}),
+        "idle_in_rounds_s": idle_in / n_dev * ns,
+        "idle_explained_s": explained / n_dev * ns,
     }
