@@ -157,6 +157,29 @@ def _schedule_group(avail, totals, node_mask, req, count, gmask, thr_fp,
     return counts_row, new_avail
 
 
+def _localized_group(avail, totals, node_mask, req, count, gmask, pref,
+                     thr_fp, require_available=False):
+    """``_schedule_group`` with soft locality: a FEASIBLE preferred row
+    (``pref`` >= 0) takes every task of the group (they queue there) and
+    consumes ``min(count, cap)`` of its availability; an infeasible one
+    leaves the whole group to the water-fill.  Bit-identical to the host
+    sequence of NodeAffinity-soft placements (``schedule_group_host``
+    with ``pref_row``).  Returns (counts_row (N+1,), new_avail)."""
+    n = totals.shape[0]
+    p = jnp.clip(pref, 0, n - 1)
+    req_pos = req > 0
+    feas_p = jnp.all(jnp.where(req_pos, totals[p] >= req, True)) \
+        & node_mask[p] & gmask[p]
+    m = jnp.where((pref >= 0) & feas_p, count, 0).astype(jnp.int32)
+    cap_p = jnp.where(req_pos, avail[p] // jnp.maximum(req, 1), _BIG).min()
+    consumed = jnp.minimum(m, jnp.clip(cap_p, 0, _BIG))
+    avail2 = avail.at[p].add(-req * consumed)
+    rest, avail3 = _schedule_group(avail2, totals, node_mask, req,
+                                   count - m, gmask, thr_fp,
+                                   require_available)
+    return rest.at[p].add(m), avail3
+
+
 @partial(jax.jit, static_argnames=("unroll", "require_available"))
 def schedule_grouped(totals, avail, node_mask, group_reqs, group_counts,
                      group_masks, thr_fp, unroll: int = 1,
@@ -375,7 +398,8 @@ def apply_dirty_classes(totals, avail, mask, keys, reqs, idx, class_reqs,
 @partial(jax.jit, static_argnames=("require_available",))
 def fused_beat(totals, avail, mask, keys, reqs, class_slots, group_counts,
                extra_mask, ov_idx, ov_avail, thr_fp,
-               require_available=False):
+               require_available=False, pref_rows=None, avoid=None,
+               local_row=None):
     """One heartbeat against the resident mirror: per-beat ephemeral row
     overrides (the raylet's planned-load debits), an extra soft mask
     (suspect avoidance), the grouped water-fill, and the per-class argmin
@@ -389,6 +413,14 @@ def fused_beat(totals, avail, mask, keys, reqs, class_slots, group_counts,
     class_slots: (G,) int32 slots into ``reqs``.  ov_idx/ov_avail:
     (B,) int32 rows + (B, R) int32 replacement avail rows applied for
     this beat only (padding idx == N; the resident mirror is untouched).
+
+    The localized variant (``pref_rows`` given; a program of its own,
+    the plain beat's is unchanged): pref_rows (G,) int32 preferred row
+    per group (-1 = none), avoid (G,) bool and the int32 scalar
+    ``local_row``.  Each step is ``_localized_group``: a feasible
+    preferred row takes the whole group, an avoid group keeps off
+    ``local_row``, and avoid wins over a preferred row.
+
     Returns (packed (G + C, N+1) int32 — rows [:G] are the water-fill
     counts with the overflow column, rows [G:] the per-class lease
     budgets (zero overflow column) — and argmin_rows (C,) int32)."""
@@ -398,13 +430,28 @@ def fused_beat(totals, avail, mask, keys, reqs, class_slots, group_counts,
     n = totals.shape[0]
     ones = jnp.ones((n,), bool)
 
-    def step(av, xs):
-        req, count = xs
-        row, new_av = _schedule_group(av, totals, mask_eff, req, count,
-                                      ones, thr_fp, require_available)
-        return new_av, row
+    if pref_rows is None:
+        def step(av, xs):
+            req, count = xs
+            row, new_av = _schedule_group(av, totals, mask_eff, req, count,
+                                          ones, thr_fp, require_available)
+            return new_av, row
 
-    av_fin, counts = jax.lax.scan(step, avail_eff, (group_reqs, group_counts))
+        av_fin, counts = jax.lax.scan(step, avail_eff,
+                                      (group_reqs, group_counts))
+    else:
+        node_rows = jnp.arange(n, dtype=jnp.int32)
+
+        def step(av, xs):
+            req, count, pref, avd = xs
+            gmask = ~(avd & (node_rows == local_row))
+            row, new_av = _localized_group(
+                av, totals, mask_eff, req, count, gmask,
+                jnp.where(avd, -1, pref), thr_fp, require_available)
+            return new_av, row
+
+        av_fin, counts = jax.lax.scan(
+            step, avail_eff, (group_reqs, group_counts, pref_rows, avoid))
 
     # Lease budgets off the post-beat avail.  Clamp >= 0 before the floor
     # division (contract: numpy and XLA disagree on negative ``//``), and

@@ -34,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .hybrid_kernel import (_BIG, _INF_KEY, _keys_one_req,
-                            _schedule_group)
+                            _localized_group)
 
 
 @jax.jit
@@ -49,26 +49,12 @@ def schedule_grouped_localized(totals, avail, node_mask, group_reqs,
     Returns (counts (G, N+1), new_avail)."""
     if extra_mask is not None:
         node_mask = node_mask & extra_mask
-    n = totals.shape[0]
 
     def step(avail, xs):
         req, count, gmask, pref = xs
-        has_pref = pref >= 0
-        p = jnp.clip(pref, 0, n - 1)
-        req_pos = req > 0
-        feas_p = jnp.all(jnp.where(req_pos, totals[p] >= req, True)) \
-            & node_mask[p] & gmask[p]
-        # host NodeAffinity-soft semantics: a FEASIBLE preferred node
-        # takes every task (they queue there); availability only gates
-        # how much is consumed.  Fallback fires only when infeasible.
-        m = jnp.where(has_pref & feas_p, count, 0).astype(jnp.int32)
-        cap_p = jnp.where(req_pos, avail[p] // jnp.maximum(req, 1),
-                          _BIG).min()
-        consumed = jnp.minimum(m, jnp.clip(cap_p, 0, _BIG))
-        avail2 = avail.at[p].add(-req * consumed)
-        rest, avail3 = _schedule_group(avail2, totals, node_mask, req,
-                                       count - m, gmask, thr_fp, False)
-        return avail3, rest.at[p].add(m)
+        row, new_avail = _localized_group(avail, totals, node_mask, req,
+                                          count, gmask, pref, thr_fp)
+        return new_avail, row
 
     new_avail, counts = jax.lax.scan(
         step, avail, (group_reqs, group_counts, group_masks, pref_rows))

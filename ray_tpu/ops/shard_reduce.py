@@ -378,13 +378,18 @@ class ShardPlane:
 
     def fused_beat(self, totals, avail, mask, keys, reqs, class_slots,
                    group_counts, extra_mask, ov_idx, ov_avail, thr_fp,
-                   require_available=False):
-        """One sharded heartbeat (``fused_program``) on the residents."""
-        return self.fused_program(require_available)(
+                   require_available=False, pref_rows=None, avoid=None,
+                   local_row=-1):
+        """One sharded heartbeat (``fused_program``) on the residents;
+        with ``pref_rows`` (replicated (G,) int32) and ``avoid``
+        (replicated (G,) bool) the localized variant."""
+        loc = () if pref_rows is None else (pref_rows, avoid,
+                                            jnp.int32(local_row))
+        return self.fused_program(require_available, bool(loc))(
             totals, avail, mask, keys, reqs, class_slots, group_counts,
-            extra_mask, ov_idx, ov_avail, jnp.int32(thr_fp))
+            extra_mask, ov_idx, ov_avail, jnp.int32(thr_fp), *loc)
 
-    def fused_program(self, require_available=False):
+    def fused_program(self, require_available=False, localized=False):
         """The jitted sharded heartbeat: per-shard ephemeral overrides + soft
         mask, the G-class water-fill scan with two-level collectives,
         and the carried-key argmin via the ICI->DCN pmin reduce.  Each
@@ -392,28 +397,27 @@ class ShardPlane:
         from the scan's final avail carry (contract.compute_budgets
         twin) — a purely node-local map, so sharding it is exact.
 
+        ``localized`` compiles the variant that takes three more inputs,
+        per-group preferred rows and avoid flags and the local row
+        (``hybrid_kernel.fused_beat``'s semantics): the shard that owns
+        a preferred row reads its totals and avail, one cross-chip sum
+        per group hands them to every shard, and only the owner debits
+        the row; an avoid flag masks the local row on its owner only.
+
         Returns (packed (G + C, N+1) int32 REPLICATED — rows [:G] the
         water-fill counts + overflow column, rows [G:] the lease
         budgets — and amin (C,) int32 replicated); the host's single
         fetch reads one buffer, the cross-device gather happened on the
         interconnect."""
-        key = bool(require_available)
+        key = (bool(require_available), bool(localized))
         if key not in self._fused:
             P = self._P
             shape = self._shape
             n_lin = self.n_shards
-            req_av = key
+            req_av = key[0]
 
-            def body(t_l, a_l, m_l, k_l, reqs, slots, counts, em_l,
-                     ovi_l, ova_l, thr):
-                n_l = t_l.shape[0]
-                my_lin = _shard_linear_index(shape)
-                offset = (my_lin * n_l).astype(jnp.int32)
-                a_eff = a_l.at[ovi_l].set(ova_l, mode="drop")
-                m_eff = m_l & em_l
-                group_reqs = reqs[jnp.clip(slots, 0,
-                                           reqs.shape[0] - 1)]
-
+            def scan_plain(a_eff, t_l, m_eff, group_reqs, counts, thr,
+                           offset, my_lin):
                 def step(av_l, xs):
                     req, count = xs
                     row_l, inf_c, new_av_l = _schedule_group_sharded(
@@ -421,8 +425,63 @@ class ShardPlane:
                         my_lin, n_lin, req_av)
                     return new_av_l, (row_l, inf_c)
 
-                av_fin, (alloc, inf) = jax.lax.scan(
-                    step, a_eff, (group_reqs, counts))
+                return jax.lax.scan(step, a_eff, (group_reqs, counts))
+
+            def scan_localized(a_eff, t_l, m_eff, group_reqs, counts, thr,
+                               offset, my_lin, prefs, avoids, local_row):
+                n_l = t_l.shape[0]
+                rows_g = offset + jnp.arange(n_l, dtype=jnp.int32)
+
+                def step(av_l, xs):
+                    req, count, pref, avd = xs
+                    gm_l = m_eff & ~(avd & (rows_g == local_row))
+                    pref = jnp.where(avd, -1, pref)
+                    lp = pref - offset
+                    mine = (pref >= 0) & (lp >= 0) & (lp < n_l)
+                    lpc = jnp.clip(lp, 0, n_l - 1)
+                    # the owner's row, summed over shards: totals, avail
+                    # and its mask bit in one collective
+                    got = _psum2(jnp.where(mine, jnp.concatenate(
+                        [t_l[lpc], av_l[lpc],
+                         gm_l[lpc].astype(jnp.int32)[None]]), 0))
+                    r = t_l.shape[1]
+                    t_p, a_p, ok_p = got[:r], got[r:2 * r], got[2 * r]
+                    req_pos = req > 0
+                    feas_p = jnp.all(jnp.where(req_pos, t_p >= req, True)) \
+                        & (ok_p > 0)
+                    m = jnp.where((pref >= 0) & feas_p, count, 0).astype(
+                        jnp.int32)
+                    cap_p = jnp.where(req_pos, a_p // jnp.maximum(req, 1),
+                                      _BIG).min()
+                    consumed = jnp.minimum(m, jnp.clip(cap_p, 0, _BIG))
+                    at = jnp.where(mine, lpc, n_l)
+                    av2_l = av_l.at[at].add(-req * consumed, mode="drop")
+                    row_l, inf_c, new_av_l = _schedule_group_sharded(
+                        av2_l, t_l, gm_l, req, count - m, thr, offset,
+                        my_lin, n_lin, req_av)
+                    row_l = row_l.at[at].add(m, mode="drop")
+                    return new_av_l, (row_l, inf_c)
+
+                return jax.lax.scan(step, a_eff,
+                                    (group_reqs, counts, prefs, avoids))
+
+            def body(t_l, a_l, m_l, k_l, reqs, slots, counts, em_l,
+                     ovi_l, ova_l, thr, *loc):
+                n_l = t_l.shape[0]
+                my_lin = _shard_linear_index(shape)
+                offset = (my_lin * n_l).astype(jnp.int32)
+                a_eff = a_l.at[ovi_l].set(ova_l, mode="drop")
+                m_eff = m_l & em_l
+                group_reqs = reqs[jnp.clip(slots, 0,
+                                           reqs.shape[0] - 1)]
+                if loc:
+                    av_fin, (alloc, inf) = scan_localized(
+                        a_eff, t_l, m_eff, group_reqs, counts, thr,
+                        offset, my_lin, *loc)
+                else:
+                    av_fin, (alloc, inf) = scan_plain(
+                        a_eff, t_l, m_eff, group_reqs, counts, thr,
+                        offset, my_lin)
 
                 # shard-local lease budgets off the post-beat avail
                 # (clamped >= 0 before ``//`` — contract.compute_budgets)
@@ -456,14 +515,16 @@ class ShardPlane:
                           P(None, ("dcn", "ici")), P(), P(), P(),
                           P(("dcn", "ici")),
                           P(("dcn", "ici")),
-                          P(("dcn", "ici"), None), P()),
+                          P(("dcn", "ici"), None), P())
+                + (P(), P(), P()) * key[1],
                 out_specs=(P(None, ("dcn", "ici")), P(),
                            P(None, ("dcn", "ici")), P()))
 
             def wrapper(t, a, m, k, reqs, slots, counts, em, ovi, ova,
-                        thr):
+                        thr, *loc):
                 alloc, inf, budgets, amin = smapped(
-                    t, a, m, k, reqs, slots, counts, em, ovi, ova, thr)
+                    t, a, m, k, reqs, slots, counts, em, ovi, ova, thr,
+                    *loc)
                 return (jnp.concatenate(
                     [jnp.concatenate([alloc, inf[:, None]], axis=1),
                      jnp.pad(budgets, ((0, 0), (0, 1)))], axis=0), amin)

@@ -96,6 +96,10 @@ class _ClassQueue:
             yield from list(dq)
 
 
+_AVOID = -2     # ``_locality_rows``: the spec's avoid flag is set
+_PROBE = object()   # ``_subgroup``: the batch needs the locality probe
+
+
 class _Subgroups(NamedTuple):
     """A round's (scheduling class, locality row, avoid flag) subgroups
     in order of first appearance (``Raylet._subgroup``)."""
@@ -530,10 +534,17 @@ class Raylet:
         # take the one subgrouping below, so scheduler_device_batch_min
         # is not observable in placements (top-k rounds excepted —
         # documented sampling divergence)
+        locs = None
         with phase("raylet.group"):
             specs = [rec.spec for rec in batch]
             sub = (self._subgroup(specs) if cfg.scheduler_device_backend
                    else None)
+        if sub is _PROBE:
+            # the locality probe, one leaf beside the grouping pass
+            with phase("raylet.locality"):
+                locs = self._locality_rows(specs)
+            with phase("raylet.group"):
+                sub = self._subgroup(specs, locs)
         if sub is not None:
             if (len(batch) >= cfg.scheduler_device_batch_min
                     and len(sub.first) <= cfg.tpu_group_capacity):
@@ -556,10 +567,28 @@ class Raylet:
                 rows[t] = self._policy.schedule(
                     snapshot, req,
                     self._options_for(specs[t],
-                                      snapshot.node_mask.shape[0]))
+                                      snapshot.node_mask.shape[0],
+                                      None if locs is None
+                                      else locs.get(id(specs[t]), -1)))
         return rows
 
-    def _subgroup(self, specs: list) -> _Subgroups | None:
+    def _locality_rows(self, specs: list) -> dict:
+        """The round's locality probe, one pass before grouping:
+        {id(spec): ``_AVOID``} for a spec whose avoid flag is set (read,
+        not consumed), {id(spec): its locality row, -1 for none} for a
+        DEFAULT-strategy spec with args; other specs are not probed."""
+        default = SchedulingStrategyKind.DEFAULT
+        avoid_set = self._avoid_local
+        locs: dict[int, int] = {}
+        for s in specs:
+            if avoid_set and s.task_id in avoid_set:
+                locs[id(s)] = _AVOID
+            elif s.args and s.strategy.kind is default:
+                row = self._locality_row(s)
+                locs[id(s)] = -1 if row is None else row
+        return locs
+
+    def _subgroup(self, specs: list, locs: dict | None = None):
         """The round's one grouping pass, shared by the device path and
         its host twin: each task's subgroup, keyed (scheduling class,
         locality row, avoid flag) in first-appearance order.  None when
@@ -570,10 +599,11 @@ class Raylet:
         built once per strategy object in the round (most specs share
         ``DEFAULT_STRATEGY``).  Only a spec with args can have a
         locality row and only a non-empty avoid set can flag one, so
-        the plain key skips both probes; ``group_probed`` counts the
-        tasks that took them."""
+        the plain key skips both.  Without ``locs`` (the round's probe,
+        ``_locality_rows``) the pass stops at the first spec that needs
+        one and returns ``_PROBE``; ``group_probed`` counts the tasks
+        the probe flagged or gave a row to look up."""
         default = SchedulingStrategyKind.DEFAULT
-        avoid_set = self._avoid_local
         strategy_slot: dict[int, int] = {}     # id(strategy) -> slot
         strategy_keys: dict[tuple, int] = {}   # strategy key -> slot
         groups: dict[tuple, int] = {}
@@ -582,6 +612,7 @@ class Raylet:
         avoids: list[bool] = []
         task_group: list[int] = []
         avoided: list[TaskID] = []
+        avoid_set = self._avoid_local
         probed = 0
         prev, slot = None, 0
         for s in specs:
@@ -597,17 +628,18 @@ class Raylet:
             # the plain key (class, slot): no locality row, no flag
             key = (s.resources.key(), slot)
             if s.args or avoid_set:
-                if avoid_set and s.task_id in avoid_set:
-                    # locality skipped: the local data node is exactly
-                    # what starved the task
+                if locs is None:
+                    return _PROBE
+                loc = locs.get(id(s))
+                if loc is not None:
                     probed += 1
-                    avoided.append(s.task_id)
-                    key = (*key, -1, True)
-                elif s.args:
-                    probed += 1
-                    row = self._locality_row(s)
-                    if row is not None:
-                        key = (*key, int(row), False)
+                    if loc == _AVOID:
+                        # locality skipped: the local data node is
+                        # exactly what starved the task
+                        avoided.append(s.task_id)
+                        key = (*key, -1, True)
+                    elif loc >= 0:
+                        key = (*key, int(loc), False)
             g = groups.get(key)
             if g is None:
                 g = groups[key] = len(first)
@@ -720,13 +752,15 @@ class Raylet:
             pref_arr[:G] = sub.prefs
             top_k = cfg.scheduler_top_k_fraction
             plain = (pref_arr < 0).all() and not any(sub.avoids)
-        if plain and top_k == 0 and not cfg.scheduler_sharded_state \
+        if top_k == 0 and not cfg.scheduler_sharded_state \
                 and cfg.scheduler_delta_beats:
             # incremental heartbeat: no snapshot copy, no full upload —
             # the resident mirror syncs from the CRM dirty journal and
-            # planned load rides along as per-beat avail overrides
+            # planned load rides along as per-beat avail overrides;
+            # locality rows and avoid flags ride along per group
+            loc = () if plain else (sub.prefs, sub.avoids, self.row)
             counts_host = self._schedule_rows_delta(req_arr[:G],
-                                                    cnt_arr[:G])
+                                                    cnt_arr[:G], *loc)
         else:
             snapshot = self._effective_snapshot()
             totals, avail, mask = (snapshot.totals, snapshot.avail,
@@ -769,15 +803,19 @@ class Raylet:
         with phase("raylet.expand"):
             return _expand(sub.task_group, counts_host)
 
-    def _schedule_rows_delta(self, req_arr, cnt_arr) -> "np.ndarray":
+    def _schedule_rows_delta(self, req_arr, cnt_arr, prefs=None,
+                             avoids=None, local_row: int = -1
+                             ) -> "np.ndarray":
         """The fused delta-heartbeat placement call
         (scheduling.policy.DeltaScheduler): the device mirror syncs
         incrementally from the CRM's dirty journal; this node's view of
         planned-but-undispatched load rides along as per-beat avail
         overrides and suspect rows as a per-beat soft mask — both with
         the exact ``_effective_snapshot`` arithmetic, so placements are
-        bit-identical to the snapshot path.  One counts readback per
-        beat.  Returns (G, N+1) int32 counts.
+        bit-identical to the snapshot path.  Per-group locality rows
+        (``prefs``) and avoid flags (``avoids``, keeping a group off
+        ``local_row``) take the beat's localized program.  One counts
+        readback per beat.  Returns (G, N+1) int32 counts.
 
         The engine comes from ``make_delta_scheduler``: with
         ``scheduler_shards`` resolving past one chip the mirror and the
@@ -813,7 +851,8 @@ class Raylet:
                 base[:w] = (base[:w] - planned[:w]).clip(-(2**30), 2**30)
                 overrides[row] = base.astype(np.int32)
         counts = eng.beat(req_arr, cnt_arr, overrides=overrides,
-                          extra_mask=extra)
+                          extra_mask=extra, prefs=prefs, avoids=avoids,
+                          local_row=local_row)
         cfg = get_config()
         if cfg.lease_plane_enabled and cfg.lease_budget_source == "beat":
             with phase("raylet.publish"):
@@ -1012,7 +1051,10 @@ class Raylet:
         # max bytes, lowest row on ties (deterministic)
         return min(by_row, key=lambda r: (-by_row[r], r))
 
-    def _options_for(self, spec, n_rows: int) -> SchedulingOptions:
+    def _options_for(self, spec, n_rows: int,
+                     loc: int | None = None) -> SchedulingOptions:
+        """``loc``: the spec's locality row from the round's probe
+        (``_locality_rows``; -1 none), None to probe here."""
         kind = spec.strategy.kind
         if kind is SchedulingStrategyKind.DEFAULT:
             if spec.task_id in self._avoid_local:
@@ -1022,8 +1064,8 @@ class Raylet:
                 self._avoid_local.discard(spec.task_id)
                 return SchedulingOptions(avoid_local_node=True,
                                          local_node_row=self.row)
-            row = self._locality_row(spec)
-            if row is not None:
+            row = self._locality_row(spec) if loc is None else loc
+            if row is not None and row >= 0:
                 # soft affinity: land on the max-local-bytes node when it
                 # can take the task, hybrid otherwise
                 return SchedulingOptions(

@@ -288,14 +288,18 @@ class DeltaScheduler:
         # tell "new beat" from "same beat re-read"
         self._budgets_host: np.ndarray | None = None
         self._budget_seq = 0
+        # groups: real groups placed; pref_groups/avoid_groups: of
+        # those, the groups with a preferred row / an avoid flag
         self.stats = {"beats": 0, "delta_beats": 0, "full_rescores": 0,
                       "clean_beats": 0, "rows_uploaded": 0,
-                      "classes_installed": 0}
+                      "classes_installed": 0, "groups": 0,
+                      "pref_groups": 0, "avoid_groups": 0}
 
     # -- public surface -----------------------------------------------------
     def beat(self, group_reqs, group_counts, overrides=None,
              extra_mask=None, require_available: bool = False,
-             spread_threshold: float | None = None) -> np.ndarray:
+             spread_threshold: float | None = None, prefs=None,
+             avoids=None, local_row: int = -1) -> np.ndarray:
         """Sync the mirror, schedule G classes, return (G, n+1) int32
         counts (column n = infeasible/queued-nowhere), matching
         ``hybrid_kernel.schedule_grouped`` on a fresh CRM snapshot.
@@ -304,6 +308,13 @@ class DeltaScheduler:
         only (the raylet's planned-load debits).  ``extra_mask``: host
         bool (n,) soft mask ANDed into the placement mask for this beat
         (suspect avoidance) — the carried key tensor ignores it.
+
+        ``prefs`` (G,) preferred node row per group (-1 = none) and
+        ``avoids`` (G,) avoid flags keeping a group off ``local_row``
+        (avoid wins over a preferred row): a beat with any of them runs
+        the localized program (``schedule_grouped_localized``'s
+        semantics), one without runs the plain one.  Groups may share a
+        class.
         """
         with phase("delta.beat"):
             with phase("delta.view"):
@@ -354,6 +365,7 @@ class DeltaScheduler:
                 counts_p = np.zeros((gp,), np.int32)
                 counts_p[:g] = np.asarray(group_counts, np.int32)
 
+                loc = self._pack_locality(gp, g, prefs, avoids, local_row)
                 ov = self._pack_overrides(overrides)
                 if extra_mask is None:
                     em = self._ones
@@ -364,7 +376,7 @@ class DeltaScheduler:
 
             with phase("delta.dispatch"):
                 counts_d, amin_d = self._fused_call(
-                    slots_p, counts_p, em, ov, thr, require_available)
+                    slots_p, counts_p, em, ov, thr, require_available, loc)
                 self._last_amin = amin_d
             with phase("delta.readback"):
                 # the one sanctioned host<-device readback of the beat:
@@ -447,16 +459,23 @@ class DeltaScheduler:
         return jax.device_put(emp)
 
     def _fused_call(self, slots_p, counts_p, em, ov, thr,
-                    require_available):
-        """The fused schedule->argmin device call; returns
+                    require_available, loc=None):
+        """The fused schedule->argmin device call (the localized program
+        when ``loc`` = (prefs, avoids, local_row) is given); returns
         (counts_device (G, n+1), amin_device (C,))."""
         import jax
 
         from ..ops import hybrid_kernel as hk
+        kw = {}
+        if loc is not None:
+            prefs, avoids, local_row = loc
+            kw = {"pref_rows": jax.device_put(prefs),
+                  "avoid": jax.device_put(avoids),
+                  "local_row": np.int32(local_row)}
         return hk.fused_beat(
             self._totals, self._avail, self._mask, self._keys, self._reqs,
             jax.device_put(slots_p), jax.device_put(counts_p), em,
-            ov[0], ov[1], thr, require_available=require_available)
+            ov[0], ov[1], thr, require_available=require_available, **kw)
 
     def _put_state(self, ht, ha, hm):
         """Place the padded mirror arrays (+ the resident all-true
@@ -579,6 +598,27 @@ class DeltaScheduler:
                     vecs[j, :vec.shape[0]] = vec
                 self._install_classes(idx, vecs, thr)
         return slots
+
+    def _pack_locality(self, gp, g, prefs, avoids, local_row):
+        """(prefs, avoids, local_row) padded to ``gp`` groups, or None
+        when no group has a preferred row or an avoid flag (the plain
+        program); counts the beat's groups in ``stats``."""
+        self.stats["groups"] += g
+        if prefs is None and avoids is None:
+            return None
+        pref_p = np.full((gp,), -1, np.int32)
+        avoid_p = np.zeros((gp,), bool)
+        if prefs is not None:
+            pref_p[:g] = prefs
+        if avoids is not None:
+            avoid_p[:g] = avoids
+        n_pref = int(((pref_p >= 0) & ~avoid_p).sum())
+        n_avoid = int(avoid_p.sum())
+        if not n_pref and not n_avoid:
+            return None
+        self.stats["pref_groups"] += n_pref
+        self.stats["avoid_groups"] += n_avoid
+        return pref_p, avoid_p, int(local_row)
 
     def _pack_overrides(self, overrides):
         import jax

@@ -113,14 +113,20 @@ class ShardedDeltaScheduler(DeltaScheduler):
         return jax.device_put(emp, self._plane.sh_vec)
 
     def _fused_call(self, slots_p, counts_p, em, ov, thr,
-                    require_available):
+                    require_available, loc=None):
         import jax
         pl = self._plane
+        kw = {}
+        if loc is not None:
+            prefs, avoids, local_row = loc
+            kw = {"pref_rows": jax.device_put(prefs, pl.sh_repl),
+                  "avoid": jax.device_put(avoids, pl.sh_repl),
+                  "local_row": local_row}
         return pl.fused_beat(
             self._totals, self._avail, self._mask, self._keys,
             self._reqs, jax.device_put(slots_p, pl.sh_repl),
             jax.device_put(counts_p, pl.sh_repl), em, ov[0], ov[1],
-            thr, require_available=require_available)
+            thr, require_available=require_available, **kw)
 
     # -- per-shard staging --------------------------------------------------
     def _shard_buckets(self, rows):

@@ -15,7 +15,11 @@ Separately, ``phase(name)`` marks a phase of the placement round on the
 profiler's own clock (``jax.profiler.TraceAnnotation``), so that a
 profiler trace puts every idle gap of the device beside the host phase
 that held it.  The names are ``PHASES``; docs/architecture.md says what
-each covers.
+each covers.  A span's name is lower-case and dotted (``layer.phase``:
+two or more parts, each a lower-case letter then lower-case letters,
+digits or ``_``), the rule the benchmark's trace reader keeps host events by
+(``benchmark/xtrace.py`` ``SPAN_NAME``), so a new span needs no reader
+of its own to reach the record.
 """
 
 from __future__ import annotations
@@ -32,8 +36,8 @@ _local = threading.local()      # driver-side ambient context
 # the raylet round's host work around the beat.
 PHASES = ("delta.beat", "delta.view", "delta.upload", "delta.stage",
           "delta.dispatch", "delta.readback",
-          "raylet.group", "raylet.overrides", "raylet.expand",
-          "raylet.publish")
+          "raylet.group", "raylet.locality", "raylet.overrides",
+          "raylet.expand", "raylet.publish")
 
 _NO_SPAN = contextlib.nullcontext()
 _annotation = None      # jax.profiler.TraceAnnotation, once JAX is loaded

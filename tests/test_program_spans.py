@@ -123,6 +123,68 @@ def test_raylet_round_emits_its_spans(tmp_path):
         assert names.count(name) == 1, name
 
 
+def test_locality_round_emits_the_probe_span(tmp_path):
+    from ray_tpu.cluster_utils import Cluster
+    from ray_tpu.common.config import Config
+    from ray_tpu.common.ids import JobID, ObjectID, TaskID
+    from ray_tpu.common.resources import ResourceRequest
+    from ray_tpu.common.task_spec import TaskSpec, TaskType
+    from ray_tpu.runtime.object_ref import ObjectRef
+    from ray_tpu.runtime.task_manager import TaskRecord
+
+    Config.reset({"scheduler_device_batch_min": 2,
+                  "health_check_period_ms": 3_600_000})
+    cluster = Cluster()
+    try:
+        for i in range(7):
+            cluster.add_node(resources={"CPU": 4 + 4 * (i == 0),
+                                        "memory": 8}, num_workers=0)
+        raylet = cluster.head()
+        refs = []
+        for row in (1, 4):
+            oid = ObjectID.from_random()
+            raylet.store.put_remote(oid, 1 << 20)
+            cluster.directory.add_location(oid, row)
+            refs.append(ObjectRef(oid))
+        job = JobID.from_int(1)
+
+        def batch():
+            return [TaskRecord(TaskSpec(
+                task_id=TaskID.for_task(job), job_id=job,
+                task_type=TaskType.NORMAL_TASK,
+                function_descriptor="spans:task",
+                args=(refs[i % 2],) if i % 3 else (),
+                resources=ResourceRequest({"CPU": 1 + i % 2})), 0, [])
+                for i in range(12)]
+
+        raylet._schedule_rows(batch())      # compiles outside the trace
+        with jax.profiler.trace(str(tmp_path)):
+            rows = raylet._schedule_rows(batch())
+        assert all(r >= 0 for r in rows)
+        assert raylet._delta_engine.stats["pref_groups"] > 0
+    finally:
+        cluster.stop()
+    events = _program_spans(tmp_path)
+    names = [n for n, _, _ in events]
+    # the probe splits the grouping pass: one more interval of
+    # raylet.group, and the probe a leaf beside it
+    assert names.count("raylet.locality") == 1
+    assert names.count("raylet.group") == 3
+    (_, lo, hi), = [e for e in events if e[0] == "raylet.locality"]
+    assert not [n for n, s, e in events
+                if n != "raylet.locality" and (lo <= s < hi or s <= lo < e)]
+    for name in ("raylet.overrides", "raylet.expand", "raylet.publish",
+                 "delta.beat"):
+        assert names.count(name) == 1, name
+
+
+def test_phase_names_follow_the_span_rule():
+    from benchmark.xtrace import SPAN_NAME
+    assert len(set(PHASES)) == len(PHASES)
+    for name in PHASES:
+        assert SPAN_NAME.match(name), name
+
+
 def test_phase_imports_no_jax():
     code = ("import sys\n"
             "from ray_tpu.util.tracing import PHASES, phase\n"

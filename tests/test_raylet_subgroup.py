@@ -1,4 +1,5 @@
-"""The raylet round's one subgrouping pass (``Raylet._subgroup``) and its
+"""The raylet round's one subgrouping pass (``Raylet._subgroup``, fed by
+the round's locality probe ``Raylet._locality_rows``) and its
 vectorised expansion (``raylet._expand``), pinned to the straightforward
 per-task loops they replace.
 
@@ -149,7 +150,7 @@ def test_subgroup_pass_matches_per_task_loops(cluster, make):
     specs, avoid = make(cluster, rng)
     _set_avoid(raylet, avoid)
     before = raylet.queue_stats()
-    sub = raylet._subgroup(specs)
+    sub = raylet._subgroup(specs, raylet._locality_rows(specs))
     after = raylet.queue_stats()
     default = all(s.strategy.kind is SchedulingStrategyKind.DEFAULT
                   for s in specs)
