@@ -17,9 +17,14 @@ objects transfer through the object manager).
 from __future__ import annotations
 
 import threading
+from itertools import chain, repeat
 from typing import Iterable
 
 from ..common.ids import ObjectID
+
+# ids looked up per hold of a lock in a batched read, so a concurrent
+# writer waits for one slice (well under a millisecond) at most
+LOOKUP_SLICE = 2048
 
 
 class ObjectDirectory:
@@ -34,6 +39,23 @@ class ObjectDirectory:
     def locations(self, object_id: ObjectID) -> tuple[int, ...]:
         with self._lock:
             return tuple(sorted(self._locs.get(object_id, ())))
+
+    def location_rows(self, object_ids: list):
+        """``locations`` of many ids in one pass, unsorted: (each id's
+        number of locations, int64; their rows back to back in id order,
+        int64).  The lock is taken once per ``LOOKUP_SLICE`` ids."""
+        import numpy as np
+        counts: list[int] = []
+        rows: list[int] = []
+        for lo in range(0, len(object_ids), LOOKUP_SLICE):
+            chunk = object_ids[lo:lo + LOOKUP_SLICE]
+            with self._lock:
+                got = list(map(self._locs.get, chunk,
+                               repeat((), len(chunk))))
+                counts.extend(map(len, got))
+                rows.extend(chain.from_iterable(got))
+        return (np.fromiter(counts, np.int64, len(counts)),
+                np.fromiter(rows, np.int64, len(rows)))
 
     def has_location(self, object_id: ObjectID, row: int) -> bool:
         with self._lock:

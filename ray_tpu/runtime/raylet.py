@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import threading
 from collections import deque
+from itertools import compress
 from typing import NamedTuple
 
 import numpy as np
@@ -168,9 +169,11 @@ class Raylet:
         # died between pipeline_target and the commit)
         self._assigned_total = 0
         self._avoid_local: set[TaskID] = set()  # lease-spilled: skip here
-        # subgrouping pass: tasks seen, and those whose key needed a
-        # locality probe or an avoid flag (the slow key; under _cv)
-        self._group_stats = {"group_tasks": 0, "group_probed": 0}
+        # subgrouping pass: tasks seen, those whose key needed a
+        # locality probe or an avoid flag (the slow key), and the plasma
+        # args the round's probe looked up (under _cv)
+        self._group_stats = {"group_tasks": 0, "group_probed": 0,
+                             "locality_args": 0}
         # last _effective_snapshot soft-masked SUSPECT rows out (gray
         # failures): tells _schedule_rows a -1 deserves a full-cluster
         # fallback pass before parking the task
@@ -552,7 +555,10 @@ class Raylet:
             return self._schedule_rows_host_subgrouped(specs, sub)
         # mixed-strategy batches: per-task CPU policy on a snapshot
         # (sequential within the round), partitioned by scheduling class
-        # in first-appearance order
+        # in first-appearance order; the round's probe runs once, first
+        if locs is None:
+            with phase("raylet.locality"):
+                locs = self._locality_rows(specs)
         snapshot = self._effective_snapshot()
         by_class: dict[tuple, list[int]] = {}
         for t, spec in enumerate(specs):
@@ -568,24 +574,28 @@ class Raylet:
                     snapshot, req,
                     self._options_for(specs[t],
                                       snapshot.node_mask.shape[0],
-                                      None if locs is None
-                                      else locs.get(id(specs[t]), -1)))
+                                      locs.get(id(specs[t]), -1)))
         return rows
 
     def _locality_rows(self, specs: list) -> dict:
         """The round's locality probe, one pass before grouping:
         {id(spec): ``_AVOID``} for a spec whose avoid flag is set (read,
         not consumed), {id(spec): its locality row, -1 for none} for a
-        DEFAULT-strategy spec with args; other specs are not probed."""
+        DEFAULT-strategy spec with args; other specs are not probed.
+        ``locality_args`` counts the plasma args looked up."""
         default = SchedulingStrategyKind.DEFAULT
         avoid_set = self._avoid_local
         locs: dict[int, int] = {}
+        probe = []
         for s in specs:
             if avoid_set and s.task_id in avoid_set:
                 locs[id(s)] = _AVOID
             elif s.args and s.strategy.kind is default:
-                row = self._locality_row(s)
-                locs[id(s)] = -1 if row is None else row
+                probe.append(s)
+        rows, looked_up = self._locality_batch(probe)
+        locs.update(zip(map(id, probe), rows.tolist()))
+        with self._cv:
+            self._group_stats["locality_args"] += looked_up
         return locs
 
     def _subgroup(self, specs: list, locs: dict | None = None):
@@ -1032,24 +1042,54 @@ class Raylet:
         return out
 
     def _locality_row(self, spec) -> int | None:
-        """Node row holding the most bytes of the spec's plasma args, or
-        None when locality gives no signal (no plasma args, or the knob
-        is off).  Reference: the core worker's locality-aware lease
-        policy targets the raylet with the most object bytes local."""
-        if not spec.args or not get_config().locality_aware_scheduling:
+        """The batch of one of ``_locality_batch``: the spec's locality
+        row, or None when locality gives no signal."""
+        if not spec.args:
             return None
-        by_row: dict[int, int] = {}
-        from .object_store import PLASMA_KINDS
-        for a in spec.args:
-            if isinstance(a, ObjectRef):
-                kind, size = self.store.plasma_info(a.id)
-                if kind in PLASMA_KINDS:
-                    for r in self.cluster.directory.locations(a.id):
-                        by_row[r] = by_row.get(r, 0) + size
-        if not by_row:
-            return None
-        # max bytes, lowest row on ties (deterministic)
-        return min(by_row, key=lambda r: (-by_row[r], r))
+        row = int(self._locality_batch([spec])[0][0])
+        return None if row < 0 else row
+
+    def _locality_batch(self, specs: list) -> tuple[np.ndarray, int]:
+        """(each spec's locality row, -1 for none; the number of plasma
+        args looked up).  The row holds the most bytes of the spec's
+        plasma args (an arg given twice counts twice), lowest row on
+        ties; no row when the spec has no plasma arg with a location or
+        the knob is off.  Reference: the core worker's locality-aware
+        lease policy targets the raylet with the most object bytes
+        local.  One batched read of the store and one of the directory
+        for all the specs' args, then one numpy reduction."""
+        rows = np.full(len(specs), -1, np.int64)
+        if not specs or not get_config().locality_aware_scheduling:
+            return rows, 0
+        ids: list = []
+        per_spec: list[int] = []
+        for s in specs:
+            refs = [a.id for a in s.args if isinstance(a, ObjectRef)]
+            per_spec.append(len(refs))
+            ids += refs
+        sizes = self.store.plasma_sizes(ids)
+        plasma = sizes >= 0
+        kept = list(compress(ids, plasma.tolist()))
+        n_locs, loc_rows = self.cluster.directory.location_rows(kept)
+        if loc_rows.size:
+            owner = np.repeat(np.arange(len(specs)), per_spec)[plasma]
+            width = int(loc_rows.max()) + 1
+            key = np.repeat(owner, n_locs) * width + loc_rows
+            order = np.argsort(key)
+            key = key[order]
+            start = np.flatnonzero(np.r_[True, key[1:] != key[:-1]])
+            # exact int64 bytes per (spec, row), keys ascending
+            total = np.add.reduceat(
+                np.repeat(sizes[plasma], n_locs)[order], start)
+            task, row = np.divmod(key[start], width)
+            # per spec the first (lowest) row holding its most bytes
+            cut = np.flatnonzero(np.r_[True, task[1:] != task[:-1]])
+            best = np.repeat(np.maximum.reduceat(total, cut),
+                             np.diff(np.r_[cut, len(task)]))
+            hit = np.flatnonzero(total == best)
+            hit = hit[np.r_[True, task[hit[1:]] != task[hit[:-1]]]]
+            rows[task[hit]] = row[hit]
+        return rows, len(kept)
 
     def _options_for(self, spec, n_rows: int,
                      loc: int | None = None) -> SchedulingOptions:
