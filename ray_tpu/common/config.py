@@ -69,27 +69,12 @@ _CONFIG_DEFS: dict[str, tuple[type, Any, str]] = {
         "The default assumed ~90 ms per device call against ~25 us per "
         "placement on the CPU; it has not been measured on a host-local "
         "chip yet (ROADMAP Queue 1, item 6)."),
-    "scheduler_delta_beats": (
-        bool, True,
-        "Incremental device heartbeat: keep the CRM mirror + carried key "
-        "tensor resident in HBM between beats and upload only the dirty "
-        "rows/classes (DeltaScheduler).  False re-uploads the full "
-        "snapshot every device round (the pre-delta behavior; parity "
-        "bisection)."),
     "scheduler_delta_max_dirty_fraction": (
         float, 0.25,
         "Full-rescore fallback knob: when more than this fraction of "
         "node rows changed since the last beat, the delta path costs "
         "more than one bulk upload + full rescore, so the heartbeat "
         "resyncs everything instead."),
-    "scheduler_sharded_state": (
-        bool, False,
-        "Shard the device scheduler's cluster-state rows over ALL local "
-        "devices (jax Mesh on a 'nodes' axis): each device owns N/n_dev "
-        "node rows and the water-fill's global reductions lower to XLA "
-        "collectives over ICI.  Off (default) keeps single-device "
-        "arrays — correct either way (dryrun-proven bit-equality); on "
-        "one chip there is nothing to shard."),
     "scheduler_shards": (
         int, 1,
         "Node-shard count for the mesh-sharded delta heartbeat "

@@ -133,60 +133,3 @@ def autoscale_np(totals, avail, node_mask, demand_reqs, demand_counts,
         jnp.asarray(type_caps, jnp.int32), jnp.asarray(type_quotas, jnp.int32),
         None if extra_mask is None else jnp.asarray(extra_mask, bool))
     return tuple(np.asarray(o) for o in out)
-
-
-_SHARDED_JIT: dict = {}
-
-
-def autoscale_sharded_np(totals, avail, node_mask, demand_reqs,
-                         demand_counts, type_caps, type_quotas,
-                         extra_mask=None, n_shards: int = 0,
-                         reduce_mode: str = "auto"):
-    """GSPMD row-sharded twin of ``autoscale_np``: existing-node rows
-    partition over the two-level mesh (ops.shard_reduce) for the
-    phase-1 fit; the phase-2 launch loop's (K, R) state is tiny and
-    stays replicated.  Bit-identical to the single-device call."""
-    from .shard_reduce import gspmd_plane, pad_node_rows
-    caps_h = np.asarray(type_caps)      # rtlint: disable=W6
-    if (caps_h > MAX_TOTAL_CU).any():
-        raise ValueError(
-            f"type_caps exceed MAX_TOTAL_CU={MAX_TOTAL_CU} cu "
-            "(int32 score-arithmetic contract)")
-    n = totals.shape[0]
-    pl = gspmd_plane(n_shards, reduce_mode)
-    pad = pad_node_rows(n, pl.n_shards)
-    if pad:
-        totals = np.pad(totals, ((0, pad), (0, 0)))
-        avail = np.pad(avail, ((0, pad), (0, 0)))
-        node_mask = np.pad(node_mask, (0, pad))
-        if extra_mask is not None:
-            extra_mask = np.pad(extra_mask, (0, pad))
-    key = (pl.n_shards, reduce_mode, jax.default_backend())
-    step = _SHARDED_JIT.get(key)
-    if step is None:
-        step = _SHARDED_JIT[key] = jax.jit(
-            autoscale, out_shardings=(pl.sh_repl, pl.sh_repl,
-                                      pl.sh_repl, pl.sh_rows))
-    launches, fit_counts, unmet, new_avail = step(
-        jax.device_put(np.ascontiguousarray(totals, np.int32), pl.sh_rows),
-        jax.device_put(np.ascontiguousarray(avail, np.int32), pl.sh_rows),
-        jax.device_put(np.ascontiguousarray(node_mask, bool), pl.sh_vec),
-        jax.device_put(np.ascontiguousarray(demand_reqs, np.int32),
-                       pl.sh_repl),
-        jax.device_put(np.ascontiguousarray(demand_counts, np.int32),
-                       pl.sh_repl),
-        jax.device_put(np.ascontiguousarray(type_caps, np.int32),
-                       pl.sh_repl),
-        jax.device_put(np.ascontiguousarray(type_quotas, np.int32),
-                       pl.sh_repl),
-        None if extra_mask is None else
-        jax.device_put(np.ascontiguousarray(extra_mask, bool), pl.sh_vec))
-    launches = np.asarray(launches)         # rtlint: disable=W6
-    fit_counts = np.asarray(fit_counts)     # rtlint: disable=W6
-    unmet = np.asarray(unmet)               # rtlint: disable=W6
-    new_avail = np.asarray(new_avail)       # rtlint: disable=W6
-    if pad:
-        fit_counts = np.concatenate([fit_counts[:, :n],
-                                     fit_counts[:, -1:]], axis=1)
-        new_avail = new_avail[:n]
-    return launches, fit_counts, unmet, new_avail

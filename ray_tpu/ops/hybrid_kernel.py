@@ -491,54 +491,3 @@ def schedule_grouped_np(totals, avail, node_mask, group_reqs, group_counts,
         jnp.asarray(group_counts, jnp.int32), jnp.asarray(group_masks, bool),
         jnp.int32(thr_fp))
     return np.asarray(counts), np.asarray(new_avail)
-
-
-_SHARDED_JIT: dict = {}
-
-
-def schedule_grouped_sharded_np(totals, avail, node_mask, group_reqs,
-                                group_counts, group_masks=None,
-                                thr_fp=None, spread_threshold=None,
-                                n_shards: int = 0,
-                                reduce_mode: str = "auto"):
-    """GSPMD row-sharded twin of ``schedule_grouped_np``: node rows
-    partition over the two-level ("dcn", "ici") mesh
-    (ops.shard_reduce) and the water-fill's global sums lower to XLA
-    collectives.  Bit-identical to the single-device call; node rows
-    pad to a shard multiple with mask-False rows (kernel no-ops)."""
-    from ..scheduling.contract import threshold_fp
-    from .shard_reduce import gspmd_plane, pad_node_rows
-    if thr_fp is None:
-        thr_fp = threshold_fp(spread_threshold)
-    g, n = group_reqs.shape[0], totals.shape[0]
-    if group_masks is None:
-        group_masks = np.ones((g, n), dtype=bool)
-    pl = gspmd_plane(n_shards, reduce_mode)
-    pad = pad_node_rows(n, pl.n_shards)
-    if pad:
-        totals = np.pad(totals, ((0, pad), (0, 0)))
-        avail = np.pad(avail, ((0, pad), (0, 0)))
-        node_mask = np.pad(node_mask, (0, pad))
-        group_masks = np.pad(group_masks, ((0, 0), (0, pad)))
-    key = ("hybrid", pl.n_shards, reduce_mode, jax.default_backend())
-    step = _SHARDED_JIT.get(key)
-    if step is None:
-        step = _SHARDED_JIT[key] = jax.jit(
-            schedule_grouped, out_shardings=(pl.sh_repl, pl.sh_rows))
-    counts, new_avail = step(
-        jax.device_put(np.ascontiguousarray(totals, np.int32), pl.sh_rows),
-        jax.device_put(np.ascontiguousarray(avail, np.int32), pl.sh_rows),
-        jax.device_put(np.ascontiguousarray(node_mask, bool), pl.sh_vec),
-        jax.device_put(np.ascontiguousarray(group_reqs, np.int32),
-                       pl.sh_repl),
-        jax.device_put(np.ascontiguousarray(group_counts, np.int32),
-                       pl.sh_repl),
-        jax.device_put(np.ascontiguousarray(group_masks, bool),
-                       pl.sh_cols),
-        jnp.int32(thr_fp))
-    counts = np.asarray(counts)             # rtlint: disable=W6
-    new_avail = np.asarray(new_avail)       # rtlint: disable=W6
-    if pad:
-        counts = np.concatenate([counts[:, :n], counts[:, -1:]], axis=1)
-        new_avail = new_avail[:n]
-    return counts, new_avail

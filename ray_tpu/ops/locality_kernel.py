@@ -170,119 +170,3 @@ def schedule_grouped_topk_np(totals, avail, node_mask, group_reqs,
         rng_key,
         None if extra_mask is None else jnp.asarray(extra_mask, bool))
     return np.asarray(counts), np.asarray(new_avail)
-
-
-_SHARDED_JIT: dict = {}
-
-
-def _sharded_call(name, fn, pl, reduce_mode):
-    key = (name, pl.n_shards, reduce_mode, jax.default_backend())
-    step = _SHARDED_JIT.get(key)
-    if step is None:
-        step = _SHARDED_JIT[key] = jax.jit(
-            fn, out_shardings=(pl.sh_repl, pl.sh_rows))
-    return step
-
-
-def schedule_grouped_localized_sharded_np(totals, avail, node_mask,
-                                          group_reqs, group_counts,
-                                          pref_rows, group_masks=None,
-                                          thr_fp=None,
-                                          spread_threshold=None,
-                                          extra_mask=None,
-                                          n_shards: int = 0,
-                                          reduce_mode: str = "auto"):
-    """GSPMD row-sharded twin of ``schedule_grouped_localized_np``:
-    node rows partition over the two-level mesh (ops.shard_reduce),
-    global reductions lower to XLA collectives.  Bit-identical."""
-    from ..scheduling.contract import threshold_fp
-    from .shard_reduce import gspmd_plane, pad_node_rows
-    if thr_fp is None:
-        thr_fp = threshold_fp(spread_threshold)
-    g, n = group_reqs.shape[0], totals.shape[0]
-    if group_masks is None:
-        group_masks = np.ones((g, n), dtype=bool)
-    pl = gspmd_plane(n_shards, reduce_mode)
-    pad = pad_node_rows(n, pl.n_shards)
-    if pad:
-        totals = np.pad(totals, ((0, pad), (0, 0)))
-        avail = np.pad(avail, ((0, pad), (0, 0)))
-        node_mask = np.pad(node_mask, (0, pad))
-        group_masks = np.pad(group_masks, ((0, 0), (0, pad)))
-        if extra_mask is not None:
-            extra_mask = np.pad(extra_mask, (0, pad))
-    step = _sharded_call("localized", schedule_grouped_localized, pl,
-                         reduce_mode)
-    counts, new_avail = step(
-        jax.device_put(np.ascontiguousarray(totals, np.int32), pl.sh_rows),
-        jax.device_put(np.ascontiguousarray(avail, np.int32), pl.sh_rows),
-        jax.device_put(np.ascontiguousarray(node_mask, bool), pl.sh_vec),
-        jax.device_put(np.ascontiguousarray(group_reqs, np.int32),
-                       pl.sh_repl),
-        jax.device_put(np.ascontiguousarray(group_counts, np.int32),
-                       pl.sh_repl),
-        jax.device_put(np.ascontiguousarray(group_masks, bool), pl.sh_cols),
-        jax.device_put(np.ascontiguousarray(pref_rows, np.int32),
-                       pl.sh_repl),
-        jnp.int32(thr_fp),
-        None if extra_mask is None else
-        jax.device_put(np.ascontiguousarray(extra_mask, bool), pl.sh_vec))
-    counts = np.asarray(counts)             # rtlint: disable=W6
-    new_avail = np.asarray(new_avail)       # rtlint: disable=W6
-    if pad:
-        counts = np.concatenate([counts[:, :n], counts[:, -1:]], axis=1)
-        new_avail = new_avail[:n]
-    return counts, new_avail
-
-
-def schedule_grouped_topk_sharded_np(totals, avail, node_mask, group_reqs,
-                                     group_counts, seed, round_index,
-                                     group_masks=None, thr_fp=None,
-                                     spread_threshold=None, k_abs=1,
-                                     k_frac=0.0, extra_mask=None,
-                                     n_shards: int = 0,
-                                     reduce_mode: str = "auto"):
-    """GSPMD row-sharded twin of ``schedule_grouped_topk_np`` (same
-    padding + collective-lowering story as the localized variant)."""
-    from fractions import Fraction
-
-    from ..scheduling.contract import threshold_fp
-    from .shard_reduce import gspmd_plane, pad_node_rows
-    if thr_fp is None:
-        thr_fp = threshold_fp(spread_threshold)
-    g, n = group_reqs.shape[0], totals.shape[0]
-    if group_masks is None:
-        group_masks = np.ones((g, n), dtype=bool)
-    pl = gspmd_plane(n_shards, reduce_mode)
-    pad = pad_node_rows(n, pl.n_shards)
-    if pad:
-        totals = np.pad(totals, ((0, pad), (0, 0)))
-        avail = np.pad(avail, ((0, pad), (0, 0)))
-        node_mask = np.pad(node_mask, (0, pad))
-        group_masks = np.pad(group_masks, ((0, 0), (0, pad)))
-        if extra_mask is not None:
-            extra_mask = np.pad(extra_mask, (0, pad))
-    frac = Fraction(k_frac).limit_denominator(1 << 16)
-    rng_key = jax.random.fold_in(
-        jax.random.PRNGKey(int(seed)), int(round_index))
-    step = _sharded_call("topk", schedule_grouped_topk, pl, reduce_mode)
-    counts, new_avail = step(
-        jax.device_put(np.ascontiguousarray(totals, np.int32), pl.sh_rows),
-        jax.device_put(np.ascontiguousarray(avail, np.int32), pl.sh_rows),
-        jax.device_put(np.ascontiguousarray(node_mask, bool), pl.sh_vec),
-        jax.device_put(np.ascontiguousarray(group_reqs, np.int32),
-                       pl.sh_repl),
-        jax.device_put(np.ascontiguousarray(group_counts, np.int32),
-                       pl.sh_repl),
-        jax.device_put(np.ascontiguousarray(group_masks, bool), pl.sh_cols),
-        jnp.int32(thr_fp), jnp.int32(max(int(k_abs), 1)),
-        jnp.int32(frac.numerator), jnp.int32(max(frac.denominator, 1)),
-        rng_key,
-        None if extra_mask is None else
-        jax.device_put(np.ascontiguousarray(extra_mask, bool), pl.sh_vec))
-    counts = np.asarray(counts)             # rtlint: disable=W6
-    new_avail = np.asarray(new_avail)       # rtlint: disable=W6
-    if pad:
-        counts = np.concatenate([counts[:, :n], counts[:, -1:]], axis=1)
-        new_avail = new_avail[:n]
-    return counts, new_avail

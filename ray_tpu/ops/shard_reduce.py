@@ -546,19 +546,3 @@ def plane_for(n_shards: int, reduce_mode: str = "auto",
         plane = _cache[key] = ShardPlane(build_mesh(n_shards,
                                                     reduce_mode))
     return plane
-
-
-def gspmd_plane(n_shards: int = 0, reduce_mode: str = "auto"):
-    """Resolve + cache the ShardPlane for the GSPMD ``*_sharded_np``
-    kernel wrappers (hybrid/locality/topk/binpack): node rows shard over
-    the two-level mesh via input NamedShardings and XLA GSPMD lowers the
-    kernels' global reductions to collectives — no shard_map rewrite per
-    kernel.  Returns the plane; callers pad the node axis to a multiple
-    of ``plane.n_shards`` with mask-False rows (kernel no-ops)."""
-    return plane_for(resolve_shards(n_shards, len(jax.local_devices())),
-                     reduce_mode)
-
-
-def pad_node_rows(n: int, n_shards: int) -> int:
-    """Rows of padding needed so the node axis divides the shard count."""
-    return (-n) % max(n_shards, 1)

@@ -178,8 +178,8 @@ class Raylet:
         # failures): tells _schedule_rows a -1 deserves a full-cluster
         # fallback pass before parking the task
         self._suspect_softmask = False
-        # device-resident delta-heartbeat engine (lazy: only rounds that
-        # take the plain device path ever build one)
+        # device-resident delta-heartbeat engine (lazy: only device
+        # rounds that are not top-k ever build one)
         self._delta_engine = None
         self._stopped = False
         # DRAINING: no new leases commit here, running tasks finish;
@@ -730,86 +730,31 @@ class Raylet:
 
     def _schedule_rows_device(self, specs: list,
                               sub: _Subgroups) -> list[int]:
-        """One device water-fill call places the whole batch (north star).
+        """One device call places the whole batch (north star): the
+        resident delta beat (``_schedule_rows_delta``), or with
+        ``scheduler_top_k_fraction`` > 0 the top-k sampler on a
+        snapshot copy (``_schedule_device_topk``).
 
         Subgroups (``_subgroup``) key on (scheduling class, locality
-        row, avoid flag):
+        row, avoid flag), one dense request row and one count each:
         locality-biased groups pre-place on their preferred row (soft
-        affinity, bit-identical to the host sequence), avoid groups mask
-        out this node, and with ``scheduler_top_k_fraction`` > 0 the
-        no-preference groups spread over their top-k keys on device
-        (documented sampling divergence — ops/locality_kernel.py)."""
-        import jax.numpy as jnp
-
-        from ..ops import schedule_grouped
-        from ..scheduling.contract import threshold_fp
-
-        cfg = get_config()
+        affinity) and avoid groups mask out this node, bit-identical to
+        the host twin (``_schedule_rows_host_subgrouped``) and the
+        oracle; top-k rounds spread their no-preference groups over
+        their top-k keys (documented sampling divergence —
+        ops/locality_kernel.py)."""
         with phase("raylet.group"):
             width = self.crm.arrays()[0].shape[1]
-            G = len(sub.first)
-            # pad the class axis to a power-of-2 bucket: every distinct G
-            # would otherwise be a fresh XLA compilation (SURVEY §7 hard
-            # part 3); count-0 padding rows are no-ops in the water-fill
-            Gp = max(8, 1 << (G - 1).bit_length())
-            req_arr = np.zeros((Gp, width), dtype=np.int32)
-            req_arr[:G] = np.stack([
+            req = np.stack([
                 specs[t].resources.dense(self.crm.resource_index, width)
                 for t in sub.first])
-            cnt_arr = np.zeros(Gp, dtype=np.int32)
-            cnt_arr[:G] = sub.counts
-            pref_arr = np.full(Gp, -1, dtype=np.int32)
-            pref_arr[:G] = sub.prefs
-            top_k = cfg.scheduler_top_k_fraction
-            plain = (pref_arr < 0).all() and not any(sub.avoids)
-        if top_k == 0 and not cfg.scheduler_sharded_state \
-                and cfg.scheduler_delta_beats:
-            # incremental heartbeat: no snapshot copy, no full upload —
-            # the resident mirror syncs from the CRM dirty journal and
-            # planned load rides along as per-beat avail overrides;
+            plain = all(p < 0 for p in sub.prefs) and not any(sub.avoids)
+        if get_config().scheduler_top_k_fraction > 0:
+            counts_host = self._schedule_device_topk(req, sub)
+        else:
             # locality rows and avoid flags ride along per group
             loc = () if plain else (sub.prefs, sub.avoids, self.row)
-            counts_host = self._schedule_rows_delta(req_arr[:G],
-                                                    cnt_arr[:G], *loc)
-        else:
-            snapshot = self._effective_snapshot()
-            totals, avail, mask = (snapshot.totals, snapshot.avail,
-                                   snapshot.node_mask)
-            if totals.shape[1] != width:
-                # a resource column appeared between the width probe and
-                # the snapshot; dense vectors only append columns, so
-                # zero-padding the request rows is exact
-                wider = np.zeros((Gp, totals.shape[1]), dtype=np.int32)
-                wider[:, :width] = req_arr
-                req_arr = wider
-            N = totals.shape[0]
-            gmask = np.ones((Gp, N), dtype=bool)
-            for g, av in enumerate(sub.avoids):
-                if av and 0 <= self.row < N:
-                    gmask[g, self.row] = False
-            if cfg.scheduler_sharded_state and plain and top_k == 0:
-                # host gmask: the sharded branch pads its node axis
-                counts_host = self._schedule_sharded(
-                    totals, avail, mask, req_arr, cnt_arr, gmask)[:G]
-            elif top_k > 0:
-                counts_host = self._schedule_device_topk(
-                    totals, avail, mask, req_arr, cnt_arr, gmask,
-                    pref_arr, cfg)[:G]
-            elif plain:
-                counts_dev, _ = schedule_grouped(
-                    jnp.asarray(totals), jnp.asarray(avail),
-                    jnp.asarray(mask), jnp.asarray(req_arr),
-                    jnp.asarray(cnt_arr), jnp.asarray(gmask),
-                    jnp.int32(threshold_fp(None)))
-                counts_host = np.asarray(counts_dev)[:G]
-            else:
-                from ..ops.locality_kernel import schedule_grouped_localized
-                counts_dev, _ = schedule_grouped_localized(
-                    jnp.asarray(totals), jnp.asarray(avail),
-                    jnp.asarray(mask), jnp.asarray(req_arr),
-                    jnp.asarray(cnt_arr), jnp.asarray(gmask),
-                    jnp.asarray(pref_arr), jnp.int32(threshold_fp(None)))
-                counts_host = np.asarray(counts_dev)[:G]
+            counts_host = self._schedule_rows_delta(req, sub.counts, *loc)
         with phase("raylet.expand"):
             return _expand(sub.task_group, counts_host)
 
@@ -817,21 +762,24 @@ class Raylet:
                              avoids=None, local_row: int = -1
                              ) -> "np.ndarray":
         """The fused delta-heartbeat placement call
-        (scheduling.policy.DeltaScheduler): the device mirror syncs
-        incrementally from the CRM's dirty journal; this node's view of
-        planned-but-undispatched load rides along as per-beat avail
-        overrides and suspect rows as a per-beat soft mask — both with
-        the exact ``_effective_snapshot`` arithmetic, so placements are
-        bit-identical to the snapshot path.  Per-group locality rows
-        (``prefs``) and avoid flags (``avoids``, keeping a group off
-        ``local_row``) take the beat's localized program.  One counts
-        readback per beat.  Returns (G, N+1) int32 counts.
+        (scheduling.policy.DeltaScheduler): no snapshot copy, no full
+        upload — the device mirror syncs incrementally from the CRM's
+        dirty journal; this node's view of planned-but-undispatched
+        load rides along as per-beat avail overrides and suspect rows
+        as a per-beat soft mask, both with the exact
+        ``_effective_snapshot`` arithmetic, so placements are
+        bit-identical to the host twin
+        (``_schedule_rows_host_subgrouped``) and the oracle.  Per-group
+        locality rows (``prefs``) and avoid flags (``avoids``, keeping
+        a group off ``local_row``) take the beat's localized program.
+        One counts readback per beat.  Returns (G, N+1) int32 counts
+        for the G request rows given.
 
         The engine comes from ``make_delta_scheduler``: with
         ``scheduler_shards`` resolving past one chip the mirror and the
-        beat shard over the device mesh (ShardedDeltaScheduler), else
-        the single-device DeltaScheduler — placements are bit-identical
-        either way."""
+        beat shard over the device mesh (ShardedDeltaScheduler ->
+        ops.shard_reduce.ShardPlane), else the single-device
+        DeltaScheduler — placements are bit-identical either way."""
         from ..scheduling.sharded_delta import make_delta_scheduler
         eng = self._delta_engine
         if eng is None:
@@ -892,20 +840,40 @@ class Raylet:
             rows[ck] = budgets[slot]
         budget_board().publish(eng.budget_seq, rows)
 
-    def _schedule_device_topk(self, totals, avail, mask, req_arr,
-                              cnt_arr, gmask, pref_arr,
-                              cfg) -> "np.ndarray":
-        """Top-k rounds on device: locality groups pre-place via the
-        localized kernel (affinity is deterministic, no sampling), then
-        the remaining groups spread over their top-k keys with a pinned
-        (row, round) random stream — deterministic replay, documented
-        divergence from the host sampler's per-task draws."""
+    def _schedule_device_topk(self, req, sub: _Subgroups) -> "np.ndarray":
+        """Top-k rounds on device, on a snapshot copy: locality groups
+        pre-place via the localized kernel (affinity is deterministic,
+        no sampling), then the remaining groups spread over their top-k
+        keys with a pinned (row, round) random stream — deterministic
+        replay, documented divergence from the host sampler's per-task
+        draws.  Returns (G, N+1) int32 counts for the G rows of
+        ``req``."""
         from ..ops.locality_kernel import (schedule_grouped_localized_np,
                                           schedule_grouped_topk_np)
+        cfg = get_config()
+        snapshot = self._effective_snapshot()
+        totals, avail, mask = (snapshot.totals, snapshot.avail,
+                               snapshot.node_mask)
+        G, N = req.shape[0], totals.shape[0]
+        # pad the class axis to a power-of-2 bucket: every distinct G
+        # would otherwise be a fresh XLA compilation (SURVEY §7 hard
+        # part 3); count-0 padding rows are no-ops in the water-fill.
+        # A resource column that appeared since ``req`` was built
+        # zero-pads it: dense vectors only append columns
+        Gp = max(8, 1 << (G - 1).bit_length())
+        req_arr = np.zeros((Gp, totals.shape[1]), dtype=np.int32)
+        req_arr[:G, :req.shape[1]] = req
+        cnt_arr = np.zeros(Gp, dtype=np.int32)
+        cnt_arr[:G] = sub.counts
+        pref_arr = np.full(Gp, -1, dtype=np.int32)
+        pref_arr[:G] = sub.prefs
+        gmask = np.ones((Gp, N), dtype=bool)
+        for g, av in enumerate(sub.avoids):
+            if av and 0 <= self.row < N:
+                gmask[g, self.row] = False
         self._topk_round = getattr(self, "_topk_round", 0) + 1
         has_pref = pref_arr >= 0
-        counts_out = np.zeros((req_arr.shape[0], totals.shape[0] + 1),
-                              dtype=np.int32)
+        counts_out = np.zeros((Gp, N + 1), dtype=np.int32)
         avail_now = avail
         if has_pref.any():
             loc_cnt = np.where(has_pref, cnt_arr, 0).astype(np.int32)
@@ -922,68 +890,7 @@ class Raylet:
                 k_abs=cfg.scheduler_top_k_absolute,
                 k_frac=cfg.scheduler_top_k_fraction)
             counts_out += c_topk
-        return counts_out
-
-    def _schedule_sharded(self, totals, avail, mask, req_arr, cnt_arr,
-                          gmask) -> "np.ndarray":
-        """The device placement call with cluster-state rows SHARDED
-        over all local devices (the live-path form of the multi-chip
-        layout ``__graft_entry__.dryrun_multichip`` proves): each device
-        owns N/n_dev node rows; the water-fill's global sums lower to
-        all-reduces over ICI.  Node rows pad to a mesh multiple with
-        mask-False rows (no-ops in the kernel).  Returns host counts of
-        shape (Gp, N+1) — real node columns plus the infeasible
-        column."""
-        import jax
-        import jax.numpy as jnp
-        from jax.sharding import Mesh, NamedSharding
-        from jax.sharding import PartitionSpec as P
-
-        from ..ops import schedule_grouped
-        from ..scheduling.contract import threshold_fp
-        # local_devices, NOT devices(): in multi-process JAX the global
-        # list includes non-addressable chips, and device_put of host
-        # arrays onto those raises
-        devs = jax.local_devices()
-        n_dev = len(devs)
-        n = totals.shape[0]
-        pad = (-n) % n_dev
-        if pad:
-            totals = np.pad(totals, ((0, pad), (0, 0)))
-            avail = np.pad(avail, ((0, pad), (0, 0)))
-            mask = np.pad(mask, (0, pad))               # padding: dead rows
-            gmask = np.pad(gmask, ((0, 0), (0, pad)))
-        cache = getattr(self, "_shard_cache", None)
-        if cache is None or cache[0] != n_dev:
-            mesh = Mesh(np.array(devs), ("nodes",))
-            shardings = {
-                "rows": NamedSharding(mesh, P("nodes", None)),
-                "vec": NamedSharding(mesh, P("nodes")),
-                "repl": NamedSharding(mesh, P()),
-                "gn": NamedSharding(mesh, P(None, "nodes")),
-            }
-            step = jax.jit(
-                schedule_grouped,
-                out_shardings=(shardings["repl"], shardings["rows"]))
-            self._shard_cache = (n_dev, shardings, step)
-        _, sh, step = self._shard_cache
-        # device_put takes host numpy + sharding directly: ONE sharded
-        # transfer per array (a jnp.asarray first would materialize on
-        # the default device and reshard — double transfer)
-        counts_dev, _ = step(
-            jax.device_put(totals, sh["rows"]),
-            jax.device_put(avail, sh["rows"]),
-            jax.device_put(mask, sh["vec"]),
-            jax.device_put(req_arr, sh["repl"]),
-            jax.device_put(cnt_arr, sh["repl"]),
-            jax.device_put(gmask, sh["gn"]),
-            jnp.int32(threshold_fp(None)))
-        counts = np.asarray(counts_dev)
-        if pad:
-            # drop padding-node columns; the infeasible column is last
-            counts = np.concatenate([counts[:, :n], counts[:, -1:]],
-                                    axis=1)
-        return counts
+        return counts_out[:G]
 
     def _effective_snapshot(self, avoid_suspect: bool = True):
         """CRM snapshot minus every node's planned-but-undispatched load

@@ -100,60 +100,44 @@ class TestMultiNode:
         finally:
             cfg.scheduler_device_batch_min = old
 
-    def test_sharded_state_live_path(self, cluster):
-        """The live scheduler with cluster-state rows sharded over the
-        8-device virtual mesh (scheduler_sharded_state): placements
-        run as one sharded XLA program and every task completes."""
+    def test_sharded_delta_live_path(self, cluster):
+        """The live multi-chip path: with ``scheduler_shards: 0`` the
+        raylet's delta engine shards over all 8 virtual devices
+        (ShardedDeltaScheduler on the ShardPlane), its beats place
+        every task, and a later wave rides a delta beat."""
         from ray_tpu.common.config import Config
+        from ray_tpu.scheduling import ShardedDeltaScheduler
         cfg = Config.instance()
-        old_min = cfg.scheduler_device_batch_min
+        old_min, old_shards = (cfg.scheduler_device_batch_min,
+                               cfg.scheduler_shards)
+        raylets = list(cluster.raylets.values())
         cfg.scheduler_device_batch_min = 8
-        cfg.scheduler_sharded_state = True
+        cfg.scheduler_shards = 0
+        for r in raylets:               # engines are built lazily
+            r._delta_engine = None
         try:
-            refs = [padded.remote(i) for i in range(64)]
-            assert ray_tpu.get(refs, timeout=60) == \
-                [i + 1 for i in range(64)]
+            engines = []
+            # a wave whose rounds all fall under the batch floor takes
+            # the host twin, and an engine's first beat is a full sync,
+            # so waves repeat until a delta beat has run
+            for _wave in range(4):
+                refs = [padded.remote(i) for i in range(64)]
+                assert ray_tpu.get(refs, timeout=60) == \
+                    [i + 1 for i in range(64)]
+                engines = [r._delta_engine for r in raylets
+                           if r._delta_engine is not None]
+                if any(e.stats["delta_beats"] for e in engines):
+                    break
+            assert engines
+            for eng in engines:
+                assert isinstance(eng, ShardedDeltaScheduler)
+                assert eng.stats["shards"] > 1
+            assert sum(e.stats["delta_beats"] for e in engines) > 0
         finally:
             cfg.scheduler_device_batch_min = old_min
-            cfg.scheduler_sharded_state = False
-
-
-class TestShardedKernelParity:
-    def test_sharded_counts_match_single_device(self):
-        """The sharded layout (rows over the mesh, pad rows masked off)
-        returns bit-identical counts to the single-device call — the
-        live-path analogue of dryrun_multichip's oracle check, with a
-        node count that does NOT divide the mesh (pad rows exercised)."""
-        import jax
-        import jax.numpy as jnp
-        import numpy as np
-
-        from ray_tpu.ops import schedule_grouped
-        from ray_tpu.runtime.raylet import Raylet
-        from ray_tpu.scheduling.contract import threshold_fp
-
-        rng = np.random.default_rng(0)
-        n, width, gp = 27, 4, 8           # 27 % 8 devices != 0
-        totals = rng.integers(400, 6400, size=(n, width)).astype(np.int32)
-        avail = (totals * rng.random((n, width))).astype(np.int32)
-        mask = np.ones(n, dtype=bool)
-        req = rng.integers(0, 300, size=(gp, width)).astype(np.int32)
-        cnt = rng.integers(0, 50, size=gp).astype(np.int32)
-        gmask = np.ones((gp, n), dtype=bool)
-
-        single, _ = schedule_grouped(
-            jnp.asarray(totals), jnp.asarray(avail), jnp.asarray(mask),
-            jnp.asarray(req), jnp.asarray(cnt), jnp.asarray(gmask),
-            jnp.int32(threshold_fp(None)))
-
-        shim = object.__new__(Raylet)     # only _schedule_sharded runs
-        sharded = Raylet._schedule_sharded(
-            shim, totals, avail, mask, req, cnt, gmask)
-        np.testing.assert_array_equal(np.asarray(single),
-                                      np.asarray(sharded))
-        # the mesh must really have been multi-shard for this to prove
-        # anything (exact count depends on the backend)
-        assert len(jax.local_devices()) >= 2
+            cfg.scheduler_shards = old_shards
+            for r in raylets:
+                r._delta_engine = None
 
 
 class TestNodeArrival:

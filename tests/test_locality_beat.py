@@ -124,9 +124,15 @@ def test_fused_beat_localized_matches_twins(seed):
     assert want[4, local] == 0 and want[9, local] == 0
 
 
-@pytest.mark.parametrize("shards", [1, 2, 8])
-def test_delta_beat_localized_under_churn(shards):
-    rng, crm, ids, vecs, counts = _churn_cluster(seed=40 + shards)
+@pytest.mark.parametrize("shards,n_nodes,capacity", [
+    pytest.param(1, 24, 32, id="1"), pytest.param(2, 24, 32, id="2"),
+    pytest.param(8, 24, 32, id="8"),
+    # 27 CRM rows: no shard count divides the node axis
+    pytest.param(2, 27, 27, id="2-uneven"),
+    pytest.param(8, 27, 27, id="8-uneven")])
+def test_delta_beat_localized_under_churn(shards, n_nodes, capacity):
+    rng, crm, ids, vecs, counts = _churn_cluster(
+        seed=40 + shards, n_nodes=n_nodes, capacity=capacity)
     eng = _engine(crm, shards)
     debts = []
     # groups share classes: each class once plain, once with locality

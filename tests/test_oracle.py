@@ -432,11 +432,18 @@ class TestShardedDeltaSequenceOracle:
     oracle on a fresh snapshot.  conftest pins 8 virtual CPU devices,
     so every shard count here runs in tier-1."""
 
-    @pytest.mark.parametrize("shards", [2, 4, 8])
-    def test_sharded_matches_single_device_and_oracle(self, shards):
+    @pytest.mark.parametrize("shards,n_nodes,capacity", [
+        pytest.param(2, 24, 32, id="2"), pytest.param(4, 24, 32, id="4"),
+        pytest.param(8, 24, 32, id="8"),
+        # 27 CRM rows: no shard count divides the node axis
+        pytest.param(2, 27, 27, id="2-uneven"),
+        pytest.param(8, 27, 27, id="8-uneven")])
+    def test_sharded_matches_single_device_and_oracle(self, shards,
+                                                      n_nodes, capacity):
         from ray_tpu.scheduling import DeltaScheduler, ShardedDeltaScheduler
 
-        rng, crm, ids, vecs, counts = _churn_cluster(seed=shards)
+        rng, crm, ids, vecs, counts = _churn_cluster(
+            seed=shards, n_nodes=n_nodes, capacity=capacity)
         eng = ShardedDeltaScheduler(crm, shards)
         ref = DeltaScheduler(crm)
         assert eng.stats["shards"] == shards

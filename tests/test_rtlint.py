@@ -802,8 +802,8 @@ class TestW6:
         assert based, "expected the sanctioned readback sites"
 
     def test_new_knobs_pass_w3(self):
-        """The r08 knobs (scheduler_delta_beats,
-        scheduler_delta_max_dirty_fraction) and the r14 knobs
+        """The r08 knob (scheduler_delta_max_dirty_fraction) and the
+        r14 knobs
         (scheduler_shards, scheduler_shard_reduce) are documented and
         referenced — W3 stays clean on the live package."""
         new, _based, _stale, _ = analyzer.check(

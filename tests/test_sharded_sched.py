@@ -1,6 +1,6 @@
 """Mesh-sharded scheduling plane (r14): shard resolution, the
-two-level mesh, GSPMD row-sharded kernel wrappers, and the slow
-8-device MULTICHIP dry-run of the sharded heartbeat.
+two-level mesh, the ShardPlane cache, and the slow 8-device
+MULTICHIP dry-run of the sharded heartbeat.
 
 conftest pins 8 virtual CPU devices, so the 2/4/8-way sharded paths
 all execute in tier-1; the dry-run is `slow`-marked and skips
@@ -8,18 +8,6 @@ below 2 devices (a single chip)."""
 
 import numpy as np
 import pytest
-
-
-def _workload(seed=0, n=77, r=4, g=6):
-    rng = np.random.default_rng(seed)
-    totals = rng.integers(4, 64, size=(n, r)).astype(np.int32)
-    avail = np.minimum(totals,
-                       rng.integers(0, 64, size=(n, r))).astype(np.int32)
-    mask = rng.random(n) > 0.1
-    reqs = rng.integers(0, 4, size=(g, r)).astype(np.int32)
-    counts = rng.integers(1, 30, size=g).astype(np.int32)
-    gmask = rng.random((g, n)) > 0.05
-    return totals, avail, mask, reqs, counts, gmask, rng
 
 
 class TestShardResolution:
@@ -51,68 +39,6 @@ class TestShardResolution:
         from ray_tpu.ops.shard_reduce import plane_for
         assert plane_for(4, "flat") is plane_for(4, "flat")
         assert plane_for(4, "flat") is not plane_for(4, "two_level")
-
-
-class TestGspmdShardedWrappers:
-    """The thin GSPMD entry points: identical kernels, node rows
-    sharded by input NamedShardings — bit-exact vs the single-device
-    ``*_np`` twins (node axis deliberately NOT a shard multiple, so
-    the padding path is always exercised)."""
-
-    @pytest.mark.parametrize("shards", [2, 8])
-    def test_hybrid(self, shards):
-        from ray_tpu.ops.hybrid_kernel import (schedule_grouped_np,
-                                               schedule_grouped_sharded_np)
-        totals, avail, mask, reqs, counts, gmask, _ = _workload()
-        a = schedule_grouped_np(totals, avail, mask, reqs, counts, gmask)
-        b = schedule_grouped_sharded_np(totals, avail, mask, reqs, counts,
-                                        gmask, n_shards=shards)
-        for x, y in zip(a, b):
-            np.testing.assert_array_equal(x, y)
-
-    @pytest.mark.parametrize("shards", [2, 8])
-    def test_localized_and_topk(self, shards):
-        from ray_tpu.ops.locality_kernel import (
-            schedule_grouped_localized_np,
-            schedule_grouped_localized_sharded_np,
-            schedule_grouped_topk_np, schedule_grouped_topk_sharded_np)
-        totals, avail, mask, reqs, counts, gmask, rng = _workload(1)
-        pref = rng.integers(-1, totals.shape[0],
-                            size=reqs.shape[0]).astype(np.int32)
-        em = rng.random(totals.shape[0]) > 0.1
-        a = schedule_grouped_localized_np(totals, avail, mask, reqs,
-                                          counts, pref, gmask,
-                                          extra_mask=em)
-        b = schedule_grouped_localized_sharded_np(totals, avail, mask,
-                                                  reqs, counts, pref,
-                                                  gmask, extra_mask=em,
-                                                  n_shards=shards)
-        for x, y in zip(a, b):
-            np.testing.assert_array_equal(x, y)
-        a = schedule_grouped_topk_np(totals, avail, mask, reqs, counts,
-                                     7, 3, gmask, k_abs=2, k_frac=0.1,
-                                     extra_mask=em)
-        b = schedule_grouped_topk_sharded_np(totals, avail, mask, reqs,
-                                             counts, 7, 3, gmask,
-                                             k_abs=2, k_frac=0.1,
-                                             extra_mask=em,
-                                             n_shards=shards)
-        for x, y in zip(a, b):
-            np.testing.assert_array_equal(x, y)
-
-    @pytest.mark.parametrize("shards", [2, 8])
-    def test_autoscale(self, shards):
-        from ray_tpu.ops.binpack_kernel import (autoscale_np,
-                                                autoscale_sharded_np)
-        totals, avail, mask, reqs, counts, _gmask, rng = _workload(2)
-        caps = rng.integers(8, 64, size=(3, totals.shape[1])).astype(
-            np.int32)
-        quotas = np.array([5, 5, 5], np.int32)
-        a = autoscale_np(totals, avail, mask, reqs, counts, caps, quotas)
-        b = autoscale_sharded_np(totals, avail, mask, reqs, counts, caps,
-                                 quotas, n_shards=shards)
-        for x, y in zip(a, b):
-            np.testing.assert_array_equal(x, y)
 
 
 @pytest.mark.slow
