@@ -22,6 +22,7 @@ import threading
 
 from ..common.config import get_config
 from ..common import clock as _clk
+from ..runtime.object_ref import ObjectRef
 from .plan import BroadcastPlan, build_plan
 
 
@@ -66,7 +67,8 @@ class BroadcastManager:
         replica (relay tree first, pull-manager fallback for stragglers)
         and returns a summary dict."""
         cluster = self._cluster
-        oid = getattr(object_id, "object_id", object_id)
+        # a ref (rollout passes one) is broadcast as its ObjectID
+        oid = object_id.id if isinstance(object_id, ObjectRef) else object_id
         cfg = get_config()
         rows = cluster.directory.locations(oid)
         if not rows:

@@ -30,25 +30,30 @@ LOOKUP_SLICE = 2048
 class ObjectDirectory:
     def __init__(self):
         self._lock = threading.Lock()
-        self._locs: dict[ObjectID, set[int]] = {}
+        # keyed by the id's raw bytes (``ObjectID.binary()``), which hash
+        # and compare in C; public methods take and return ObjectIDs
+        self._locs: dict[bytes, set[int]] = {}
 
     def add_location(self, object_id: ObjectID, row: int) -> None:
+        key = object_id.binary()
         with self._lock:
-            self._locs.setdefault(object_id, set()).add(row)
+            self._locs.setdefault(key, set()).add(row)
 
     def locations(self, object_id: ObjectID) -> tuple[int, ...]:
+        key = object_id.binary()
         with self._lock:
-            return tuple(sorted(self._locs.get(object_id, ())))
+            return tuple(sorted(self._locs.get(key, ())))
 
-    def location_rows(self, object_ids: list):
-        """``locations`` of many ids in one pass, unsorted: (each id's
-        number of locations, int64; their rows back to back in id order,
-        int64).  The lock is taken once per ``LOOKUP_SLICE`` ids."""
+    def location_rows(self, keys: list[bytes]):
+        """``locations`` of many ids in one pass, given as their raw keys
+        (``ObjectID.binary()``), unsorted: (each id's number of
+        locations, int64; their rows back to back in id order, int64).
+        The lock is taken once per ``LOOKUP_SLICE`` ids."""
         import numpy as np
         counts: list[int] = []
         rows: list[int] = []
-        for lo in range(0, len(object_ids), LOOKUP_SLICE):
-            chunk = object_ids[lo:lo + LOOKUP_SLICE]
+        for lo in range(0, len(keys), LOOKUP_SLICE):
+            chunk = keys[lo:lo + LOOKUP_SLICE]
             with self._lock:
                 got = list(map(self._locs.get, chunk,
                                repeat((), len(chunk))))
@@ -58,28 +63,27 @@ class ObjectDirectory:
                 np.fromiter(rows, np.int64, len(rows)))
 
     def has_location(self, object_id: ObjectID, row: int) -> bool:
+        key = object_id.binary()
         with self._lock:
-            return row in self._locs.get(object_id, ())
+            return row in self._locs.get(key, ())
 
     def is_tracked(self, object_id: ObjectID) -> bool:
+        key = object_id.binary()
         with self._lock:
-            return object_id in self._locs
+            return key in self._locs
 
     def drop(self, object_ids: Iterable[ObjectID]) -> None:
         """Object deleted cluster-wide (refcount zero / loss)."""
         with self._lock:
             for oid in object_ids:
-                self._locs.pop(oid, None)
+                self._locs.pop(oid.binary(), None)
 
     def sole_copies_on(self, row: int) -> list[ObjectID]:
         """Objects whose ONLY copy lives on ``row`` (a node holding any is
         not safe to terminate; the autoscaler migrates them first)."""
-        out = []
         with self._lock:
-            for oid, rows in self._locs.items():
-                if rows == {row}:
-                    out.append(oid)
-        return out
+            return [ObjectID(key) for key, rows in self._locs.items()
+                    if rows == {row}]
 
     def on_node_removed(self, row: int) -> list[ObjectID]:
         """Node death: its copies vanish.  Returns objects whose LAST copy
@@ -87,11 +91,11 @@ class ObjectDirectory:
         lineage or surfaced as ObjectLostError, SURVEY §5.3)."""
         lost = []
         with self._lock:
-            for oid, rows in list(self._locs.items()):
+            for key, rows in list(self._locs.items()):
                 rows.discard(row)
                 if not rows:
-                    del self._locs[oid]
-                    lost.append(oid)
+                    del self._locs[key]
+                    lost.append(ObjectID(key))
         return lost
 
     def location_matrix(self, object_ids: list[ObjectID], n_rows: int):
@@ -100,7 +104,7 @@ class ObjectDirectory:
         out = np.zeros((len(object_ids), n_rows), dtype=bool)
         with self._lock:
             for i, oid in enumerate(object_ids):
-                for r in self._locs.get(oid, ()):
+                for r in self._locs.get(oid.binary(), ()):
                     if r < n_rows:
                         out[i, r] = True
         return out

@@ -103,9 +103,13 @@ class MemoryStore:
         from ..common.config import get_config
         cfg = get_config()
         self._cv = threading.Condition()
+        # every index here is keyed by the id's raw bytes
+        # (``ObjectID.binary()``): bytes hash and compare in C, so the
+        # locality probe's batched lookups skip ``ObjectID.__hash__``.
+        # Public methods take ObjectIDs and hand ObjectIDs back.
         # LRU order: least-recently-touched first (spill victims)
-        self._objects: "OrderedDict[ObjectID, object]" = OrderedDict()
-        self._listeners: dict[ObjectID, list[Callable[[ObjectID], None]]] = {}
+        self._objects: "OrderedDict[bytes, object]" = OrderedDict()
+        self._listeners: dict[bytes, list[Callable[[ObjectID], None]]] = {}
         self.arena = arena
         self._spill_dir = spill_dir
         self._threshold = (direct_call_threshold
@@ -115,23 +119,24 @@ class MemoryStore:
                             else cfg.object_spilling_threshold)
         self.spilled_bytes = 0
         self.restored_bytes = 0
-        # deleted-while-pinned shm entries, keyed by (oid, offset) so a
+        # deleted-while-pinned shm entries, keyed by (id, offset) so a
         # re-seal + re-delete of the same object id cannot overwrite an
         # older zombie; the block is freed only when the last outstanding
         # descriptor is unpinned
-        self._zombies: dict[tuple[ObjectID, int], ShmEntry] = {}
+        self._zombies: dict[tuple[bytes, int], ShmEntry] = {}
 
     # -- write --------------------------------------------------------------
     def put(self, object_id: ObjectID, value) -> None:
         """Seal an in-band Python value (first write wins; real bytes
         upgrade a metadata-only RemoteEntry)."""
+        key = object_id.binary()
         with self._cv:
-            existing = self._objects.get(object_id)
+            existing = self._objects.get(key)
             if existing is not None and \
                     not isinstance(existing, RemoteEntry):
                 return
-            self._objects[object_id] = value
-            listeners = self._listeners.pop(object_id, ())
+            self._objects[key] = value
+            listeners = self._listeners.pop(key, ())
             self._cv.notify_all()
         for cb in listeners:
             cb(object_id)
@@ -144,8 +149,9 @@ class MemoryStore:
         if self.arena is None or data.nbytes <= self._threshold:
             self.put(object_id, deserialize(data))
             return
+        key = object_id.binary()
         with self._cv:
-            existing = self._objects.get(object_id)
+            existing = self._objects.get(key)
             if existing is not None and \
                     not isinstance(existing, RemoteEntry):
                 return
@@ -157,11 +163,11 @@ class MemoryStore:
                 # strand waiters — spill the payload straight to disk, or
                 # hold it in-band when there is no spill dir (the restore
                 # path's bytes fallback, in reverse)
-                entry = self._spill_direct_locked(object_id, data)
+                entry = self._spill_direct_locked(key, data)
                 if entry is None:
                     entry = deserialize(data)
-            self._objects[object_id] = entry
-            listeners = self._listeners.pop(object_id, ())
+            self._objects[key] = entry
+            listeners = self._listeners.pop(key, ())
             self._cv.notify_all()
         for cb in listeners:
             cb(object_id)
@@ -203,38 +209,38 @@ class MemoryStore:
         Pinned entries are skipped: a worker may hold their (offset, size)
         descriptor and read the block at any moment."""
         victim = None
-        for oid, entry in self._objects.items():      # LRU first
+        for key, entry in self._objects.items():      # LRU first
             if isinstance(entry, ShmEntry) and entry.pins == 0:
-                victim = (oid, entry)
+                victim = (key, entry)
                 break
         if victim is None or self._spill_dir is None:
             return False
-        oid, entry = victim
-        path = self._write_spill_file(oid, self.arena.view(entry.offset,
+        key, entry = victim
+        path = self._write_spill_file(key, self.arena.view(entry.offset,
                                                            entry.size))
         self.arena.free(entry.offset)
-        self._objects[oid] = SpillEntry(path, entry.size)
+        self._objects[key] = SpillEntry(path, entry.size)
         self.spilled_bytes += entry.size
         return True
 
-    def _write_spill_file(self, object_id: ObjectID, data) -> str:
+    def _write_spill_file(self, key: bytes, data) -> str:
         os.makedirs(self._spill_dir, exist_ok=True)
-        path = os.path.join(self._spill_dir, object_id.hex())
+        path = os.path.join(self._spill_dir, key.hex())
         with open(path, "wb") as f:
             f.write(data)
         return path
 
-    def _spill_direct_locked(self, object_id: ObjectID,
+    def _spill_direct_locked(self, key: bytes,
                              data) -> SpillEntry | None:
         """Payload that cannot enter the arena goes straight to disk
         (sealed as a SpillEntry); None when no spill dir is configured."""
         if self._spill_dir is None:
             return None
-        path = self._write_spill_file(object_id, data)
+        path = self._write_spill_file(key, data)
         self.spilled_bytes += data.nbytes
         return SpillEntry(path, data.nbytes)
 
-    def _restore_locked(self, object_id: ObjectID,
+    def _restore_locked(self, key: bytes,
                         entry: SpillEntry) -> ShmEntry | bytes:
         """Bring a spilled payload back; prefer the arena (zero-copy for
         readers), fall back to raw bytes if it cannot fit."""
@@ -246,18 +252,19 @@ class MemoryStore:
         except ObjectStoreFullError:
             return data
         os.unlink(entry.path)
-        self._objects[object_id] = shm
+        self._objects[key] = shm
         return shm
 
     def put_remote(self, object_id: ObjectID, size: int) -> None:
         """Seal a remote-resident object's METADATA (first write wins):
         the bytes live on another node's plane; local readers go through
         the pull manager, which ingests real bytes over this entry."""
+        key = object_id.binary()
         with self._cv:
-            if object_id in self._objects:
+            if key in self._objects:
                 return
-            self._objects[object_id] = RemoteEntry(size)
-            listeners = self._listeners.pop(object_id, ())
+            self._objects[key] = RemoteEntry(size)
+            listeners = self._listeners.pop(key, ())
             self._cv.notify_all()
         for cb in listeners:
             cb(object_id)
@@ -273,9 +280,10 @@ class MemoryStore:
         re-checked against the entry — a concurrent RESTORE unlinks the
         file while moving the bytes into the arena (the object is still
         live; only a true delete returns None)."""
+        key = object_id.binary()
         for _ in range(4):
             with self._cv:
-                entry = self._objects.get(object_id)
+                entry = self._objects.get(key)
                 if isinstance(entry, ShmEntry):
                     # a peer asking past the end (stale size metadata,
                     # malformed op_read) must not produce a negative-
@@ -315,9 +323,10 @@ class MemoryStore:
         the bytes are on the socket), or plain spill-file bytes with a
         no-op release.  ``(None, None)`` when the object has no local
         bytes."""
+        key = object_id.binary()
         for _ in range(4):
             with self._cv:
-                entry = self._objects.get(object_id)
+                entry = self._objects.get(key)
                 if isinstance(entry, ShmEntry):
                     if offset < 0 or offset >= entry.size:
                         return b"", _NO_RELEASE
@@ -348,7 +357,7 @@ class MemoryStore:
         Prefers an arena block (spilling LRU victims for room); falls
         back to writing a spill file when the arena cannot take it."""
         with self._cv:
-            entry = self._objects.get(object_id)
+            entry = self._objects.get(object_id.binary())
             if entry is not None and not isinstance(entry, RemoteEntry):
                 return None
             if self.arena is not None and size > self._threshold:
@@ -394,8 +403,9 @@ class MemoryStore:
 
     def _commit_ingest(self, object_id: ObjectID, entry) -> None:
         """Seal ingested bytes over an absent or RemoteEntry slot."""
+        key = object_id.binary()
         with self._cv:
-            existing = self._objects.get(object_id)
+            existing = self._objects.get(key)
             if existing is not None and \
                     not isinstance(existing, RemoteEntry):
                 # lost the race to another ingest/seal: discard ours
@@ -403,8 +413,8 @@ class MemoryStore:
                 return
             if isinstance(entry, ShmEntry):
                 entry.pins = 0      # birth pin released at seal
-            self._objects[object_id] = entry
-            listeners = self._listeners.pop(object_id, ())
+            self._objects[key] = entry
+            listeners = self._listeners.pop(key, ())
             self._cv.notify_all()
         for cb in listeners:
             cb(object_id)
@@ -413,19 +423,21 @@ class MemoryStore:
         """Remove a metadata-only RemoteEntry (its backing copies are
         gone — node death).  Real local entries are left alone; waiters
         re-park on absence and wake at the re-seal or poison."""
+        key = object_id.binary()
         with self._cv:
-            if isinstance(self._objects.get(object_id), RemoteEntry):
-                del self._objects[object_id]
+            if isinstance(self._objects.get(key), RemoteEntry):
+                del self._objects[key]
 
     def delete(self, object_ids: Iterable[ObjectID]) -> None:
         with self._cv:
             for oid in object_ids:
-                entry = self._objects.pop(oid, None)
+                key = oid.binary()
+                entry = self._objects.pop(key, None)
                 if isinstance(entry, ShmEntry) and entry.pins > 0:
                     # a worker still holds a descriptor: defer the free
                     # until the last unpin (plasma: delete waits for the
                     # in-use count to drop)
-                    self._zombies[(oid, entry.offset)] = entry
+                    self._zombies[(key, entry.offset)] = entry
                     continue
                 self._release_entry(entry)
 
@@ -449,16 +461,17 @@ class MemoryStore:
         with self._cv:
             for p in pins:
                 oid, off = p if isinstance(p, tuple) else (p, None)
-                entry = self._objects.get(oid)
+                key = oid.binary()
+                entry = self._objects.get(key)
                 if isinstance(entry, ShmEntry) and \
                         (off is None or entry.offset == off):
                     if entry.pins > 0:
                         entry.pins -= 1
                     continue
                 if off is not None:
-                    zkey = (oid, off)
+                    zkey = (key, off)
                 else:       # id-only unpin: any zombie of this object
-                    zkey = next((k for k in self._zombies if k[0] == oid),
+                    zkey = next((k for k in self._zombies if k[0] == key),
                                 None)
                 z = self._zombies.get(zkey) if zkey is not None else None
                 if z is not None:
@@ -489,8 +502,9 @@ class MemoryStore:
         """(kind, size): kind is "shm" | "spill" (plasma-routed, has
         directory locations), "inband" (ships with specs), or None
         (absent)."""
+        key = object_id.binary()
         with self._cv:
-            e = self._objects.get(object_id)
+            e = self._objects.get(key)
             if isinstance(e, ShmEntry):
                 return "shm", e.size
             if isinstance(e, SpillEntry):
@@ -499,16 +513,17 @@ class MemoryStore:
                 return "remote", e.size
             return (None, 0) if e is None else ("inband", 0)
 
-    def plasma_sizes(self, object_ids: list):
-        """``plasma_info`` of many ids in one pass: an int64 per id, its
-        size where its kind is in ``PLASMA_KINDS``, -1 where it is
-        in-band or absent.  The lock is taken once per ``LOOKUP_SLICE``
-        ids, for the dict reads only (an entry's size never changes)."""
+    def plasma_sizes(self, keys: list[bytes]):
+        """``plasma_info`` of many ids in one pass, given as their raw
+        keys (``ObjectID.binary()``): an int64 per id, its size where its
+        kind is in ``PLASMA_KINDS``, -1 where it is in-band or absent.
+        The lock is taken once per ``LOOKUP_SLICE`` ids, for the dict
+        reads only (an entry's size never changes)."""
         import numpy as np
         from .object_directory import LOOKUP_SLICE
         entries: list = []
-        for lo in range(0, len(object_ids), LOOKUP_SLICE):
-            chunk = object_ids[lo:lo + LOOKUP_SLICE]
+        for lo in range(0, len(keys), LOOKUP_SLICE):
+            chunk = keys[lo:lo + LOOKUP_SLICE]
             with self._cv:
                 entries.extend(map(self._objects.get, chunk))
         plasma = _PLASMA_ENTRIES
@@ -521,49 +536,50 @@ class MemoryStore:
         hanging (reference: lost plasma objects raise ObjectLostError).
         The only sanctioned break of seal-once immutability; pinned
         blocks park as zombies until their descriptors release."""
+        key = object_id.binary()
         with self._cv:
-            entry = self._objects.get(object_id)
+            entry = self._objects.get(key)
             if isinstance(entry, ShmEntry) and entry.pins > 0:
-                self._zombies[(object_id, entry.offset)] = entry
+                self._zombies[(key, entry.offset)] = entry
             else:
                 self._release_entry(entry)
-            self._objects[object_id] = error
-            listeners = self._listeners.pop(object_id, ())
+            self._objects[key] = error
+            listeners = self._listeners.pop(key, ())
             self._cv.notify_all()
         for cb in listeners:
             cb(object_id)
 
     # -- materialization ----------------------------------------------------
-    def _value_locked(self, object_id: ObjectID):
+    def _value_locked(self, key: bytes):
         """Deserialize/restore an entry into a Python value; touches LRU."""
-        entry = self._objects[object_id]
-        self._objects.move_to_end(object_id)
+        entry = self._objects[key]
+        self._objects.move_to_end(key)
         if isinstance(entry, RemoteEntry):
             raise RemoteObjectUnavailable(
-                f"object {object_id.hex()[:12]} is resident on a remote "
+                f"object {key.hex()[:12]} is resident on a remote "
                 "plane; pull it first")
         if isinstance(entry, SpillEntry):
-            entry = self._restore_locked(object_id, entry)
+            entry = self._restore_locked(key, entry)
             if isinstance(entry, bytes):
                 return deserialize(entry)
         if isinstance(entry, ShmEntry):
             return deserialize(self.arena.view(entry.offset, entry.size))
         return entry
 
-    def _descriptor_locked(self, object_id: ObjectID):
+    def _descriptor_locked(self, key: bytes):
         """Wire form for worker replies: ("v", value) in-band, or
         ("s", offset, size) for zero-copy shm reads.  Spilled objects are
         restored first; if the arena can't take them, bytes go in-band.
         Shm descriptors PIN the entry — the caller owes one
         ``unpin([object_id])`` once the worker is done with the block."""
-        entry = self._objects[object_id]
-        self._objects.move_to_end(object_id)
+        entry = self._objects[key]
+        self._objects.move_to_end(key)
         if isinstance(entry, RemoteEntry):
             raise RemoteObjectUnavailable(
-                f"object {object_id.hex()[:12]} is resident on a remote "
+                f"object {key.hex()[:12]} is resident on a remote "
                 "plane; pull it first")
         if isinstance(entry, SpillEntry):
-            entry = self._restore_locked(object_id, entry)
+            entry = self._restore_locked(key, entry)
             if isinstance(entry, bytes):
                 return ("b", entry)
         if isinstance(entry, ShmEntry):
@@ -573,15 +589,16 @@ class MemoryStore:
 
     # -- read ---------------------------------------------------------------
     def contains(self, object_id: ObjectID) -> bool:
+        key = object_id.binary()
         with self._cv:
-            return object_id in self._objects
+            return key in self._objects
 
-    def _await_locked(self, object_ids: Sequence[ObjectID],
+    def _await_locked(self, keys: list[bytes],
                       deadline: float | None) -> bool:
-        """Wait (caller holds lock) until all ids exist. False on timeout."""
+        """Wait (caller holds lock) until all keys exist. False on
+        timeout."""
         while True:
-            missing = [o for o in object_ids if o not in self._objects]
-            if not missing:
+            if all(k in self._objects for k in keys):
                 return True
             if deadline is not None:
                 remaining = deadline - _clk.monotonic()
@@ -595,13 +612,14 @@ class MemoryStore:
             timeout: float | None = None) -> list:
         """Blocking fetch of all ids (in order). Raises stored errors."""
         deadline = None if timeout is None else _clk.monotonic() + timeout
+        keys = [o.binary() for o in object_ids]
         with self._cv:
-            if not self._await_locked(object_ids, deadline):
-                missing = sum(o not in self._objects for o in object_ids)
+            if not self._await_locked(keys, deadline):
+                missing = sum(k not in self._objects for k in keys)
                 raise GetTimeoutError(
-                    f"get timed out; {missing} of {len(object_ids)} "
+                    f"get timed out; {missing} of {len(keys)} "
                     "objects not ready")
-            values = [self._value_locked(o) for o in object_ids]
+            values = [self._value_locked(k) for k in keys]
         for v in values:
             if isinstance(v, RayTaskError):
                 raise v.cause if v.cause is not None else v
@@ -612,9 +630,11 @@ class MemoryStore:
              ) -> tuple[list[ObjectID], list[ObjectID]]:
         """ray.wait semantics: (ready, not_ready), order-preserving."""
         deadline = None if timeout is None else _clk.monotonic() + timeout
+        keys = [o.binary() for o in object_ids]
         with self._cv:
             while True:
-                ready = [o for o in object_ids if o in self._objects]
+                ready = [o for o, k in zip(object_ids, keys)
+                         if k in self._objects]
                 if len(ready) >= num_returns:
                     break
                 if deadline is not None:
@@ -636,8 +656,9 @@ class MemoryStore:
         metadata-only RemoteEntry); no materialization.  False on
         timeout."""
         deadline = None if timeout is None else _clk.monotonic() + timeout
+        keys = [o.binary() for o in object_ids]
         with self._cv:
-            return self._await_locked(object_ids, deadline)
+            return self._await_locked(keys, deadline)
 
     def get_raw_blocking(self, object_ids: Sequence[ObjectID],
                          timeout: float | None = None) -> list | None:
@@ -645,10 +666,11 @@ class MemoryStore:
         are returned as values (the worker-side get re-raises them).
         Returns None on timeout."""
         deadline = None if timeout is None else _clk.monotonic() + timeout
+        keys = [o.binary() for o in object_ids]
         with self._cv:
-            if not self._await_locked(object_ids, deadline):
+            if not self._await_locked(keys, deadline):
                 return None
-            return [self._value_locked(o) for o in object_ids]
+            return [self._value_locked(k) for k in keys]
 
     def get_descriptors_blocking(self, object_ids: Sequence[ObjectID],
                                  timeout: float | None = None
@@ -657,20 +679,23 @@ class MemoryStore:
         objects ship as (offset, size) for zero-copy reads, small ones as
         in-band values.  Returns None on timeout."""
         deadline = None if timeout is None else _clk.monotonic() + timeout
+        keys = [o.binary() for o in object_ids]
         with self._cv:
-            if not self._await_locked(object_ids, deadline):
+            if not self._await_locked(keys, deadline):
                 return None
-            return [self._descriptor_locked(o) for o in object_ids]
+            return [self._descriptor_locked(k) for k in keys]
 
     def peek(self, object_id: ObjectID):
         """Non-blocking read (materializes); KeyError if absent."""
+        key = object_id.binary()
         with self._cv:
-            return self._value_locked(object_id)
+            return self._value_locked(key)
 
     def descriptor_of(self, object_id: ObjectID):
         """Non-blocking wire descriptor; KeyError if absent."""
+        key = object_id.binary()
         with self._cv:
-            return self._descriptor_locked(object_id)
+            return self._descriptor_locked(key)
 
     def error_of(self, object_id: ObjectID):
         """Non-blocking: the stored ``RayTaskError`` if this object's
@@ -678,8 +703,9 @@ class MemoryStore:
         always stored in-band (never shm/spill), so this never
         materializes a data payload — completion observers use it to
         classify a sealed result without paying a deserialize."""
+        key = object_id.binary()
         with self._cv:
-            e = self._objects.get(object_id)
+            e = self._objects.get(key)
         return e if isinstance(e, RayTaskError) else None
 
     # -- listeners (dependency manager hook) --------------------------------
@@ -687,9 +713,10 @@ class MemoryStore:
                  callback: Callable[[ObjectID], None]) -> None:
         """Invoke ``callback(oid)`` once the object exists (immediately if
         it already does). Callback runs without the store lock held."""
+        key = object_id.binary()
         with self._cv:
-            if object_id not in self._objects:
-                self._listeners.setdefault(object_id, []).append(callback)
+            if key not in self._objects:
+                self._listeners.setdefault(key, []).append(callback)
                 return
         callback(object_id)
 
@@ -697,15 +724,16 @@ class MemoryStore:
         """Deregister a pending ``on_ready`` listener (no-op if it already
         fired or was never registered) — abandoning waiters must not leak
         closures."""
+        key = object_id.binary()
         with self._cv:
-            lst = self._listeners.get(object_id)
+            lst = self._listeners.get(key)
             if lst is not None:
                 try:
                     lst.remove(callback)
                 except ValueError:
                     return
                 if not lst:
-                    del self._listeners[object_id]
+                    del self._listeners[key]
 
     # -- introspection ------------------------------------------------------
     def size(self) -> int:
@@ -717,7 +745,8 @@ class MemoryStore:
         is known for shm/spilled entries, -1 for in-band values."""
         with self._cv:
             out = []
-            for oid, entry in self._objects.items():
+            for key, entry in self._objects.items():
+                oid = ObjectID(key)
                 if isinstance(entry, ShmEntry):
                     out.append((oid, entry.size, "shm"))
                 elif isinstance(entry, SpillEntry):

@@ -968,15 +968,15 @@ class Raylet:
         rows = np.full(len(specs), -1, np.int64)
         if not specs or not get_config().locality_aware_scheduling:
             return rows, 0
-        ids: list = []
+        keys: list[bytes] = []      # the args' raw id keys
         per_spec: list[int] = []
         for s in specs:
-            refs = [a.id for a in s.args if isinstance(a, ObjectRef)]
+            refs = [a.binary() for a in s.args if isinstance(a, ObjectRef)]
             per_spec.append(len(refs))
-            ids += refs
-        sizes = self.store.plasma_sizes(ids)
+            keys += refs
+        sizes = self.store.plasma_sizes(keys)
         plasma = sizes >= 0
-        kept = list(compress(ids, plasma.tolist()))
+        kept = list(compress(keys, plasma.tolist()))
         n_locs, loc_rows = self.cluster.directory.location_rows(kept)
         if loc_rows.size:
             owner = np.repeat(np.arange(len(specs)), per_spec)[plasma]

@@ -289,6 +289,21 @@ class TestBroadcastManager:
         assert s["bcast_members_reached"] == 2
         assert s["bcast_time_to_all_ewma_s"] > 0
 
+    def test_broadcast_of_a_ref_moves_its_object(self, mgr_cluster):
+        """A ref is broadcast as its id (rollout passes the ref)."""
+        from ray_tpu.runtime.object_ref import ObjectRef
+        Config.reset({"broadcast_chunk_mb": 1})
+        c, eps = mgr_cluster
+        payload = _payload(2 << 20)
+        oid = _oid()
+        eps[0].seal(oid, payload)
+        c.directory.add_location(oid, 0)
+        res = c.broadcasts.broadcast(ObjectRef(oid), node_rows=[2])
+        assert res["ok"], res
+        assert res["members"] == 1 and res["reached"] == 1
+        assert c.directory.locations(oid) == (0, 2)
+        assert eps[2].store.peek(oid) == payload
+
     def test_concurrent_pull_joins_active_tree(self, mgr_cluster):
         """Satellite: a pull arriving while a tree is active grafts on
         as a leaf — bytes flow over ``bc_fetch``, never ``op_fetch``,

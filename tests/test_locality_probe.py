@@ -65,7 +65,7 @@ def raylet(store):
 def _shm(store, directory, rows, n=10_000):
     oid = ObjectID.from_random()
     store.put_serialized(oid, serialize(b"s" * n))
-    assert isinstance(store._objects[oid], ShmEntry)
+    assert isinstance(store._objects[oid.binary()], ShmEntry)
     for r in rows:
         directory.add_location(oid, r)
     return oid
@@ -74,7 +74,7 @@ def _shm(store, directory, rows, n=10_000):
 def _spill(store, directory, rows):
     oid = ObjectID.from_random()
     store.put_serialized(oid, serialize(b"d" * (CAP + 1)))   # past the arena
-    assert isinstance(store._objects[oid], SpillEntry)
+    assert isinstance(store._objects[oid.binary()], SpillEntry)
     for r in rows:
         directory.add_location(oid, r)
     return oid
@@ -388,13 +388,14 @@ def test_batched_reads_match_single_reads(store, change):
     ids = _objects(store, directory)
     change(store, directory, ids)
 
-    sizes = store.plasma_sizes(ids)
+    keys = [oid.binary() for oid in ids]
+    sizes = store.plasma_sizes(keys)
     assert sizes.dtype == np.int64 and sizes.shape == (len(ids),)
     for oid, size in zip(ids, sizes.tolist()):
         kind, want = store.plasma_info(oid)
         assert size == (want if kind in PLASMA_KINDS else -1)
 
-    counts, rows = directory.location_rows(ids)
+    counts, rows = directory.location_rows(keys)
     assert counts.dtype == rows.dtype == np.int64
     assert counts.shape == (len(ids),) and rows.shape == (counts.sum(),)
     starts = np.concatenate([[0], np.cumsum(counts)])

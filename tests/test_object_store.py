@@ -46,7 +46,7 @@ def _oid() -> ObjectID:
 def test_small_payload_stays_in_band(store):
     oid = _oid()
     store.put_serialized(oid, _payload(10))
-    assert isinstance(store._objects[oid], bytes)  # deserialized value
+    assert isinstance(store._objects[oid.binary()], bytes)  # deserialized value
     assert store.stats()["num_shm"] == 0
     assert store.get([oid])[0] == b"x" * 10
 
@@ -54,7 +54,7 @@ def test_small_payload_stays_in_band(store):
 def test_large_payload_routes_to_arena(store):
     oid = _oid()
     store.put_serialized(oid, _payload(10_000))
-    assert isinstance(store._objects[oid], ShmEntry)
+    assert isinstance(store._objects[oid.binary()], ShmEntry)
     assert store.stats()["arena_bytes_in_use"] > 0
     assert store.get([oid])[0] == b"x" * 10_000
 
@@ -116,7 +116,7 @@ def test_oversized_payload_seals_via_disk(store):
     every waiter)."""
     oid = _oid()
     store.put_serialized(oid, serialize(b"z" * (2 * CAP)))
-    assert isinstance(store._objects[oid], SpillEntry)
+    assert isinstance(store._objects[oid.binary()], SpillEntry)
     assert store.get([oid], timeout=1)[0] == b"z" * (2 * CAP)
 
 
@@ -147,7 +147,7 @@ def test_pinned_object_survives_spill_pressure(store):
     # arena several times over
     for i in range(40):
         store.put_serialized(_oid(), serialize(bytes([i]) * 150_000))
-    entry = store._objects[pinned_oid]
+    entry = store._objects[pinned_oid.binary()]
     assert isinstance(entry, ShmEntry), "pinned entry must not be spilled"
     assert bytes(store.arena.view(desc[1], desc[2])) == payload, \
         "pinned block was reallocated under a live descriptor"
@@ -155,7 +155,7 @@ def test_pinned_object_survives_spill_pressure(store):
     store.unpin([pinned_oid])
     for i in range(10):
         store.put_serialized(_oid(), serialize(bytes([i]) * 150_000))
-    assert isinstance(store._objects[pinned_oid], SpillEntry), \
+    assert isinstance(store._objects[pinned_oid.binary()], SpillEntry), \
         "unpinned LRU entry should spill under pressure"
     assert store.get([pinned_oid])[0] == b"precious" * 20_000
 
@@ -167,7 +167,7 @@ def test_unpinned_spill_would_corrupt(store):
     oid = _oid()
     payload = serialize(b"precious" * 20_000)
     store.put_serialized(oid, payload)
-    entry = store._objects[oid]
+    entry = store._objects[oid.binary()]
     off, size = entry.offset, entry.size            # descriptor, unpinned
     for i in range(40):
         store.put_serialized(_oid(), serialize(bytes([i]) * 150_000))
@@ -195,10 +195,10 @@ def test_pin_counts_are_per_descriptor(store):
     store.descriptor_of(oid)
     store.descriptor_of(oid)                        # two handouts
     store.unpin([oid])
-    assert store._objects[oid].pins == 1
+    assert store._objects[oid.binary()].pins == 1
     assert not store._spill_one_locked()            # still pinned
     store.unpin([oid])
-    assert store._objects[oid].pins == 0
+    assert store._objects[oid.binary()].pins == 0
 
 
 def test_unpin_with_offset_targets_zombie_not_reput(store):
@@ -214,9 +214,9 @@ def test_unpin_with_offset_targets_zombie_not_reput(store):
     assert desc_new[1] != desc_old[1]               # distinct blocks
     store.unpin([(oid, desc_old[1])])               # old descriptor done
     assert not store._zombies                       # zombie freed
-    assert store._objects[oid].pins == 1            # new pin untouched
+    assert store._objects[oid.binary()].pins == 1            # new pin untouched
     store.unpin([(oid, desc_new[1])])
-    assert store._objects[oid].pins == 0
+    assert store._objects[oid.binary()].pins == 0
 
 
 # -- concurrency stress ----------------------------------------------------
