@@ -180,6 +180,91 @@ def _localized_group(avail, totals, node_mask, req, count, gmask, pref,
     return rest.at[p].add(m), avail3
 
 
+# A SPREAD row's capacity is avail // req <= MAX_TOTAL_CU = 2**17 (the
+# contract's width audit), so its pass bisection needs 18 steps.
+_CAP_BITS = 18
+
+
+def _rotation_rank(elig, start):
+    """(each row's 0-based place among the ``elig`` rows in rotation
+    order ``start, start+1, ..., 0, ..., start-1``; the number of
+    ``elig`` rows).  Meaningless where ``elig`` is False."""
+    e = elig.astype(jnp.int32)
+    before = jnp.cumsum(e) - e
+    rows = jnp.arange(e.shape[0], dtype=jnp.int32)
+    skipped = jnp.where(rows < start, e, 0).sum()
+    total = e.sum()
+    return jnp.where(rows >= start, before - skipped,
+                     before + total - skipped), total
+
+
+def _last_in_rotation(elig, start):
+    """The last ``elig`` row in rotation order from ``start`` (0 when
+    there is none)."""
+    rank, total = _rotation_rank(elig, start)
+    return jnp.argmax(elig & (rank == total - 1)).astype(jnp.int32)
+
+
+def _spread_group(avail, cursor, totals, node_mask, req, count, n_rows):
+    """One SPREAD subgroup from the round-robin ``cursor``: the closed
+    form of ``SpreadSchedulingPolicy`` run task by task.
+
+    Available phase: row ``i`` fits ``cap_i = min(avail_i // req,
+    count)`` tasks (0 where masked out or infeasible; a zero request
+    fits none here and goes whole to the feasible phase, where it lands
+    alike).  Pass ``p`` gives one task to each row with ``cap_i >= p``
+    in rotation order from the cursor, so the counts are ``min(cap_i,
+    T)`` for the largest ``T`` they do not exceed the group with, plus
+    one for the first rows in rotation order with ``cap_i > T``
+    (bisection over ``T``); they debit ``avail``.  Feasible phase: the
+    rest go round-robin over the feasible rows from the cursor the
+    available phase left, with no debit.  The cursor ends one past the
+    last task's row, modulo ``n_rows`` (the CRM's row count, not the
+    pad).
+
+    Returns (counts_row (N+1,) — column N counts tasks no row holds —,
+    the feasible phase's counts (N,), new_avail, new cursor)."""
+    n = totals.shape[0]
+    req_pos = req > 0
+    feas = jnp.all(jnp.where(req_pos[None, :], totals >= req[None, :], True),
+                   axis=1) & node_mask
+    caps = jnp.where(req_pos[None, :],
+                     avail // jnp.maximum(req, 1)[None, :], _BIG).min(axis=1)
+    cap = jnp.where(feas & req_pos.any(), jnp.clip(caps, 0, count), 0)
+    k_avail = jnp.minimum(count, cap.sum())
+
+    # the largest T in [0, max cap] with sum(min(cap, T)) <= k_avail
+    def bisect(carry, _):
+        lo, hi = carry
+        mid = (lo + hi + 1) // 2
+        ok = jnp.minimum(cap, mid).sum() <= k_avail
+        return (jnp.where(ok, mid, lo), jnp.where(ok, hi, mid - 1)), None
+
+    (t, _), _ = jax.lax.scan(bisect, (jnp.int32(0), cap.max()), None,
+                             length=_CAP_BITS)
+    base = jnp.minimum(cap, t)
+    over = cap > t
+    rank, _ = _rotation_rank(over, cursor)
+    alloc = base + (over & (rank < k_avail - base.sum())).astype(jnp.int32)
+    new_avail = avail - alloc[:, None] * req[None, :]
+    top = alloc.max()
+    last = _last_in_rotation((alloc == top) & (top > 0), cursor)
+    cur = jnp.where(k_avail > 0, (last + 1) % n_rows, cursor)
+
+    rest = count - k_avail
+    n_feas = feas.sum()
+    per = jnp.maximum(n_feas, 1)
+    rank_f, _ = _rotation_rank(feas, cur)
+    queued = jnp.where(feas, rest // per + (rank_f < rest % per), 0)
+    last_f = jnp.argmax(feas & (rank_f == (rest - 1) % per))
+    cur = jnp.where((rest > 0) & (n_feas > 0), (last_f + 1) % n_rows, cur)
+
+    counts_row = jnp.zeros(n + 1, jnp.int32).at[:n].set(alloc + queued)
+    counts_row = counts_row.at[n].add(jnp.where(n_feas == 0, rest, 0))
+    return counts_row, queued.astype(jnp.int32), new_avail, \
+        cur.astype(jnp.int32)
+
+
 @partial(jax.jit, static_argnames=("unroll", "require_available"))
 def schedule_grouped(totals, avail, node_mask, group_reqs, group_counts,
                      group_masks, thr_fp, unroll: int = 1,
@@ -327,6 +412,70 @@ def schedule_group_host(avail, totals, node_mask, req, count,
     return counts_row, new_avail
 
 
+def _rotation_rank_host(elig, start):
+    """Pure-numpy twin of ``_rotation_rank``."""
+    e = elig.astype(np.int64)
+    before = np.cumsum(e) - e
+    skipped = int(e[:start].sum())
+    total = int(e.sum())
+    rows = np.arange(e.shape[0])
+    return np.where(rows >= start, before - skipped,
+                    before + total - skipped), total
+
+
+def spread_group_host(avail, totals, node_mask, req, count, cursor,
+                      n_rows=None):
+    """Pure-NUMPY twin of ``_spread_group`` (one SPREAD subgroup from
+    the round-robin ``cursor``; ``n_rows`` defaults to the row count):
+    the raylet's host twin places a SPREAD subgroup with it.
+
+    Returns ``(counts_row (N+1,) int32, feasible-phase counts (N,)
+    int64, new_avail (N, R) int64, new cursor)``."""
+    totals = np.asarray(totals, np.int64)
+    avail = np.asarray(avail, np.int64)
+    req = np.asarray(req, np.int64)
+    n = totals.shape[0]
+    n_rows = n if n_rows is None else int(n_rows)
+    cursor, count = int(cursor) % n_rows, int(count)
+    req_pos = req > 0
+    feas = np.where(req_pos[None, :], totals >= req[None, :],
+                    True).all(axis=1) & np.asarray(node_mask, bool)
+    caps = np.where(req_pos[None, :], avail // np.maximum(req, 1)[None, :],
+                    _BIG).min(axis=1)
+    cap = np.where(feas & req_pos.any(), caps.clip(0, count), 0)
+    k_avail = min(count, int(cap.sum()))
+
+    lo, hi = 0, int(cap.max(initial=0))
+    while lo < hi:
+        mid = (lo + hi + 1) // 2
+        if int(np.minimum(cap, mid).sum()) <= k_avail:
+            lo = mid
+        else:
+            hi = mid - 1
+    base = np.minimum(cap, lo)
+    over = cap > lo
+    rank, _ = _rotation_rank_host(over, cursor)
+    alloc = base + (over & (rank < k_avail - int(base.sum())))
+    new_avail = avail - alloc[:, None] * req[None, :]
+    if k_avail:
+        top = alloc == alloc.max()
+        rank, total = _rotation_rank_host(top, cursor)
+        cursor = (int(np.argmax(top & (rank == total - 1))) + 1) % n_rows
+
+    rest = count - k_avail
+    n_feas = int(feas.sum())
+    queued = np.zeros(n, np.int64)
+    if rest and n_feas:
+        rank, _ = _rotation_rank_host(feas, cursor)
+        queued = np.where(feas, rest // n_feas + (rank < rest % n_feas), 0)
+        last = int(np.argmax(feas & (rank == (rest - 1) % n_feas)))
+        cursor = (last + 1) % n_rows
+    counts_row = np.zeros(n + 1, np.int32)
+    counts_row[:n] = alloc + queued
+    counts_row[n] = 0 if n_feas else rest
+    return counts_row, queued, new_avail, cursor
+
+
 # -- delta-heartbeat kernels --------------------------------------------------
 #
 # The heartbeat keeps three residents in HBM between beats: the CRM mirror
@@ -399,7 +548,7 @@ def apply_dirty_classes(totals, avail, mask, keys, reqs, idx, class_reqs,
 def fused_beat(totals, avail, mask, keys, reqs, class_slots, group_counts,
                extra_mask, ov_idx, ov_avail, thr_fp,
                require_available=False, pref_rows=None, avoid=None,
-               local_row=None):
+               local_row=None, spread=None, cursor=None, n_rows=None):
     """One heartbeat against the resident mirror: per-beat ephemeral row
     overrides (the raylet's planned-load debits), an extra soft mask
     (suspect avoidance), the grouped water-fill, and the per-class argmin
@@ -421,37 +570,74 @@ def fused_beat(totals, avail, mask, keys, reqs, class_slots, group_counts,
     preferred row takes the whole group, an avoid group keeps off
     ``local_row``, and avoid wins over a preferred row.
 
+    The SPREAD variant (``spread`` given; a program of its own): spread
+    (G,) bool marks the groups placed by ``_spread_group``'s round-robin
+    fill instead of the water-fill, from the int32 scalar ``cursor``
+    (rotation modulo the int32 scalar ``n_rows``), which the groups
+    carry in order beside ``avail``.  Which groups are SPREAD is data:
+    any mix compiles once per bucket.
+
     Returns (packed (G + C, N+1) int32 — rows [:G] are the water-fill
     counts with the overflow column, rows [G:] the per-class lease
-    budgets (zero overflow column) — and argmin_rows (C,) int32)."""
+    budgets (zero overflow column); the SPREAD variant appends G rows:
+    each group's feasible-phase counts with, in column N, the cursor
+    after the group — and argmin_rows (C,) int32)."""
     avail_eff = avail.at[ov_idx].set(ov_avail, mode="drop")
     mask_eff = mask & extra_mask
     group_reqs = reqs[jnp.clip(class_slots, 0, reqs.shape[0] - 1)]
     n = totals.shape[0]
     ones = jnp.ones((n,), bool)
 
-    if pref_rows is None:
+    node_rows = jnp.arange(n, dtype=jnp.int32)
+
+    def hybrid(av, req, count, pref, avd):
+        if pref_rows is None:
+            return _schedule_group(av, totals, mask_eff, req, count, ones,
+                                   thr_fp, require_available)
+        gmask = ~(avd & (node_rows == local_row))
+        return _localized_group(
+            av, totals, mask_eff, req, count, gmask,
+            jnp.where(avd, -1, pref), thr_fp, require_available)
+
+    if spread is None and pref_rows is None:
         def step(av, xs):
             req, count = xs
-            row, new_av = _schedule_group(av, totals, mask_eff, req, count,
-                                          ones, thr_fp, require_available)
+            row, new_av = hybrid(av, req, count, None, None)
             return new_av, row
 
         av_fin, counts = jax.lax.scan(step, avail_eff,
                                       (group_reqs, group_counts))
-    else:
-        node_rows = jnp.arange(n, dtype=jnp.int32)
-
+    elif spread is None:
         def step(av, xs):
-            req, count, pref, avd = xs
-            gmask = ~(avd & (node_rows == local_row))
-            row, new_av = _localized_group(
-                av, totals, mask_eff, req, count, gmask,
-                jnp.where(avd, -1, pref), thr_fp, require_available)
+            row, new_av = hybrid(av, *xs)
             return new_av, row
 
         av_fin, counts = jax.lax.scan(
             step, avail_eff, (group_reqs, group_counts, pref_rows, avoid))
+    else:
+        g = group_counts.shape[0]
+        loc = ((jnp.full((g,), -1, jnp.int32), jnp.zeros((g,), bool))
+               if pref_rows is None else (pref_rows, avoid))
+
+        def step(carry, xs):
+            av, cur = carry
+            req, count, pref, avd, is_spread = xs
+
+            def spread_fill(av, cur):
+                return _spread_group(av, cur, totals, mask_eff, req, count,
+                                     n_rows)
+
+            def water_fill(av, cur):
+                row, new_av = hybrid(av, req, count, pref, avd)
+                return row, jnp.zeros((n,), jnp.int32), new_av, cur
+
+            row, queued, new_av, cur = jax.lax.cond(
+                is_spread, spread_fill, water_fill, av, cur)
+            return (new_av, cur), (row, jnp.concatenate([queued, cur[None]]))
+
+        (av_fin, _), (counts, spread_rows) = jax.lax.scan(
+            step, (avail_eff, jnp.int32(cursor) % n_rows),
+            (group_reqs, group_counts, *loc, spread))
 
     # Lease budgets off the post-beat avail.  Clamp >= 0 before the floor
     # division (contract: numpy and XLA disagree on negative ``//``), and
@@ -472,6 +658,8 @@ def fused_beat(totals, avail, mask, keys, reqs, class_slots, group_counts,
     budgets = jax.vmap(budget_row)(reqs).astype(jnp.int32)          # (C, N)
     packed = jnp.concatenate(
         [counts, jnp.pad(budgets, ((0, 0), (0, 1)))], axis=0)       # +1 col
+    if spread is not None:
+        packed = jnp.concatenate([packed, spread_rows], axis=0)
     amin = jnp.argmin(keys, axis=1).astype(jnp.int32)
     return packed, amin
 

@@ -7,12 +7,14 @@ Reference parity: the raylet's ``NodeManager`` + ``ClusterTaskManager``
 until args exist) + spillback to the chosen remote raylet — ``src/ray/
 raylet/``, SURVEY.md §1 layer 4 / §3.2 hot loop; mount empty.
 
-TPU-first: when a scheduling round's batch is big enough and uniformly
-default-strategy, the WHOLE batch is placed by the device water-fill kernel
-(``ray_tpu.ops.schedule_grouped``) in one call — the north-star data plane
-running inside the live runtime.  Small or mixed batches take the per-task
-CPU policy, which is bit-identical by the parity contract, so the switch is
-invisible to callers (``scheduler_device_backend`` config).
+TPU-first: when a scheduling round's batch is big enough and all its
+strategies are DEFAULT or SPREAD, the WHOLE batch is placed by one delta
+beat on the device (``DeltaScheduler.beat``: the water-fill for DEFAULT
+subgroups, the round-robin fill for SPREAD ones) — the north-star data
+plane running inside the live runtime.  Small batches take its host twin;
+batches with another strategy take the per-task CPU policy.  All three are
+bit-identical by the parity contract, so the switch is invisible to
+callers (``scheduler_device_backend`` config).
 
 The scheduling loop is event-driven (condition variable, not a busy tick):
 it wakes on task arrival, dependency readiness, worker release, and
@@ -102,26 +104,61 @@ _PROBE = object()   # ``_subgroup``: the batch needs the locality probe
 
 
 class _Subgroups(NamedTuple):
-    """A round's (scheduling class, locality row, avoid flag) subgroups
-    in order of first appearance (``Raylet._subgroup``)."""
+    """A round's (scheduling class, strategy, locality row, avoid flag)
+    subgroups in order of first appearance (``Raylet._subgroup``).  A
+    SPREAD subgroup has no locality row and no avoid flag; its tasks
+    rotate from the raylet's one SPREAD cursor
+    (``SpreadSchedulingPolicy.cursor``)."""
     task_group: np.ndarray      # (T,) int32: each task's subgroup
     counts: np.ndarray          # (G,) tasks per subgroup
     first: list                 # per subgroup: index of its first task
     prefs: list                 # per subgroup: locality row, -1 for none
     avoids: list                # per subgroup: keep off this node
+    spread: list                # per subgroup: the SPREAD strategy
 
 
-def _expand(task_group: np.ndarray, counts: np.ndarray) -> list[int]:
+def _expand(task_group: np.ndarray, counts: np.ndarray,
+            orders: dict | None = None) -> list[int]:
     """Per-task rows from (G, N+1) placement counts (column N counts the
     unplaced): the tasks of a subgroup, in batch order, take its node
-    rows ascending, then -1 (tasks of one class are interchangeable)."""
+    rows ascending, then -1 (tasks of one class are interchangeable);
+    those of a subgroup in ``orders`` ({subgroup: its rows in placement
+    order}, ``_spread_order``) take those rows in turn."""
     n_groups, width = counts.shape
     cols = np.arange(width, dtype=np.int32)
     cols[-1] = -1
     rows = np.empty(task_group.shape[0], dtype=np.int32)
-    rows[np.argsort(task_group, kind="stable")] = np.repeat(
-        np.tile(cols, n_groups), counts.ravel())
+    seq = np.repeat(np.tile(cols, n_groups), counts.ravel())
+    if orders:
+        ends = np.cumsum(counts.sum(axis=1))
+        for g, order in orders.items():
+            seq[ends[g] - order.size:ends[g]] = order
+    rows[np.argsort(task_group, kind="stable")] = seq
     return rows.tolist()
+
+
+def _rotation_order(counts: np.ndarray, start: int, n: int) -> np.ndarray:
+    """The rows a SPREAD phase gives its tasks, in turn: pass ``p``
+    visits each row with ``counts >= p`` once, in rotation order from
+    ``start`` (``start, ..., n-1, 0, ..., start-1``)."""
+    rows = np.flatnonzero(counts)
+    reps = counts[rows]
+    seq = np.repeat(rows, reps)
+    passes = np.arange(seq.size) - np.repeat(np.cumsum(reps) - reps, reps)
+    return seq[np.argsort(passes * n + (seq - start) % n)]
+
+
+def _spread_order(row_counts: np.ndarray, queued: np.ndarray, start: int,
+                  n: int) -> np.ndarray:
+    """A SPREAD subgroup's rows in placement order, as
+    ``SpreadSchedulingPolicy`` hands them out task by task from cursor
+    ``start``: the available phase (``row_counts`` less the feasible
+    phase's ``queued``) pass by pass, then the feasible phase from the
+    cursor it left, then -1 for the tasks no row holds."""
+    first = _rotation_order(row_counts[:n] - queued, start, n)
+    cur = (int(first[-1]) + 1) % n if first.size else start
+    return np.concatenate([first, _rotation_order(queued, cur, n),
+                           np.full(row_counts[n], -1, np.int32)])
 
 
 class Raylet:
@@ -171,9 +208,13 @@ class Raylet:
         self._avoid_local: set[TaskID] = set()  # lease-spilled: skip here
         # subgrouping pass: tasks seen, those whose key needed a
         # locality probe or an avoid flag (the slow key), and the plasma
-        # args the round's probe looked up (under _cv)
+        # args the round's probe looked up; rounds that took the
+        # per-task CPU policy, and device-sized rounds with SPREAD
+        # subgroups that took the host twin (a sharded or top-k engine
+        # has no SPREAD program) (under _cv)
         self._group_stats = {"group_tasks": 0, "group_probed": 0,
-                             "locality_args": 0}
+                             "locality_args": 0, "per_task_rounds": 0,
+                             "spread_host_rounds": 0}
         # last _effective_snapshot soft-masked SUSPECT rows out (gray
         # failures): tells _schedule_rows a -1 deserves a full-cluster
         # fallback pass before parking the task
@@ -531,9 +572,9 @@ class Raylet:
         otherwise — bit-identical placements either way (parity contract).
         """
         cfg = get_config()
-        # ALL-DEFAULT batches take the subgroup route (device or its
-        # host twin): locality bias, lease-avoidance, and top-k are
-        # device surfaces too (ops/locality_kernel.py).  Both backends
+        # DEFAULT and SPREAD batches take the subgroup route (device or
+        # its host twin): locality bias, lease-avoidance, top-k and the
+        # SPREAD round-robin are device surfaces too.  Both backends
         # take the one subgrouping below, so scheduler_device_batch_min
         # is not observable in placements (top-k rounds excepted —
         # documented sampling divergence)
@@ -549,13 +590,22 @@ class Raylet:
             with phase("raylet.group"):
                 sub = self._subgroup(specs, locs)
         if sub is not None:
-            if (len(batch) >= cfg.scheduler_device_batch_min
-                    and len(sub.first) <= cfg.tpu_group_capacity):
+            device = (len(batch) >= cfg.scheduler_device_batch_min
+                      and len(sub.first) <= cfg.tpu_group_capacity)
+            if device and any(sub.spread) and (
+                    cfg.scheduler_top_k_fraction > 0
+                    or not self._engine().spread_on_device):
+                device = False
+                with self._cv:
+                    self._group_stats["spread_host_rounds"] += 1
+            if device:
                 return self._schedule_rows_device(specs, sub)
             return self._schedule_rows_host_subgrouped(specs, sub)
         # mixed-strategy batches: per-task CPU policy on a snapshot
         # (sequential within the round), partitioned by scheduling class
         # in first-appearance order; the round's probe runs once, first
+        with self._cv:
+            self._group_stats["per_task_rounds"] += 1
         if locs is None:
             with phase("raylet.locality"):
                 locs = self._locality_rows(specs)
@@ -578,10 +628,11 @@ class Raylet:
         return rows
 
     def _locality_rows(self, specs: list) -> dict:
-        """The round's locality probe, one pass before grouping:
-        {id(spec): ``_AVOID``} for a spec whose avoid flag is set (read,
-        not consumed), {id(spec): its locality row, -1 for none} for a
-        DEFAULT-strategy spec with args; other specs are not probed.
+        """The round's locality probe, one pass before grouping, over
+        the DEFAULT-strategy specs (no other strategy reads locality or
+        an avoid flag, ``_options_for``): {id(spec): ``_AVOID``} for a
+        spec whose avoid flag is set (read, not consumed), {id(spec):
+        its locality row, -1 for none} for one with args.
         ``locality_args`` counts the plasma args looked up."""
         default = SchedulingStrategyKind.DEFAULT
         avoid_set = self._avoid_local
@@ -589,7 +640,8 @@ class Raylet:
         probe = []
         for s in specs:
             if avoid_set and s.task_id in avoid_set:
-                locs[id(s)] = _AVOID
+                if s.strategy.kind is default:
+                    locs[id(s)] = _AVOID
             elif s.args and s.strategy.kind is default:
                 probe.append(s)
         rows, looked_up = self._locality_batch(probe)
@@ -602,42 +654,48 @@ class Raylet:
         """The round's one grouping pass, shared by the device path and
         its host twin: each task's subgroup, keyed (scheduling class,
         locality row, avoid flag) in first-appearance order.  None when
-        a non-DEFAULT strategy sends the batch to the per-class CPU
-        policy (no avoid flag is consumed then).
+        a strategy other than DEFAULT and SPREAD sends the batch to the
+        per-class CPU policy (no avoid flag is consumed then).
 
         The class is ``TaskSpec.scheduling_class()``, its strategy key
         built once per strategy object in the round (most specs share
-        ``DEFAULT_STRATEGY``).  Only a spec with args can have a
-        locality row and only a non-empty avoid set can flag one, so
-        the plain key skips both.  Without ``locs`` (the round's probe,
-        ``_locality_rows``) the pass stops at the first spec that needs
-        one and returns ``_PROBE``; ``group_probed`` counts the tasks
-        the probe flagged or gave a row to look up."""
+        ``DEFAULT_STRATEGY``), so SPREAD specs of a class form their own
+        subgroup.  Only a DEFAULT spec with args can have a locality
+        row and only a non-empty avoid set can flag one, so the plain
+        key skips both; a SPREAD spec takes neither and keeps its flag.
+        Without ``locs`` (the round's probe, ``_locality_rows``) the
+        pass stops at the first spec that needs one and returns
+        ``_PROBE``; ``group_probed`` counts the tasks the probe flagged
+        or gave a row to look up."""
         default = SchedulingStrategyKind.DEFAULT
-        strategy_slot: dict[int, int] = {}     # id(strategy) -> slot
+        spread_kind = SchedulingStrategyKind.SPREAD
+        # id(strategy) -> (slot, is SPREAD)
+        strategy_slot: dict[int, tuple[int, bool]] = {}
         strategy_keys: dict[tuple, int] = {}   # strategy key -> slot
         groups: dict[tuple, int] = {}
         first: list[int] = []
         prefs: list[int] = []
         avoids: list[bool] = []
+        spreads: list[bool] = []
         task_group: list[int] = []
         avoided: list[TaskID] = []
         avoid_set = self._avoid_local
         probed = 0
-        prev, slot = None, 0
+        prev, slot, is_spread = None, 0, False
         for s in specs:
             st = s.strategy
             if st is not prev:
                 prev = st
-                slot = strategy_slot.get(id(st))
-                if slot is None:
-                    if st.kind is not default:
+                got = strategy_slot.get(id(st))
+                if got is None:
+                    if st.kind is not default and st.kind is not spread_kind:
                         return None
-                    slot = strategy_slot[id(st)] = strategy_keys.setdefault(
-                        st.key(), len(strategy_keys))
+                    got = strategy_slot[id(st)] = (strategy_keys.setdefault(
+                        st.key(), len(strategy_keys)), st.kind is spread_kind)
+                slot, is_spread = got
             # the plain key (class, slot): no locality row, no flag
             key = (s.resources.key(), slot)
-            if s.args or avoid_set:
+            if (s.args or avoid_set) and not is_spread:
                 if locs is None:
                     return _PROBE
                 loc = locs.get(id(s))
@@ -657,6 +715,7 @@ class Raylet:
                 pref, avoid = key[2:] or (-1, False)
                 prefs.append(pref)
                 avoids.append(avoid)
+                spreads.append(is_spread)
             task_group.append(g)
         with self._cv:      # flags consumed, like the host path
             for tid in avoided:
@@ -665,7 +724,7 @@ class Raylet:
             self._group_stats["group_tasks"] += len(specs)
         tg = np.array(task_group, dtype=np.int32)
         return _Subgroups(tg, np.bincount(tg, minlength=len(first)),
-                          first, prefs, avoids)
+                          first, prefs, avoids, spreads)
 
     def _schedule_rows_host_subgrouped(self, specs: list,
                                        sub: _Subgroups) -> list[int]:
@@ -681,13 +740,17 @@ class Raylet:
         loops: the per-task path's numpy overhead on the scheduling
         thread was the dominant GIL cost of tiny-task rounds.
         Singletons and top-k sampling rounds keep the per-task policy
-        (the host sampler draws per task)."""
+        (the host sampler draws per task).  SPREAD subgroups place
+        through the numpy round-robin fill
+        (``ops.hybrid_kernel.spread_group_host``) from the shared
+        cursor, and leave it where their last task left it."""
         snapshot = self._effective_snapshot()
         n_rows = snapshot.node_mask.shape[0]
         # each subgroup's tasks in batch order
         members = np.split(np.argsort(sub.task_group, kind="stable"),
                            np.cumsum(sub.counts)[:-1])
         rows = np.full(len(specs), -1, dtype=np.int32)
+        spread = self._policy.spread
         vec_ok = get_config().scheduler_top_k_fraction == 0
         if vec_ok:
             from ..ops.hybrid_kernel import schedule_group_host
@@ -699,6 +762,16 @@ class Raylet:
             idxs, pref, avoid = members[g], sub.prefs[g], sub.avoids[g]
             req = specs[sub.first[g]].resources.dense(
                 self.crm.resource_index, snapshot.totals.shape[1])
+            if sub.spread[g]:
+                from ..ops.hybrid_kernel import spread_group_host
+                counts_row, queued, new_avail, cursor = spread_group_host(
+                    snapshot.avail, snapshot.totals, snapshot.node_mask,
+                    req, len(idxs), spread.cursor)
+                rows[idxs] = _spread_order(counts_row, queued,
+                                           spread.cursor, n_rows)
+                snapshot.avail[:] = new_avail       # sequential carry
+                spread.cursor = cursor
+                continue
             if vec_ok and len(idxs) > 1:
                 gmask = None
                 if avoid and 0 <= self.row < n_rows:
@@ -749,18 +822,47 @@ class Raylet:
                 specs[t].resources.dense(self.crm.resource_index, width)
                 for t in sub.first])
             plain = all(p < 0 for p in sub.prefs) and not any(sub.avoids)
+            spread = sub.spread if any(sub.spread) else None
+        orders = None
         if get_config().scheduler_top_k_fraction > 0:
             counts_host = self._schedule_device_topk(req, sub)
         else:
-            # locality rows and avoid flags ride along per group
+            # locality rows, avoid flags and SPREAD flags ride along per
+            # group
             loc = () if plain else (sub.prefs, sub.avoids, self.row)
-            counts_host = self._schedule_rows_delta(req, sub.counts, *loc)
+            start = self._policy.spread.cursor
+            counts_host = self._schedule_rows_delta(req, sub.counts, *loc,
+                                                    spread=spread)
+            if spread is not None:
+                with phase("raylet.spread_order"):
+                    orders = self._spread_orders(counts_host, spread,
+                                                 start)
         with phase("raylet.expand"):
-            return _expand(sub.task_group, counts_host)
+            return _expand(sub.task_group, counts_host, orders)
+
+    def _spread_orders(self, counts: np.ndarray, spread: list,
+                       start: int) -> dict:
+        """{SPREAD subgroup: its rows in placement order} from the
+        beat's counts and its SPREAD readback (each group's
+        feasible-phase counts and the cursor after it), the round
+        having started from cursor ``start``."""
+        queued, after = self._delta_engine.last_spread()
+        n = counts.shape[1] - 1
+        return {g: _spread_order(counts[g], queued[g],
+                                 int(after[g - 1]) if g else start, n)
+                for g in np.flatnonzero(spread).tolist()}
+
+    def _engine(self):
+        """The delta engine, built on first use (``make_delta_scheduler``:
+        sharded over the device mesh past one chip)."""
+        if self._delta_engine is None:
+            from ..scheduling.sharded_delta import make_delta_scheduler
+            self._delta_engine = make_delta_scheduler(self.crm)
+        return self._delta_engine
 
     def _schedule_rows_delta(self, req_arr, cnt_arr, prefs=None,
-                             avoids=None, local_row: int = -1
-                             ) -> "np.ndarray":
+                             avoids=None, local_row: int = -1,
+                             spread=None) -> "np.ndarray":
         """The fused delta-heartbeat placement call
         (scheduling.policy.DeltaScheduler): no snapshot copy, no full
         upload — the device mirror syncs incrementally from the CRM's
@@ -771,19 +873,18 @@ class Raylet:
         bit-identical to the host twin
         (``_schedule_rows_host_subgrouped``) and the oracle.  Per-group
         locality rows (``prefs``) and avoid flags (``avoids``, keeping
-        a group off ``local_row``) take the beat's localized program.
-        One counts readback per beat.  Returns (G, N+1) int32 counts
-        for the G request rows given.
+        a group off ``local_row``) take the beat's localized program;
+        SPREAD flags (``spread``) its SPREAD variant, from this
+        raylet's one SPREAD cursor, which the beat's readback then
+        advances.  One counts readback per beat.  Returns (G, N+1)
+        int32 counts for the G request rows given.
 
         The engine comes from ``make_delta_scheduler``: with
         ``scheduler_shards`` resolving past one chip the mirror and the
         beat shard over the device mesh (ShardedDeltaScheduler ->
         ops.shard_reduce.ShardPlane), else the single-device
         DeltaScheduler — placements are bit-identical either way."""
-        from ..scheduling.sharded_delta import make_delta_scheduler
-        eng = self._delta_engine
-        if eng is None:
-            eng = self._delta_engine = make_delta_scheduler(self.crm)
+        eng = self._engine()
         with phase("raylet.overrides"):
             _v, totals_f, avail_f, place_mask, _rows = \
                 self.crm.delta_view(-2)
@@ -808,9 +909,14 @@ class Raylet:
                 base = avail_f[row].astype(np.int64)
                 base[:w] = (base[:w] - planned[:w]).clip(-(2**30), 2**30)
                 overrides[row] = base.astype(np.int32)
+        round_robin = self._policy.spread
         counts = eng.beat(req_arr, cnt_arr, overrides=overrides,
                           extra_mask=extra, prefs=prefs, avoids=avoids,
-                          local_row=local_row)
+                          local_row=local_row, spread=spread,
+                          cursor=round_robin.cursor)
+        after = eng.last_spread()
+        if after is not None:
+            round_robin.cursor = int(after[1][-1])
         cfg = get_config()
         if cfg.lease_plane_enabled and cfg.lease_budget_source == "beat":
             with phase("raylet.publish"):

@@ -114,10 +114,23 @@ class HybridSchedulingPolicy(ISchedulingPolicy):
 class SpreadSchedulingPolicy(ISchedulingPolicy):
     """Round-robin over feasible+available nodes (reference
     ``SpreadSchedulingPolicy``: best-effort even spreading with a rotating
-    start cursor)."""
+    start cursor).
+
+    ``cursor`` is the raylet's one SPREAD cursor: the per-task path
+    advances it here, and the raylet's subgroup path hands it to the
+    delta beat (``DeltaScheduler.beat``) or its host twin and stores
+    the cursor they leave, so both paths rotate from the same place."""
 
     def __init__(self):
         self._cursor = 0
+
+    @property
+    def cursor(self) -> int:
+        return self._cursor
+
+    @cursor.setter
+    def cursor(self, value: int) -> None:
+        self._cursor = int(value)
 
     def schedule(self, state, req, options):
         thr = threshold_fp(options.spread_threshold)
@@ -256,6 +269,9 @@ class DeltaScheduler:
     rescore == CPU oracle.
     """
 
+    # the beat places SPREAD groups (``beat(spread=...)``)
+    spread_on_device = True
+
     def __init__(self, crm):
         self._crm = crm
         self._version = -2          # pre-first-sync sentinel (< any epoch)
@@ -288,18 +304,24 @@ class DeltaScheduler:
         # tell "new beat" from "same beat re-read"
         self._budgets_host: np.ndarray | None = None
         self._budget_seq = 0
-        # groups: real groups placed; pref_groups/avoid_groups: of
-        # those, the groups with a preferred row / an avoid flag
+        # the last SPREAD beat's (feasible-phase counts, cursor after
+        # each group), host slices of its readback
+        self._spread_host = None
+        # groups: real groups placed; pref_groups/avoid_groups/
+        # spread_groups: of those, the groups with a preferred row / an
+        # avoid flag / the SPREAD strategy
         self.stats = {"beats": 0, "delta_beats": 0, "full_rescores": 0,
                       "clean_beats": 0, "rows_uploaded": 0,
                       "classes_installed": 0, "groups": 0,
-                      "pref_groups": 0, "avoid_groups": 0}
+                      "pref_groups": 0, "avoid_groups": 0,
+                      "spread_groups": 0}
 
     # -- public surface -----------------------------------------------------
     def beat(self, group_reqs, group_counts, overrides=None,
              extra_mask=None, require_available: bool = False,
              spread_threshold: float | None = None, prefs=None,
-             avoids=None, local_row: int = -1) -> np.ndarray:
+             avoids=None, local_row: int = -1, spread=None,
+             cursor: int = 0) -> np.ndarray:
         """Sync the mirror, schedule G classes, return (G, n+1) int32
         counts (column n = infeasible/queued-nowhere), matching
         ``hybrid_kernel.schedule_grouped`` on a fresh CRM snapshot.
@@ -315,6 +337,13 @@ class DeltaScheduler:
         the localized program (``schedule_grouped_localized``'s
         semantics), one without runs the plain one.  Groups may share a
         class.
+
+        ``spread`` (G,) bools mark the SPREAD groups: a beat with any
+        runs the SPREAD variant, whose marked groups take the
+        round-robin fill from ``cursor`` (``SpreadSchedulingPolicy``'s,
+        rotating over the CRM's rows) in order among the others.  The
+        feasible-phase counts and the cursor after each group ride the
+        same readback (``last_spread``); the last is the new cursor.
         """
         with phase("delta.beat"):
             with phase("delta.view"):
@@ -366,6 +395,7 @@ class DeltaScheduler:
                 counts_p[:g] = np.asarray(group_counts, np.int32)
 
                 loc = self._pack_locality(gp, g, prefs, avoids, local_row)
+                sp = self._pack_spread(gp, g, spread, cursor, n_real)
                 ov = self._pack_overrides(overrides)
                 if extra_mask is None:
                     em = self._ones
@@ -376,13 +406,19 @@ class DeltaScheduler:
 
             with phase("delta.dispatch"):
                 counts_d, amin_d = self._fused_call(
-                    slots_p, counts_p, em, ov, thr, require_available, loc)
+                    slots_p, counts_p, em, ov, thr, require_available, loc,
+                    sp)
                 self._last_amin = amin_d
             with phase("delta.readback"):
                 # the one sanctioned host<-device readback of the beat:
                 # rows [:gp] are the water-fill counts, rows [gp:] the
                 # lease budgets
                 packed = np.asarray(counts_d)
+                self._spread_host = None
+                if sp is not None:
+                    # the SPREAD variant's last gp rows
+                    tail, packed = packed[-gp:], packed[:-gp]
+                    self._spread_host = (tail[:g, :n_real], tail[:g, -1])
                 counts = packed[:gp]
                 self._budgets_host = packed[gp:, :n_real]
                 self._budget_seq += 1
@@ -434,6 +470,12 @@ class DeltaScheduler:
         sync — this is the host slice the beat already fetched."""
         return self._budgets_host
 
+    def last_spread(self) -> tuple[np.ndarray, np.ndarray] | None:
+        """The last beat's SPREAD readback: ((G, n_real) feasible-phase
+        counts, (G,) cursor after each group), or None when that beat
+        had no SPREAD group.  NOT a device sync."""
+        return self._spread_host
+
     def class_vectors(self) -> dict[int, np.ndarray]:
         """{slot: interned dense request vector} for every resident
         class — the publisher's map from budget rows back to lease
@@ -459,10 +501,11 @@ class DeltaScheduler:
         return jax.device_put(emp)
 
     def _fused_call(self, slots_p, counts_p, em, ov, thr,
-                    require_available, loc=None):
+                    require_available, loc=None, sp=None):
         """The fused schedule->argmin device call (the localized program
-        when ``loc`` = (prefs, avoids, local_row) is given); returns
-        (counts_device (G, n+1), amin_device (C,))."""
+        when ``loc`` = (prefs, avoids, local_row) is given, the SPREAD
+        variant when ``sp`` = (spread flags, cursor, row count) is);
+        returns (counts_device (G, n+1), amin_device (C,))."""
         import jax
 
         from ..ops import hybrid_kernel as hk
@@ -472,6 +515,10 @@ class DeltaScheduler:
             kw = {"pref_rows": jax.device_put(prefs),
                   "avoid": jax.device_put(avoids),
                   "local_row": np.int32(local_row)}
+        if sp is not None:
+            flags, cursor, n_rows = sp
+            kw.update(spread=jax.device_put(flags), cursor=np.int32(cursor),
+                      n_rows=np.int32(n_rows))
         return hk.fused_beat(
             self._totals, self._avail, self._mask, self._keys, self._reqs,
             jax.device_put(slots_p), jax.device_put(counts_p), em,
@@ -620,6 +667,19 @@ class DeltaScheduler:
         self.stats["avoid_groups"] += n_avoid
         return pref_p, avoid_p, int(local_row)
 
+    def _pack_spread(self, gp, g, spread, cursor, n_rows):
+        """(spread flags padded to ``gp`` groups, cursor, row count), or
+        None when no group is SPREAD; counts them in ``stats``."""
+        if spread is None:
+            return None
+        flags = np.zeros((gp,), bool)
+        flags[:g] = spread
+        n_spread = int(flags.sum())
+        if not n_spread:
+            return None
+        self.stats["spread_groups"] += n_spread
+        return flags, int(cursor) % n_rows, n_rows
+
     def _pack_overrides(self, overrides):
         import jax
         if not overrides:
@@ -649,6 +709,12 @@ class CompositeSchedulingPolicy(ISchedulingPolicy):
             SchedulingType.NODE_AFFINITY: NodeAffinitySchedulingPolicy(),
             SchedulingType.NODE_LABEL: NodeLabelSchedulingPolicy(),
         }
+
+    @property
+    def spread(self) -> SpreadSchedulingPolicy:
+        """The SPREAD policy, whose ``cursor`` the raylet's subgroup
+        path shares."""
+        return self._policies[SchedulingType.SPREAD]
 
     def schedule(self, state, req, options):
         return self._policies[options.scheduling_type].schedule(

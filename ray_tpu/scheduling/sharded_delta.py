@@ -42,8 +42,11 @@ class ShardedDeltaScheduler(DeltaScheduler):
     override staging, and the fused beat with the two-level ICI/DCN
     argmin reduce.  The sync protocol (epoch journal, dirty-fraction
     fallback, class slot registry, double-buffered staging parity) is
-    inherited unchanged — so is the public surface.
+    inherited unchanged — so is the public surface, short of the SPREAD
+    variant (``spread_on_device``).
     """
+
+    spread_on_device = False
 
     def __init__(self, crm, n_shards: int = 0,
                  reduce_mode: str | None = None):
@@ -113,8 +116,12 @@ class ShardedDeltaScheduler(DeltaScheduler):
         return jax.device_put(emp, self._plane.sh_vec)
 
     def _fused_call(self, slots_p, counts_p, em, ov, thr,
-                    require_available, loc=None):
+                    require_available, loc=None, sp=None):
         import jax
+        if sp is not None:
+            raise NotImplementedError(
+                "the sharded beat has no SPREAD program: the raylet places "
+                "rounds with SPREAD groups on its host twin")
         pl = self._plane
         kw = {}
         if loc is not None:

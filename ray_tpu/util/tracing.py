@@ -37,7 +37,7 @@ _local = threading.local()      # driver-side ambient context
 PHASES = ("delta.beat", "delta.view", "delta.upload", "delta.stage",
           "delta.dispatch", "delta.readback",
           "raylet.group", "raylet.locality", "raylet.overrides",
-          "raylet.expand", "raylet.publish")
+          "raylet.spread_order", "raylet.expand", "raylet.publish")
 
 _NO_SPAN = contextlib.nullcontext()
 _annotation = None      # jax.profiler.TraceAnnotation, once JAX is loaded

@@ -65,6 +65,19 @@ def test_fused_beat(one_chip):
         s(())).compile()
 
 
+@pytest.mark.parametrize("localized", [False, True])
+def test_fused_beat_spread_variant(one_chip, localized):
+    from ray_tpu.ops.hybrid_kernel import fused_beat
+    s = lambda *a, **k: _shape(one_chip, *a, **k)      # noqa: E731
+    kw = {"spread": s((C,), bool), "cursor": s(()), "n_rows": s(())}
+    if localized:
+        kw.update(pref_rows=s((C,)), avoid=s((C,), bool), local_row=s(()))
+    fused_beat.lower(
+        s((N, R)), s((N, R)), s((N,), bool), s((C, N)), s((C, R)),
+        s((C,)), s((C,)), s((N,), bool), s((8,)), s((8, R)),
+        s(()), **kw).compile()
+
+
 def test_apply_dirty_rows(one_chip):
     from ray_tpu.ops.hybrid_kernel import apply_dirty_rows
     s = lambda *a, **k: _shape(one_chip, *a, **k)      # noqa: E731

@@ -86,7 +86,20 @@ def _locality_and_avoid(cluster, rng):
 def _non_default(cluster, rng):
     specs, avoid = _locality_and_avoid(cluster, rng)
     specs[40] = _spec(1, strategy=SchedulingStrategy(
-        kind=SchedulingStrategyKind.SPREAD))
+        kind=SchedulingStrategyKind.NODE_AFFINITY,
+        node_id=cluster.crm.id_of(3), soft=True))
+    return specs, avoid
+
+
+def _spread(cluster, rng):
+    # SPREAD specs, flagged ones and ones with args among them, form
+    # their own subgroups: no locality row, no avoid flag
+    specs, avoid = _locality_and_avoid(cluster, rng)
+    spread = SchedulingStrategy(kind=SchedulingStrategyKind.SPREAD)
+    for t in range(0, 64, 3):
+        specs[t] = _spec(int(rng.integers(0, 3)), specs[t].args, spread)
+        if t % 2:
+            avoid.add(specs[t].task_id)
     return specs, avoid
 
 
@@ -106,11 +119,13 @@ def _host_twin_small(cluster, rng):
 
 def _reference_subgroups(raylet, specs, avoid):
     """The per-task loop the pass replaces: key (scheduling class,
-    locality row or -1, avoid), first appearance decides the order."""
+    locality row or -1, avoid), first appearance decides the order; a
+    SPREAD spec has neither a locality row nor an avoid flag."""
     groups, first, prefs, avoids, task_group = {}, [], [], [], []
     for t, s in enumerate(specs):
-        a = s.task_id in avoid
-        pref = -1 if a else raylet._locality_row(s)
+        spread = s.strategy.kind is SchedulingStrategyKind.SPREAD
+        a = s.task_id in avoid and not spread
+        pref = -1 if a or spread else raylet._locality_row(s)
         pref = -1 if pref is None else pref
         key = (s.scheduling_class(), pref, a)
         if key not in groups:
@@ -143,7 +158,7 @@ def _set_avoid(raylet, avoid):
 
 @pytest.mark.parametrize("make", [
     _no_args, _locality_and_avoid, _non_default, _distinct_equal_objects,
-    _host_twin_small], ids=lambda f: f.__name__.strip("_"))
+    _host_twin_small, _spread], ids=lambda f: f.__name__.strip("_"))
 def test_subgroup_pass_matches_per_task_loops(cluster, make):
     rng = np.random.default_rng(7)
     raylet = cluster.head()
@@ -152,9 +167,8 @@ def test_subgroup_pass_matches_per_task_loops(cluster, make):
     before = raylet.queue_stats()
     sub = raylet._subgroup(specs, raylet._locality_rows(specs))
     after = raylet.queue_stats()
-    default = all(s.strategy.kind is SchedulingStrategyKind.DEFAULT
-                  for s in specs)
-    if not default:
+    kinds = {SchedulingStrategyKind.DEFAULT, SchedulingStrategyKind.SPREAD}
+    if not all(s.strategy.kind in kinds for s in specs):
         # the mixed path: nothing consumed, nothing counted
         assert sub is None
         assert raylet._avoid_local == avoid
@@ -171,14 +185,21 @@ def test_subgroup_pass_matches_per_task_loops(cluster, make):
     assert sub.first == first
     assert sub.prefs == prefs
     assert sub.avoids == avoids
+    assert sub.spread == [specs[t].strategy.kind
+                          is SchedulingStrategyKind.SPREAD for t in first]
     assert sub.counts.tolist() == np.bincount(
         task_group, minlength=len(first)).tolist()
-    assert not raylet._avoid_local          # flags consumed
-    slow = sum(1 for s in specs if s.args or s.task_id in avoid)
+    default = [s for s in specs
+               if s.strategy.kind is SchedulingStrategyKind.DEFAULT]
+    # the DEFAULT specs' flags consumed, the SPREAD specs' kept
+    assert raylet._avoid_local == avoid - {s.task_id for s in default}
+    slow = sum(1 for s in default if s.args or s.task_id in avoid)
     assert after["group_tasks"] - before["group_tasks"] == len(specs)
     assert after["group_probed"] - before["group_probed"] == slow
-    if make is _locality_and_avoid:
+    if make in (_locality_and_avoid, _spread):
         assert {2, 5} <= set(prefs) and any(avoids)
+    if make is _spread:
+        assert any(sub.spread) and raylet._avoid_local
 
     # the expansion, on counts that place each subgroup anywhere
     n = len(raylet.crm.arrays()[0])
@@ -193,6 +214,7 @@ def test_subgroup_pass_matches_per_task_loops(cluster, make):
     got = {}
     for name, floor in (("host", 10**9), ("device", 1)):
         _set_avoid(raylet, avoid)
+        raylet._policy.spread.cursor = 0    # both from one SPREAD cursor
         cfg.scheduler_device_batch_min = floor
         got[name] = raylet._schedule_rows_soft(batch)
     assert got["host"] == got["device"]
