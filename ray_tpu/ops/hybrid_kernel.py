@@ -30,9 +30,14 @@ The resolution implemented here:
         s(j)<=L  ⟺  ∀i: used_i + (j+1) r_i) * S < (L+1) T_i
                  ⟺  j+1 <= ((L+1) T_i - used_i S - 1) // (r_i S)
 
-    so "slots with key <= L" is a vectorized O(N*R) expression and L* is a
-    14-step integer binary search — no data-dependent iteration counts, no
-    dynamic shapes, everything jit-compiles to one XLA program.
+    so "slots with key <= L" is a vectorized O(N*R) expression.  L* is
+    found by a ``_SEARCH_WAYS``-ary search over the 2*SCALE + 1 levels:
+    each step evaluates the slot count at ways - 1 levels in one
+    (ways-1, N, R) broadcast and keeps the one run of levels where the
+    monotone "covers the group" predicate turns true — 3 dependent steps
+    at 32 ways where a binary search takes 14, and exactly the L* a binary
+    search returns.  No data-dependent iteration counts, no dynamic
+    shapes, everything jit-compiles to one XLA program.
 3.  Groups run under one lax.scan carrying ``avail`` — G steps (number of
     distinct scheduling classes, typically tens), not T steps (tasks).
 
@@ -56,6 +61,13 @@ from ..scheduling.contract import AVAIL_SHIFT, BUDGET_CAP, SCALE, SCORE_SHIFT
 # (ROADMAP Queue 1, item 8 checks it on a local one).
 _BIG = 1 << 30
 _INF_KEY = 2**31 - 1
+# Levels probed per step of the water-fill's level search, plus one: 32
+# ways take 3 steps over the 8,193 levels (16 take 4, 8 take 5).  A step
+# costs about the same at 16 ways as at 32 on a v5e, so fewer steps win;
+# 32 was the fastest of 4, 8, 16 and 32.  The sharded twin
+# (ops/shard_reduce.py) has its own, since each of its steps also costs
+# a cross-chip sum.
+_SEARCH_WAYS = 32
 
 
 def _keys_one_req(totals, avail, req, thr_fp, mask):
@@ -79,18 +91,85 @@ def _keys_one_req(totals, avail, req, thr_fp, mask):
     return jnp.where(feas, key, _INF_KEY)
 
 
-def _slots_at_or_below(L, totals, used, req, req_pos, m_max, thr_fp):
-    """m_n(L): per-node count of placement slots with eff-score key <= L.
+def _slot_terms(totals, avail, req):
+    """The level-free terms of m(L), computed once, outside the search
+    loop: the totals (N, R), used * SCALE + 1 (N, R), the divisor
+    d = req * SCALE (R,) and its f32 reciprocal.  A column the request
+    leaves at 0 gets totals 0, used term -_BIG and d = 1: its quotient is
+    _BIG at every level, so it drops out of the min over columns."""
+    pos = req > 0
+    d = jnp.where(pos, req * SCALE, 1)
+    return (jnp.where(pos, totals, 0),
+            jnp.where(pos, (totals - avail) * SCALE + 1, -_BIG),
+            d, 1.0 / d.astype(jnp.float32))
+
+
+def _floor_div(num, d, inv):
+    """num // d, exact, where |num // d| < 2**20 (so wherever d >= SCALE,
+    by the contract's width audit) or d = 1 and num = _BIG (exact in
+    f32): the f32 quotient is within 1/4 of the true one, and the int32
+    remainder corrects the floor by one either way.  Costs a few vector
+    ops where int32 division costs many."""
+    q = jnp.floor(num.astype(jnp.float32) * inv).astype(jnp.int32)
+    rem = num - q * d                       # exact: wraps back into range
+    return q - (rem < 0).astype(jnp.int32) + (rem >= d).astype(jnp.int32)
+
+
+def _slots_at_or_below(L, terms, m_max, thr_fp):
+    """m_n(L): per-node count of placement slots with eff-score key <= L,
+    shape L.shape + (N,) (a vector of levels is one broadcast);
+    ``terms`` from ``_slot_terms``.
 
     Threshold collapse: levels below thr_fp all equal the level-0 count
-    (eff score of a sub-threshold available slot is 0).
+    (eff score of a sub-threshold available slot is 0).  The clip to
+    [0, m_max] comes after the min over columns, where it is the same.
     """
-    Lp = jnp.where(L < thr_fp, thr_fp - 1, L)
-    num = (Lp + 1) * totals - used * SCALE - 1          # (N, R)
-    denom = jnp.maximum(req * SCALE, 1)[None, :]
-    jc = jnp.clip(num // denom, 0, _BIG)
-    jcount = jnp.where(req_pos[None, :], jc, _BIG).min(axis=1)
-    return jnp.minimum(m_max, jcount)
+    totals, used_s, d, inv = terms
+    Lp = jnp.where(L < thr_fp, thr_fp - 1, L)[..., None, None]
+    num = (Lp + 1) * totals - used_s                    # (..., N, R)
+    return jnp.clip(_floor_div(num, d, inv).min(axis=-1), 0, m_max)
+
+
+def _level_strides(ways):
+    """Static run lengths of a ``ways``-ary search over the 2*SCALE + 1
+    levels [0, 2*SCALE]: step i cuts what is left into ``ways`` runs of
+    ``strides[i]`` levels, the last step into single levels."""
+    strides, left = [], 2 * SCALE + 1
+    while left > 1:
+        left = -(-left // ways)
+        strides.append(left)
+    return strides
+
+
+def _probe_levels(lo, stride, ways):
+    """The ``ways`` - 1 levels one step probes: the last of every run but
+    the last, clamped to 2*SCALE (where the predicate holds, see
+    ``_level_search``)."""
+    t = lo + stride * jnp.arange(1, ways, dtype=jnp.int32) - 1
+    return jnp.minimum(t, 2 * SCALE)
+
+
+def _narrowed(lo, stride, ok):
+    """The first level of the run where the monotone ``ok`` over the
+    probes turns true: a run per false probe lies below it."""
+    return lo + stride * (ok.shape[0] - ok.sum(dtype=jnp.int32))
+
+
+def _level_search(covers, ways, lo=0, steps=0):
+    """L*: the smallest level in [0, 2*SCALE] where ``covers`` holds.
+
+    ``covers`` maps a (ways-1,) vector of increasing levels to a (ways-1,)
+    bool, monotone in the level, and holds at 2*SCALE (the water-fill's
+    slot count there is every slot, so it covers any ``n_avail`` <=
+    ``total_cap``).  Each step of the scan calls it once; a caller that
+    took the first ``steps`` steps itself resumes at its ``lo``."""
+    def narrow(lo, stride):
+        ok = covers(_probe_levels(lo, stride, ways))
+        return _narrowed(lo, stride, ok), None
+
+    strides = jnp.array(_level_strides(ways)[steps:], jnp.int32)
+    l_star, _ = jax.lax.scan(narrow, jnp.int32(lo), strides)
+    return l_star
 
 
 def _schedule_group(avail, totals, node_mask, req, count, gmask, thr_fp,
@@ -100,7 +179,6 @@ def _schedule_group(avail, totals, node_mask, req, count, gmask, thr_fp,
     n = totals.shape[0]
     req_pos = req > 0
     any_req = req_pos.any()
-    used = totals - avail
 
     feas = jnp.all(jnp.where(req_pos[None, :], totals >= req[None, :], True),
                    axis=1) & node_mask & gmask
@@ -112,19 +190,12 @@ def _schedule_group(avail, totals, node_mask, req, count, gmask, thr_fp,
     n_avail = jnp.minimum(count, total_cap)     # placements that consume
     overflow = count - n_avail                  # queue on best feasible
 
-    m_of = partial(_slots_at_or_below, totals=totals, used=used, req=req,
-                   req_pos=req_pos, m_max=m_max, thr_fp=thr_fp)
+    m_of = partial(_slots_at_or_below, terms=_slot_terms(totals, avail, req),
+                   m_max=m_max, thr_fp=thr_fp)
 
-    # binary search smallest L in [0, 2*SCALE] with sum(m(L)) >= n_avail
-    def bisect(carry, _):
-        lo, hi = carry
-        mid = (lo + hi) // 2
-        ok = m_of(mid).sum() >= n_avail
-        return (jnp.where(ok, lo, mid + 1), jnp.where(ok, mid, hi)), None
-
-    (l_star, _), _ = jax.lax.scan(
-        bisect, (jnp.int32(0), jnp.int32(2 * SCALE)), None,
-        length=SCALE.bit_length() + 2)
+    # smallest L in [0, 2*SCALE] with sum(m(L)) >= n_avail
+    l_star = _level_search(lambda t: m_of(t).sum(axis=-1) >= n_avail,
+                           _SEARCH_WAYS)
 
     base = jnp.where(l_star > 0, m_of(jnp.maximum(l_star - 1, 0)), 0)
     at_level = m_of(l_star)

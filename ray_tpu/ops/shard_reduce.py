@@ -16,7 +16,9 @@ chip), under explicit ``shard_map`` bodies rather than GSPMD so each device
 
 and the beat's global decisions lower to two collectives:
 
-- water-fill sums: ``psum`` over "ici" (intra-slice) then "dcn";
+- water-fill sums: ``psum`` over "ici" (intra-slice) then "dcn", one per
+  step of the level search (the ways - 1 probed levels' sums in one
+  vector; the first step's also carries the class's total capacity);
 - the placement argmin: each shard's local min PACKED key already carries
   the global traversal index in its low ``NODE_BITS`` bits (ties are
   impossible across nodes), so a plain ``pmin`` over "ici" then "dcn" IS
@@ -44,6 +46,8 @@ import numpy as np
 from ..scheduling.contract import (AVAIL_SHIFT, BUDGET_CAP, MAX_NODES,
                                    SCALE, SCORE_SHIFT)
 from ..util.jax_compat import shard_map_compat
+from .hybrid_kernel import (_level_search, _level_strides, _narrowed,
+                            _probe_levels, _slot_terms, _slots_at_or_below)
 
 # Python ints folded as literals — NOT jnp scalars: a closure-captured
 # device buffer was once seen to force a slow synchronous mode on a
@@ -51,6 +55,10 @@ from ..util.jax_compat import shard_map_compat
 _BIG = 1 << 30
 _INF_KEY = 2**31 - 1
 _IDX_MASK = MAX_NODES - 1
+# Levels probed per step of the sharded level search, plus one.  Each
+# step ends in one cross-chip sum, which costs the same whatever it
+# carries, so this path takes 32 ways: 3 sums a class step.
+_SEARCH_WAYS = 32
 
 
 def resolve_shards(requested: int, n_devices: int) -> int:
@@ -166,18 +174,6 @@ def _keys_cols_block(totals_l, avail_l, mask_l, reqs, idx_l, thr_fp,
     return jnp.where(feas, key, _INF_KEY)
 
 
-def _slots_at_or_below_l(L, totals_l, used_l, req, req_pos, m_max_l,
-                         thr_fp):
-    """Shard-local m_n(L) — identical closed form to
-    hybrid_kernel._slots_at_or_below on this shard's rows."""
-    Lp = jnp.where(L < thr_fp, thr_fp - 1, L)
-    num = (Lp + 1) * totals_l - used_l * SCALE - 1
-    denom = jnp.maximum(req * SCALE, 1)[None, :]
-    jc = jnp.clip(num // denom, 0, _BIG)
-    jcount = jnp.where(req_pos[None, :], jc, _BIG).min(axis=1)
-    return jnp.minimum(m_max_l, jcount)
-
-
 def _schedule_group_sharded(avail_l, totals_l, mask_l, req, count,
                             thr_fp, offset, my_lin, n_lin,
                             require_available):
@@ -187,7 +183,6 @@ def _schedule_group_sharded(avail_l, totals_l, mask_l, req, count,
     n_l = totals_l.shape[0]
     req_pos = req > 0
     any_req = req_pos.any()
-    used_l = totals_l - avail_l
 
     feas = jnp.all(jnp.where(req_pos[None, :], totals_l >= req[None, :],
                              True), axis=1) & mask_l
@@ -196,34 +191,37 @@ def _schedule_group_sharded(avail_l, totals_l, mask_l, req, count,
     m_max_l = jnp.where(feas & any_req,
                         jnp.clip(caps.min(axis=1), 0, _BIG), 0)
 
-    total_cap = _psum2(m_max_l.sum())
-    n_avail = jnp.minimum(count, total_cap)
+    m_of = partial(_slots_at_or_below,
+                   terms=_slot_terms(totals_l, avail_l, req),
+                   m_max=m_max_l, thr_fp=thr_fp)
+
+    # smallest L in [0, 2*SCALE] with sum(m(L)) >= n_avail, one two-level
+    # sum a step.  The first step is taken here: the total capacity rides
+    # in its sum, since n_avail is needed only once the sums are back.
+    stride = _level_strides(_SEARCH_WAYS)[0]
+    t0 = _probe_levels(jnp.int32(0), stride, _SEARCH_WAYS)
+    got = _psum2(jnp.concatenate([m_of(t0).sum(axis=-1),
+                                  m_max_l.sum()[None]]))
+    n_avail = jnp.minimum(count, got[-1])       # got[-1]: total_cap
     overflow = count - n_avail
-
-    m_of = partial(_slots_at_or_below_l, totals_l=totals_l, used_l=used_l,
-                   req=req, req_pos=req_pos, m_max_l=m_max_l,
-                   thr_fp=thr_fp)
-
-    def bisect(carry, _):
-        lo, hi = carry
-        mid = (lo + hi) // 2
-        ok = _psum2(m_of(mid).sum()) >= n_avail
-        return (jnp.where(ok, lo, mid + 1), jnp.where(ok, mid, hi)), None
-
-    (l_star, _), _ = jax.lax.scan(
-        bisect, (jnp.int32(0), jnp.int32(2 * SCALE)), None,
-        length=SCALE.bit_length() + 2)
+    lo = _narrowed(jnp.int32(0), stride, got[:-1] >= n_avail)
+    l_star = _level_search(
+        lambda t: _psum2(m_of(t).sum(axis=-1)) >= n_avail, _SEARCH_WAYS,
+        lo=lo, steps=1)
 
     base_l = jnp.where(l_star > 0, m_of(jnp.maximum(l_star - 1, 0)), 0)
     at_level = m_of(l_star)
     extra_l = at_level - base_l
-    rem = n_avail - _psum2(base_l.sum())
-    # global exclusive prefix over traversal order: local cumsum plus the
-    # level-set mass of every PRECEDING shard (row blocks are contiguous
-    # in shard-linear order, so "preceding shard" == "lower rows")
-    g_ici = jax.lax.all_gather(extra_l.sum(), "ici")      # (ici,)
-    g_all = jax.lax.all_gather(g_ici, "dcn").reshape(-1)  # (S,)
-    before = jnp.where(jnp.arange(n_lin) < my_lin, g_all, 0).sum()
+    # one gather of each shard's (level-set mass, base mass): the global
+    # base is the base column's sum, and the global exclusive prefix over
+    # traversal order is the local cumsum plus the level-set mass of
+    # every PRECEDING shard (row blocks are contiguous in shard-linear
+    # order, so "preceding shard" == "lower rows")
+    g_ici = jax.lax.all_gather(jnp.stack([extra_l.sum(), base_l.sum()]),
+                               "ici")                     # (ici, 2)
+    g_all = jax.lax.all_gather(g_ici, "dcn").reshape(-1, 2)  # (S, 2)
+    rem = n_avail - g_all[:, 1].sum()
+    before = jnp.where(jnp.arange(n_lin) < my_lin, g_all[:, 0], 0).sum()
     prefix_l = jnp.cumsum(extra_l) - extra_l + before
     give = jnp.clip(rem - prefix_l, 0, extra_l)
     alloc_l = base_l + give
